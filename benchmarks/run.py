@@ -206,6 +206,8 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--out", type=str, default=None)
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.enable()
     quick, smoke = args.quick, args.smoke
     out_path = args.out or _DEFAULT_OUT
     if quick and args.out is None:

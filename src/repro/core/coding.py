@@ -30,7 +30,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ops
+from repro.kernels import ops, ref
 
 
 @dataclasses.dataclass(frozen=True)
@@ -240,17 +240,26 @@ def tree_unravel(vec: jax.Array, spec) -> object:
 # splits/merges only unsharded dims => no collective, no remat.
 
 def _fwht_axis1(x: jax.Array) -> jax.Array:
-    """Unnormalized FWHT along axis 1 of (A, n, Ns) via butterflies that
-    never touch the other (possibly sharded) axes."""
+    """Unnormalized FWHT along axis 1 of (A, n, Ns) that never touches
+    the other (possibly sharded) axes.
+
+    ``H_n = H_c (x) H_w`` with ``w = min(n, 128)``: splitting axis 1 into
+    (c, w), the transform is one contraction with ``H_w`` and one with
+    ``H_c`` (matmuls on the MXU).  A log2(n)-pass butterfly of
+    reshape/stack would leave size-1/2 minor dims that the TPU pads to
+    full (8, 128) tiles — hundreds of times the leaf's bytes in HBM.
+    ``HIGHEST`` keeps the +-1 products exact in f32.
+    """
     a_dim, n, ns = x.shape
-    m = 1
-    while m < n:
-        x = x.reshape(a_dim, n // (2 * m), 2, m, ns)
-        lo = x[:, :, 0]
-        hi = x[:, :, 1]
-        x = jnp.stack([lo + hi, lo - hi], axis=2).reshape(a_dim, n, ns)
-        m *= 2
-    return x
+    w = min(n, 128)
+    c = n // w
+    hp = jax.lax.Precision.HIGHEST
+    x = x.reshape(a_dim, c, w, ns)
+    x = jnp.einsum("acwn,wv->acvn", x, ref.hadamard_matrix(w), precision=hp)
+    if c > 1:
+        x = jnp.einsum("acvn,cd->advn", x, ref.hadamard_matrix(c),
+                       precision=hp)
+    return x.reshape(a_dim, n, ns)
 
 
 @dataclasses.dataclass(frozen=True)

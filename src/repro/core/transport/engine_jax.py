@@ -51,16 +51,9 @@ from repro.core.transport import dcqcn, designs, faults, network, topology
 from repro.core.transport import engine as engine_mod
 from repro.core.transport.params import SimParams
 
-try:  # the repo runs on a CPU jax build; keep the module importable without
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.experimental import enable_x64
-    HAVE_JAX = True
-    _JAX_ERR: Exception | None = None
-except Exception as e:  # pragma: no cover - exercised only without jax
-    HAVE_JAX = False
-    _JAX_ERR = e
+import jax
+import jax.numpy as jnp
+from jax import lax
 
 # Trace-time counter: incremented once per jit compilation of a core
 # (the function body only runs while tracing).  The jit-cache-reuse
@@ -72,13 +65,6 @@ TRACE_COUNT = [0]
 # tail block per trace length).
 _CORE_CACHE: dict = {}
 _WINDOW_CACHE: dict = {}
-
-
-def _require_jax():
-    if not HAVE_JAX:  # pragma: no cover
-        raise RuntimeError(
-            f"backend='jax' needs a working jax install ({_JAX_ERR!r}); "
-            "use backend='numpy'")
 
 
 # ----------------------------------------------------------------------
@@ -397,7 +383,6 @@ def traces_batched(eng, design_list, n_rounds: int, seeds, *,
     tolerance contract) with ``BatchedEngine.traces(...,
     legacy_streams=False)`` per seed.
     """
-    _require_jax()
     p = eng.p
     net, rel = p.net, p.rel
     unknown = [d for d in design_list if d not in designs.DESIGNS]
@@ -566,7 +551,7 @@ def traces_batched(eng, design_list, n_rounds: int, seeds, *,
           "good": np.zeros((S, n), dtype=np.int32)}
     rate_scales = np.stack([st.rate_scale for st in streams])
 
-    with enable_x64():
+    with jax.enable_x64(True):
         for t0 in range(0, T, block_steps):
             tb = min(block_steps, T - t0)
             host = [host_block(st, t0, tb, si)
@@ -625,6 +610,14 @@ def _scatter_block(out, res, si, t0, plan, ph_steps, ph_pkts, hgs,
 # Jitted fixed bounded-window assembly
 # ----------------------------------------------------------------------
 
+def _cumsum1(x):
+    """Inclusive prefix sum along axis 1.  ``jnp.cumsum`` on f64 takes
+    XLA:TPU minutes to compile (its f64 is emulated, and the windowed
+    cumsum lowering grows with the axis); the associative scan compiles
+    in under a second and differs only in f64 summation order."""
+    return lax.associative_scan(jnp.add, x, axis=1)
+
+
 def _make_window(ph_rows, ph_frac, n_groups, perms=None):
     """Jitted twin of ``BatchedEngine._assemble_phase_window_fixed``
     (which the round window is the single-phase case of).
@@ -643,7 +636,7 @@ def _make_window(ph_rows, ph_frac, n_groups, perms=None):
         for k, rows in enumerate(ph_rows):
             b_k = budget_us * ph_frac[k]
             nat_k = nat[:, rows]
-            cum = jnp.cumsum(nat_k, axis=1)
+            cum = _cumsum1(nat_k)
             total_t = cum[:, -1]
             over = total_t > b_k
             times = times + jnp.where(over, b_k, total_t)
@@ -666,7 +659,7 @@ def _make_window(ph_rows, ph_frac, n_groups, perms=None):
             if perms is not None:
                 K = jnp.where(over, d_k.sum(axis=1) - got_k, 0.0)
                 d_perm = d_k[:, perms[k]]
-                cum_d = jnp.cumsum(d_perm, axis=1)
+                cum_d = _cumsum1(d_perm)
                 cutfrac = jnp.clip(
                     (K[:, None] - (cum_d - d_perm))
                     / jnp.maximum(d_perm, 1e-30), 0.0, 1.0)
@@ -694,7 +687,6 @@ def assemble_window_fixed(nat, deliv, tot_sum, budget_us, groups,
     round for the round window; ``perms`` selects the priority cut
     order (one static permutation per phase block, None = arrival).
     """
-    _require_jax()
     ph_rows = [np.asarray(r) for r in ph_rows]
     ph_frac = np.asarray(ph_frac, dtype=np.float64)
     if perms is not None:
@@ -706,7 +698,7 @@ def assemble_window_fixed(nat, deliv, tot_sum, budget_us, groups,
     if fn is None:
         fn = _make_window(ph_rows, ph_frac, len(groups), perms=perms)
         _WINDOW_CACHE[key] = fn
-    with enable_x64():
+    with jax.enable_x64(True):
         times, got, got_g = jax.device_get(
             fn(nat, deliv, np.float64(budget_us),
                [gd for gd, _ in groups]))

@@ -4,24 +4,29 @@ TPU-native design (this is the HW adaptation of the paper's Hadamard
 recovery, which OptiReduce runs on GPU with CUDA butterflies):
 
 - The Sylvester Hadamard matrix factors as a Kronecker product,
-  ``H_n = H_a (x) H_b`` with ``n = a*b``.  Reshaping each length-``n``
-  row to ``(a, b)``, the transform becomes **two dense matmuls**::
+  ``H_n = H_c (x) H_128`` with ``n = c * 128``.  Cutting each length-``n``
+  row into ``c`` lane-aligned chunks of 128, the transform is
 
-      Y = H_a @ X @ H_b
+      Y_i = sum_k H_c[i, k] * (X_k @ H_128)
 
-  Both land on the MXU (128x128 systolic array) instead of log2(n)
-  strided butterfly passes, which would be VPU-bound and HBM-unfriendly.
-  For the default n=4096 tile: a = b = 64, so the per-row cost is two
-  64x64 matmuls - arithmetic intensity ~64 FLOPs/byte, comfortably
-  compute-bound on the MXU.
+  The intra-chunk stage is one dense (rows, 128) x (128, 128) matmul per
+  chunk on the MXU; the cross-chunk stage is a log2(c)-pass butterfly of
+  whole-chunk adds and subtracts on the VPU.  No op splits the lane
+  dimension: every slice is a 128-lane-aligned window of the tile, which
+  is what Mosaic lays out natively (an in-kernel reshape of the lane axis
+  to ``(a, b)`` with ``b < 128`` is refused by the TPU compiler).  Rows
+  of ``n <= 128`` are one chunk: a single ``X @ H_n`` matmul.
 
 - Grid tiles rows; each kernel instance holds a ``(block_rows, n)`` tile
-  plus the two (a,a)/(b,b) Hadamard factors in VMEM.  With the default
-  ``block_rows=128`` and n=4096 (f32) the working set is
-  128*4096*4 * 2 (in+out) + small factors ~= 4.2 MB << 16 MB VMEM.
+  plus the (128, 128) Hadamard factor in VMEM.  With ``block_rows=128``
+  and n=4096 (f32) the in/out tiles are 2 MiB each, double-buffered.
 
-All matmul dims are multiples of (8,128) sublane/lane tiling for f32 as
-long as n >= 128 and block_rows % 8 == 0 (enforced by ops.py padding).
+- The matmuls run at ``Precision.HIGHEST``: the factor is exactly +-1
+  (times a power-of-two scale for even log2(n)), so the f32 result
+  matches the jnp butterfly oracle to f32 rounding.
+
+``block_rows`` must be a multiple of 8 (f32 sublanes) or the whole row
+count; ops.py pads rows to a multiple of it.
 """
 from __future__ import annotations
 
@@ -33,62 +38,80 @@ from jax.experimental import pallas as pl
 
 from repro.kernels import ref
 
-
-def _kron_factors(n: int) -> tuple[int, int]:
-    """Split n = a*b with a, b as close as possible (both pow2)."""
-    log = n.bit_length() - 1
-    la = (log + 1) // 2
-    return 1 << la, 1 << (log - la)
+LANES = 128
 
 
-def _fwht_kernel(x_ref, ha_ref, hb_ref, *rest, a: int, b: int):
-    (o_ref,) = rest[-1:]
+def _rotate(x_ref, h_ref, signs_ref):
+    """The tile's rows, Hadamard-transformed: a list of (rows, w) f32
+    chunks, ``w = min(n, 128)``, in output order."""
+    n = x_ref.shape[1]
+    w = h_ref.shape[0]
+    h = h_ref[...]
+    chunks = []
+    for k in range(n // w):
+        xk = x_ref[:, k * w:(k + 1) * w].astype(jnp.float32)
+        if signs_ref is not None:
+            # fused Rademacher pre-multiply on the VMEM-resident tile
+            # instead of a separate HBM round trip before the transform
+            xk = xk * signs_ref[:, k * w:(k + 1) * w]
+        chunks.append(jnp.dot(xk, h, preferred_element_type=jnp.float32,
+                              precision=jax.lax.Precision.HIGHEST))
+    half = 1
+    while half < len(chunks):
+        for i in range(0, len(chunks), 2 * half):
+            for j in range(i, i + half):
+                a, b = chunks[j], chunks[j + half]
+                chunks[j], chunks[j + half] = a + b, a - b
+        half *= 2
+    return chunks
+
+
+def _fwht_kernel(x_ref, h_ref, *rest):
+    o_ref = rest[-1]
     signs_ref = rest[0] if len(rest) == 2 else None
-    rows = x_ref.shape[0]
-    x = x_ref[...].astype(jnp.float32).reshape(rows, a, b)
-    if signs_ref is not None:
-        # fused Rademacher pre-multiply: one VPU op on the VMEM-resident
-        # tile instead of a separate HBM round-trip before the transform
-        x = x * signs_ref[...].reshape(a, b)[None]
-    ha = ha_ref[...]
-    hb = hb_ref[...]
-    # t[r,k,j] = sum_l x[r,k,l] * hb[l,j]   (contract over l)
-    t = jax.lax.dot_general(
-        x, hb, (((2,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    # y[r,i,j] = sum_k ha[i,k] * t[r,k,j]   (contract over k)
-    y = jax.lax.dot_general(
-        t, ha, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    # dot_general output order is (r, j, i) -> transpose back to (r, i, j)
-    y = jnp.swapaxes(y, 1, 2)
-    o_ref[...] = y.reshape(rows, a * b).astype(o_ref.dtype)
+    chunks = _rotate(x_ref, h_ref, signs_ref)
+    w = h_ref.shape[0]
+    for k, y in enumerate(chunks):
+        o_ref[:, k * w:(k + 1) * w] = y.astype(o_ref.dtype)
 
 
-def _fwht_quant_kernel(x_ref, ha_ref, hb_ref, *rest, a: int, b: int):
+def _fwht_quant_kernel(x_ref, h_ref, *rest):
     q_ref, scale_ref = rest[-2:]
     if len(rest) == 4:
         signs_ref, noise_ref = rest[0], rest[1]
     else:
         signs_ref, noise_ref = None, rest[0]
-    rows = x_ref.shape[0]
-    x = x_ref[...].astype(jnp.float32).reshape(rows, a, b)
-    if signs_ref is not None:
-        x = x * signs_ref[...].reshape(a, b)[None]
-    ha = ha_ref[...]
-    hb = hb_ref[...]
-    t = jax.lax.dot_general(
-        x, hb, (((2,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    y = jax.lax.dot_general(
-        t, ha, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    y = jnp.swapaxes(y, 1, 2).reshape(rows, a * b)
+    chunks = _rotate(x_ref, h_ref, signs_ref)
+    w = h_ref.shape[0]
     # quantize while the rotated tile is still in VMEM: the unfused
     # pair writes the f32 rotation to HBM and reads it straight back —
     # this kernel's whole point is skipping that round trip, leaving
     # one f32 read (input) + one int8 write (output) per element
-    absmax = jnp.max(jnp.abs(y), axis=-1, keepdims=True)
+    absmax = functools.reduce(
+        jnp.maximum,
+        [jnp.max(jnp.abs(y), axis=-1, keepdims=True) for y in chunks])
     qscale = jnp.where(absmax > 0, absmax / 127.0, 1.0)
-    q = jnp.floor(y / qscale + noise_ref[...].astype(jnp.float32))
-    q_ref[...] = jnp.clip(q, -127, 127).astype(jnp.int8)
-    scale_ref[...] = qscale[:, 0]
+    for k, y in enumerate(chunks):
+        sl = slice(k * w, (k + 1) * w)
+        q = jnp.floor(y / qscale + noise_ref[:, sl].astype(jnp.float32))
+        q_ref[:, sl] = jnp.clip(q, -127, 127).astype(jnp.int8)
+    scale_ref[...] = qscale
+
+
+def _rotate_specs(x, signs, scale, block_rows):
+    """BlockSpecs + operands shared by both kernels: the row tile, the
+    (w, w) Hadamard factor with ``scale`` folded in, optional signs."""
+    rows, n = x.shape
+    assert rows % block_rows == 0, (rows, block_rows)
+    w = min(n, LANES)
+    h = ref.hadamard_matrix(w) * jnp.float32(scale)
+    in_specs = [pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
+                pl.BlockSpec((w, w), lambda i: (0, 0))]
+    operands = [x, h]
+    if signs is not None:
+        in_specs.append(pl.BlockSpec((1, n), lambda i: (0, 0)))
+        operands.append(signs.reshape(1, n).astype(jnp.float32))
+    return in_specs, operands
 
 
 @functools.partial(jax.jit,
@@ -96,53 +119,43 @@ def _fwht_quant_kernel(x_ref, ha_ref, hb_ref, *rest, a: int, b: int):
 def fwht_quantize_pallas(x: jax.Array, noise: jax.Array,
                          signs: jax.Array | None = None, *,
                          scale: float = 1.0, block_rows: int = 128,
-                         interpret: bool = True):
+                         interpret: bool):
     """Fused FWHT + per-row absmax int8 quantization in one pass.
 
-    The rotate stage is exactly :func:`fwht_pallas` (same two-matmul
+    The rotate stage is exactly :func:`fwht_pallas` (same chunked
     Kronecker body, same optional Rademacher/scale fusions); its VMEM
     tile feeds the :mod:`quantize` stage directly.  Returns
     ``(q int8 (rows, n), scale f32 (rows,))`` — the wire payload of
     ``coding.encode_quantized``.
     """
     rows, n = x.shape
-    assert rows % block_rows == 0, (rows, block_rows)
-    a, b = _kron_factors(n)
-    ha = ref.hadamard_matrix(a) * jnp.float32(scale)
-    hb = ref.hadamard_matrix(b)
-    grid = (rows // block_rows,)
-    in_specs = [
-        pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
-        pl.BlockSpec((a, a), lambda i: (0, 0)),
-        pl.BlockSpec((b, b), lambda i: (0, 0)),
-    ]
-    operands = [x, ha, hb]
-    if signs is not None:
-        in_specs.append(pl.BlockSpec((1, n), lambda i: (0, 0)))
-        operands.append(signs.reshape(1, n).astype(jnp.float32))
+    in_specs, operands = _rotate_specs(x, signs, scale, block_rows)
     in_specs.append(pl.BlockSpec((block_rows, n), lambda i: (i, 0)))
     operands.append(noise)
-    return pl.pallas_call(
-        functools.partial(_fwht_quant_kernel, a=a, b=b),
-        grid=grid,
+    # the per-row scale leaves as a (rows, 1) column: a 1-D block of a
+    # longer 1-D array does not match the chip's HBM tiling
+    q, scale = pl.pallas_call(
+        _fwht_quant_kernel,
+        grid=(rows // block_rows,),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
+            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((rows, n), jnp.int8),
-            jax.ShapeDtypeStruct((rows,), jnp.float32),
+            jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ],
         interpret=interpret,
     )(*operands)
+    return q, scale[:, 0]
 
 
 @functools.partial(jax.jit,
                    static_argnames=("block_rows", "interpret", "scale"))
 def fwht_pallas(x: jax.Array, signs: jax.Array | None = None, *,
                 scale: float = 1.0, block_rows: int = 128,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool) -> jax.Array:
     """FWHT along the last axis of a 2-D array via pallas_call.
 
     ``x`` must be (rows, n) with n a power of two >= 2 and rows a
@@ -153,28 +166,15 @@ def fwht_pallas(x: jax.Array, signs: jax.Array | None = None, *,
 
     - ``signs`` (n,): Rademacher diagonal multiplied into the input tile
       in VMEM before the transform;
-    - ``scale``: static scalar folded into the left Hadamard factor
-      (entries become ±scale), so the normalization costs zero extra
-      FLOPs on the MXU path.
+    - ``scale``: static scalar folded into the Hadamard factor (entries
+      become ±scale), so the normalization costs zero extra FLOPs on
+      the MXU path.
     """
     rows, n = x.shape
-    assert rows % block_rows == 0, (rows, block_rows)
-    a, b = _kron_factors(n)
-    ha = ref.hadamard_matrix(a) * jnp.float32(scale)
-    hb = ref.hadamard_matrix(b)
-    grid = (rows // block_rows,)
-    in_specs = [
-        pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
-        pl.BlockSpec((a, a), lambda i: (0, 0)),
-        pl.BlockSpec((b, b), lambda i: (0, 0)),
-    ]
-    operands = [x, ha, hb]
-    if signs is not None:
-        in_specs.append(pl.BlockSpec((1, n), lambda i: (0, 0)))
-        operands.append(signs.reshape(1, n).astype(jnp.float32))
+    in_specs, operands = _rotate_specs(x, signs, scale, block_rows)
     return pl.pallas_call(
-        functools.partial(_fwht_kernel, a=a, b=b),
-        grid=grid,
+        _fwht_kernel,
+        grid=(rows // block_rows,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),

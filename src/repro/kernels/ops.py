@@ -1,16 +1,14 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Shape-polymorphic entry points: callers pass any (rows, n) with n a
-power of two; padding to kernel tile multiples happens here.  On this
-CPU container the kernels execute in interpret mode (the kernel body
-runs in Python op-by-op); on TPU set ``REPRO_PALLAS_INTERPRET=0`` to
-compile for the MXU.  ``use_pallas=False`` routes to the pure-jnp oracle
-(used by the dry-run lowering, where interpret-mode callbacks cannot be
-staged for a TPU mesh).
+power of two; padding to kernel tile multiples happens here.  The
+kernels compile for the chip; on the CPU backend (tests, the dry-run
+host) they run in Pallas interpret mode instead, decided per call from
+``jax.default_backend()``.  ``use_pallas=False`` routes to the pure-jnp
+oracle (used by the dry-run lowering, where interpret-mode callbacks
+cannot be staged for a TPU mesh).
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +18,10 @@ from repro.kernels import quantize as _quant
 from repro.kernels import ref
 from repro.kernels import unbias as _unbias
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+
+def _interpret() -> bool:
+    """Interpret the kernels only where there is no chip to compile for."""
+    return jax.default_backend() == "cpu"
 
 
 def _pad_rows(x: jax.Array, mult: int) -> tuple[jax.Array, int]:
@@ -47,7 +48,7 @@ def fwht(x: jax.Array, *, signs: jax.Array | None = None, scale: float = 1.0,
     block_rows = min(block_rows, max(8, rows))
     xp, rows0 = _pad_rows(x, block_rows)
     out = _fwht.fwht_pallas(xp, signs, scale=scale, block_rows=block_rows,
-                            interpret=INTERPRET)
+                            interpret=_interpret())
     return out[:rows0]
 
 
@@ -70,12 +71,12 @@ def fwht_quantize(x: jax.Array, noise: jax.Array, *,
     np_, _ = _pad_rows(noise, block_rows)
     q, s = _fwht.fwht_quantize_pallas(xp, np_, signs, scale=scale,
                                       block_rows=block_rows,
-                                      interpret=INTERPRET)
+                                      interpret=_interpret())
     return q[:rows0], s[:rows0]
 
 
 def quantize_int8(x: jax.Array, noise: jax.Array, *, use_pallas: bool = True,
-                  block_rows: int = 256):
+                  block_rows: int = 128):
     if not use_pallas:
         return ref.quantize_int8(x, noise)
     rows, n = x.shape
@@ -83,7 +84,7 @@ def quantize_int8(x: jax.Array, noise: jax.Array, *, use_pallas: bool = True,
     xp, rows0 = _pad_rows(x, block_rows)
     np_, _ = _pad_rows(noise, block_rows)
     q, scale = _quant.quantize_int8_pallas(xp, np_, block_rows=block_rows,
-                                           interpret=INTERPRET)
+                                           interpret=_interpret())
     return q[:rows0], scale[:rows0]
 
 
@@ -100,5 +101,5 @@ def masked_unbias(y_sum: jax.Array, counts: jax.Array, total: int, *,
     yp, rows0 = _pad_rows(y_sum, block_rows)
     cp, _ = _pad_rows(counts, block_rows)
     out = _unbias.masked_unbias_pallas(yp, cp, total=total,
-                                       block_rows=block_rows, interpret=INTERPRET)
+                                       block_rows=block_rows, interpret=_interpret())
     return out[:rows0]

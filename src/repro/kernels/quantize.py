@@ -25,17 +25,19 @@ def _quant_kernel(x_ref, noise_ref, q_ref, scale_ref):
     scale = jnp.where(absmax > 0, absmax / 127.0, 1.0)
     q = jnp.floor(x / scale + noise_ref[...].astype(jnp.float32))
     q_ref[...] = jnp.clip(q, -127, 127).astype(jnp.int8)
-    scale_ref[...] = scale[:, 0]
+    scale_ref[...] = scale
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def quantize_int8_pallas(x: jax.Array, noise: jax.Array, *,
-                         block_rows: int = 256,
-                         interpret: bool = True):
+                         block_rows: int = 128,
+                         interpret: bool):
     rows, n = x.shape
     assert rows % block_rows == 0, (rows, block_rows)
     grid = (rows // block_rows,)
-    return pl.pallas_call(
+    # the per-row scale leaves as a (rows, 1) column: a 1-D block of a
+    # longer 1-D array does not match the chip's HBM tiling
+    q, scale = pl.pallas_call(
         _quant_kernel,
         grid=grid,
         in_specs=[
@@ -44,11 +46,12 @@ def quantize_int8_pallas(x: jax.Array, noise: jax.Array, *,
         ],
         out_specs=[
             pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
+            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((rows, n), jnp.int8),
-            jax.ShapeDtypeStruct((rows,), jnp.float32),
+            jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ],
         interpret=interpret,
     )(x, noise)
+    return q, scale[:, 0]

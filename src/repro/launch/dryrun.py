@@ -1,9 +1,13 @@
 import os
 import sys
 
+# A simulated-mesh lowering tool: it lowers onto host CPU devices only
+# and must never take an attached chip (whose one device would break
+# every production mesh below).  Like the device count, this has to be
+# set before jax is imported.
+os.environ["JAX_PLATFORMS"] = "cpu"
 # --scale-check needs 1024 simulated devices; everything else keeps the
-# 512-device default (REPRO_DRYRUN_DEVICES overrides).  Must be decided
-# before jax is imported.
+# 512-device default (REPRO_DRYRUN_DEVICES overrides).
 _N_DEV = int(os.environ.get(
     "REPRO_DRYRUN_DEVICES",
     1024 if "--scale-check" in sys.argv else 512))
@@ -115,8 +119,6 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # jax 0.4.x: list of one dict
-        cost = cost[0] if cost else None
     colls = costs.hlo_collective_bytes(compiled.as_text())
 
     n_dev = mesh.devices.size

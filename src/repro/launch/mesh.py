@@ -6,8 +6,7 @@ x 256 with a leading "pod" axis; "pod" composes with "data" for
 gradient reduction (DP = pod x data) and is the axis Celeris's lossy
 sync cares about most (cross-pod DCI links are the slow, lossy hops).
 
-All construction goes through :func:`repro.sharding.make_mesh` so the
-jax 0.4/0.8 API split stays in one place.
+All construction goes through :func:`repro.sharding.make_mesh`.
 """
 from __future__ import annotations
 

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 import repro.configs as C
 from repro import sharding as shd
+from repro.launch import compile_cache
 from repro.launch import mesh as mesh_mod
 from repro.models import model as M
 from repro.serve import serve_step
@@ -30,6 +31,7 @@ def main():
     ap.add_argument("--mesh", choices=["none", "single", "multi"],
                     default="none")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = C.get_smoke(args.arch) if args.smoke else C.get(args.arch)
     if args.mesh != "none":

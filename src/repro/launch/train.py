@@ -14,6 +14,7 @@ import jax
 
 import repro.configs as C
 from repro import sharding as shd
+from repro.launch import compile_cache
 from repro.data.pipeline import DataConfig
 from repro.launch import mesh as mesh_mod
 from repro.optim.adamw import OptConfig
@@ -38,6 +39,7 @@ def main():
     ap.add_argument("--mesh", choices=["none", "host", "single", "multi"],
                     default="none")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = C.get_smoke(args.arch) if args.smoke else C.get(args.arch)
     mesh = None

@@ -1,10 +1,8 @@
-"""Mesh / shard_map compatibility shims (JAX 0.4.x through 0.8.x).
+"""Mesh / shard_map helpers.
 
-The repo targets the jax 0.8 surface (``jax.shard_map`` with
-``axis_names``/``check_vma``, ``jax.sharding.AxisType``,
-``jax.lax.axis_size``); containers pinned to 0.4.x lack all three.
 Every mesh / shard_map / axis-size use in the tree goes through this
-module so the version split lives in exactly one place.
+module, so the mesh conventions (Auto axis types, explicit device
+slices, the dp/model axis names) live in one place.
 """
 from __future__ import annotations
 
@@ -13,67 +11,36 @@ from typing import Sequence
 
 import jax
 
-_HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-_HAS_LAX_AXIS_SIZE = hasattr(jax.lax, "axis_size")
-
-try:  # jax >= 0.8: top-level shard_map (axis_names / check_vma API)
-    _shard_map_new = jax.shard_map
-    _shard_map_old = None
-except AttributeError:  # jax 0.4.x: experimental (auto / check_rep API)
-    _shard_map_new = None
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
               check_vma: bool = False):
-    """jax-0.8-style shard_map on either jax.
-
-    ``axis_names``: the *manual* axes (None = all mesh axes manual).  On
-    0.4.x this is translated to the complementary ``auto`` frozenset and
-    ``check_vma`` to ``check_rep``.  Note 0.4.x partial-auto shard_map
-    only traces under ``jit`` — every call site in this repo is jitted.
-    """
-    if _shard_map_new is not None:
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return _shard_map_new(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=check_vma, **kw)
-    auto = frozenset()
+    """``jax.shard_map``; ``axis_names`` are the *manual* axes (None =
+    all mesh axes manual), the rest stay auto (GSPMD)."""
+    kw = {}
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma,
-                          auto=auto)
+        kw["axis_names"] = set(axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 
 def make_mesh(shape: Sequence[int], axis_names: Sequence[str]
               ) -> jax.sharding.Mesh:
-    """jax.make_mesh with the pre-0.9 Auto axis-type behavior pinned and
-    the device list sliced explicitly (0.4.x requires an exact count)."""
+    """jax.make_mesh with Auto axis types pinned and the device list
+    sliced explicitly (the first ``prod(shape)`` devices)."""
     n = math.prod(shape)
     devices = jax.devices()
     if len(devices) < n:
         raise ValueError(f"mesh {tuple(shape)} needs {n} devices, "
                          f"only {len(devices)} available")
-    kw = {}
-    if _HAS_AXIS_TYPE:
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(tuple(shape))
-    return jax.make_mesh(tuple(shape), tuple(axis_names),
-                         devices=devices[:n], **kw)
+    return jax.make_mesh(
+        tuple(shape), tuple(axis_names), devices=devices[:n],
+        axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(shape)))
 
 
 def axis_size(axis_name) -> int:
     """Static size of a (possibly composite) mesh axis inside shard_map."""
     names = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
-    size = 1
-    for a in names:
-        if _HAS_LAX_AXIS_SIZE:
-            size *= jax.lax.axis_size(a)
-        else:  # 0.4.x: core.axis_frame(name) is the bound size
-            from jax._src import core as _core
-            size *= int(_core.axis_frame(a))
-    return size
+    return math.prod(jax.lax.axis_size(a) for a in names)
 
 
 # ----------------------------------------------------------------------
@@ -105,20 +72,3 @@ def dp_axes(mesh: jax.sharding.Mesh | None = None) -> tuple[str, ...]:
 
 MODEL_AXIS = "model"
 POD_AXIS = "pod"
-
-
-# ----------------------------------------------------------------------
-# Partitioner capability gates.  The jax 0.4.x CPU SPMD partitioner
-# CHECK-crashes (hlo_sharding_util IsManualSubgroup) when raw/uncoded AD
-# gradients cross a partial-auto shard_map boundary — on 0.4.x only the
-# Hadamard-coded psum island lowers, so plain-lossy applies its receiver
-# window to the GSPMD-synced gradient (receiver granularity).  jax 0.8's
-# partitioner handles the general island, unlocking per-(peer, wire-row)
-# loss granularity for the uncoded mode too.  train_step dispatches on
-# this gate; keep every version split in this module.
-# ----------------------------------------------------------------------
-
-def plain_lossy_island_supported() -> bool:
-    """True when per-(peer,row) plain-lossy can run as a partial-auto
-    shard_map island (jax >= 0.8 partitioner)."""
-    return _shard_map_new is not None

@@ -7,9 +7,12 @@ The gradient collective dispatches on
 - **exact** — lossless all-reduce (RoCE-like semantics).  On a mesh
   this is pure GSPMD: the batch is dp-sharded and value_and_grad of the
   global batch-mean loss makes the partitioner insert the all-reduces.
-- **lossy** — best-effort WITHOUT coding, the Fig.-1 ablation: wire
-  rows beyond the bounded receiver window are holes in the raw
-  gradient (:func:`_mask_grads_plain`, GSPMD-composable).
+- **lossy** — best-effort WITHOUT coding, the Fig.-1 ablation: a
+  shard_map island like the coded one, with per-(peer, wire-row) masks
+  applied *before* the plain psum — true sender-side loss, no recovery
+  and no rescaling (:func:`_sync_grads_plain_island`).  Without a dp
+  axis the single device applies one receiver-window mask per leaf
+  (:func:`_mask_grads_plain`).
 - **lossy_hadamard** — the paper's §III-B path, a **shard_map island,
   manual over the dp axes ('pod','data'), auto (GSPMD) over 'model'**:
   each dp shard runs value_and_grad on its local batch, then per-leaf
@@ -40,14 +43,6 @@ The gradient collective dispatches on
   encode/quantize stage, so in-pod sync stays full-precision f32 while
   the DCI payload ships int8 (the bandwidth-starved hop is the only
   one paying the precision cost).
-
-On jax >= 0.8 (``sharding.plain_lossy_island_supported``) the **lossy**
-mode also runs as a shard_map island with per-(peer, wire-row) masks
-applied *before* the plain psum — true sender-side loss without
-recovery.  The 0.4.x CPU partitioner CHECK-crashes on that island shape
-(only the coded psum graph survives partial-auto), so there the mode
-keeps the receiver-window fallback: masking the already-synced
-gradient.
 
 Then the optimizer update (AdamW, fp32 master, ZeRO-1-sharded state)
 under plain GSPMD.  The factory precomputes the per-leaf Hadamard
@@ -138,9 +133,7 @@ def _dp_size(dp, mesh):
 
 def _leaf_mask(key, i, peer_id, n_rot, drop_rate):
     """Per-(leaf, peer) arrival mask.  ``peer_id`` is this shard's index
-    along the dp axes, passed in explicitly: ``axis_index`` inside a
-    partially-auto shard_map lowers to a PartitionId op the SPMD
-    partitioner rejects (jax 0.4.x CPU)."""
+    along the dp axes (a P(dp)-sharded arange fed into the island)."""
     k = jax.random.fold_in(jax.random.fold_in(key, 2 * i + 1), peer_id)
     return lc.arrival_mask(k, n_rot, drop_rate)
 
@@ -199,12 +192,10 @@ def _sync_grads_celeris(grads, dp, plans, key, drop_rate, celeris, mesh,
 
 def _sync_grads_plain_island(grads, dp, plans, key, drop_rate, mesh,
                              peer_id):
-    """Per-(peer, wire-row) loss WITHOUT coding, inside the island
-    (jax >= 0.8 only — see ``sharding.plain_lossy_island_supported``):
-    each peer masks its own contribution *before* the plain psum, so a
+    """Per-(peer, wire-row) loss WITHOUT coding, inside the island: each
+    peer masks its own contribution *before* the plain psum, so a
     dropped row is missing from that peer only, with no recovery and no
-    rescaling — the uncoded sender-side ablation the 0.4.x partitioner
-    can't lower."""
+    rescaling — the uncoded sender-side ablation."""
     flat, treedef = jax.tree_util.tree_flatten(grads)
     n_dp = _dp_size(dp, mesh)
     out, fracs = [], []
@@ -228,14 +219,11 @@ def _sync_grads_plain_island(grads, dp, plans, key, drop_rate, mesh,
 def _mask_grads_plain(grads, plans, key, drop_rate):
     """Receiver-window loss WITHOUT coding — the Fig.-1 ablation.
 
-    One arrival mask per leaf is applied to the (already synced or
-    local) gradient: wire rows that miss the bounded window are holes in
-    the raw gradient, with no recovery — exactly the damage §III-B's
-    coding absorbs.  This is receiver-granularity loss (a late row is
-    lost from every peer at once); the per-(peer, row) form lives in the
-    Hadamard island, whose coded psum is the only shape the jax 0.4.x
-    CPU partitioner lowers under partial-auto shard_map.  Pure
-    elementwise + reshape ops, so it composes with any mesh via GSPMD.
+    One arrival mask per leaf is applied to the single device's
+    gradient: wire rows that miss the bounded window are holes in the
+    raw gradient, with no recovery — exactly the damage §III-B's coding
+    absorbs.  On a dp mesh the per-(peer, row) form runs in the island
+    (:func:`_sync_grads_plain_island`).
     """
     flat, treedef = jax.tree_util.tree_flatten(grads)
     out, fracs = [], []
@@ -343,11 +331,8 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
     pod_axes = tuple(a for a in dp if a == shd.POD_AXIS)
     data_axes = tuple(a for a in dp if a != shd.POD_AXIS)
 
-    def island(params, batch, key, drop_rate, plans, peer=None):
-        # this shard's index along the dp axes (None when no lossy sync
-        # consumes it: an unused manual-sharded input CHECK-crashes the
-        # jax 0.4.x CPU SPMD partitioner)
-        peer_id = peer[0] if peer is not None else 0
+    def island(params, batch, key, drop_rate, plans, peer):
+        peer_id = peer[0]     # this shard's index along the dp axes
         loss, nll, aux, grads = _accum_grads(params, batch, key, drop_rate)
 
         if mode is CollectiveMode.HIERARCHICAL:
@@ -409,14 +394,9 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
                  if l.size >= celeris.min_coded_size else None
                  for l, sp in zip(flat, flat_specs)]
 
-        island_modes = {CollectiveMode.LOSSY_HADAMARD}
+        island_modes = {CollectiveMode.LOSSY_HADAMARD, CollectiveMode.LOSSY}
         if pod_axes:
             island_modes.add(CollectiveMode.HIERARCHICAL)
-        if shd.plain_lossy_island_supported():
-            # jax >= 0.8: the uncoded island lowers too, unlocking
-            # per-(peer,row) plain-lossy (0.4.x keeps the post-sync
-            # receiver-window fallback below)
-            island_modes.add(CollectiveMode.LOSSY)
         use_island = (dp and mode in island_modes
                       and any(p is not None for p in plans))
         if use_island:
@@ -436,21 +416,14 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
             )(params, batch, key, drop_rate,
               jnp.arange(_dp_size(dp, mesh), dtype=jnp.int32))
         elif dp:
-            # Exact (and plain-lossy, and hadamard-with-nothing-to-code)
-            # collectives on a mesh need no manual island: with the
+            # Exact collectives on a mesh (and lossy modes with no leaf
+            # large enough to code) need no manual island: with the
             # batch dp-sharded, value_and_grad of the global batch-mean
             # loss makes GSPMD insert exactly the lossless all-reduces
-            # the island's pmean would (and the jax 0.4.x CPU
-            # partitioner CHECK-crashes on a partial-auto island whose
-            # gradients cross the boundary uncoded).  Plain-lossy then
-            # applies the receiver window to the synced gradient.
+            # the island's pmean would.
             loss, nll, aux, grads = _accum_grads(params, batch, key,
                                                  drop_rate)
-            if mode is CollectiveMode.LOSSY:
-                grads, frac = _mask_grads_plain(grads, plans, key,
-                                                drop_rate)
-            else:
-                frac = jnp.float32(1.0)
+            frac = jnp.float32(1.0)
         else:   # single-device / no-dp path
             lossy_ctx = M.LossyCtx(enabled=celeris.lossy_moe, key=key,
                                    drop_rate=jnp.reshape(drop_rate,

@@ -8,7 +8,6 @@ import textwrap
 import numpy as np
 import pytest
 
-from repro import sharding as shd
 from repro.core.transport import (BatchedEngine, NetworkParams, SimParams,
                                   coupling)
 
@@ -180,13 +179,8 @@ def test_scale_check_512_lowers_plain_collectives():
     assert "OK" in out
 
 
-@pytest.mark.skipif(
-    not shd.plain_lossy_island_supported(),
-    reason="per-(peer,row) plain-lossy island needs the jax >= 0.8 "
-           "partitioner (0.4.x CPU CHECK-crashes on the uncoded island); "
-           "exercised by the CI jax-0.8 matrix leg")
 def test_plain_lossy_island_roundtrip_8dev():
-    """jax >= 0.8 only: CollectiveMode.LOSSY runs as a shard_map island
+    """CollectiveMode.LOSSY runs as a shard_map island
     (``_sync_grads_plain_island``) — per-(peer, wire-row) masks applied
     *before* the plain psum.  Zero drop must match the exact baseline
     (no coding in this path, so equality is tight), and at a real rate
@@ -198,7 +192,6 @@ def test_plain_lossy_island_roundtrip_8dev():
         from repro.data.pipeline import DataConfig, make_source
         from repro.optim.adamw import OptConfig
         from repro.train import train_step as ts, sharding_rules as rules
-        assert shd.plain_lossy_island_supported()
         mesh = shd.make_mesh((8,), ('data',))
         shd.set_global_mesh(mesh)
         cfg = C.get_smoke('qwen2-0.5b')
@@ -229,5 +222,3 @@ def test_plain_lossy_island_roundtrip_8dev():
         assert np.isfinite(m_ld['loss'])
         print('OK')
     """)
-    # NOTE for the 0.4.x container: this test auto-skips; the CI 0.8
-    # leg runs it (see .github/workflows/ci.yml, tier1-jax08 job).
