@@ -37,7 +37,6 @@ import json
 import math
 import os
 import sys
-import time
 import traceback
 
 _ROOT = os.path.dirname(os.path.abspath(__file__))
@@ -80,12 +79,6 @@ def _peak_bytes(devices=None) -> list:
         stats = d.memory_stats() or {}
         out.append(stats.get("peak_bytes_in_use"))
     return out
-
-
-def _timed(fn, *args):
-    t0 = time.perf_counter()
-    out = jax.block_until_ready(fn(*args))
-    return out, time.perf_counter() - t0
 
 
 # ----------------------------------------------------------------------
@@ -174,19 +167,15 @@ def train_phase(cfg: ModelConfig, *, seq: int, batch: int, steps: int = 5,
         init_key = jax.random.fold_in(tr.key, 0)   # Trainer's own init
         b0 = {k: jnp.asarray(v) for k, v in tr.source.global_batch(0).items()}
         # zero-drop step from the initial state (also the compile)
-        (st1, m1), compile_s = _timed(
-            tr.step_fn, tr.state, b0, jax.random.fold_in(tr.key, 0),
-            jnp.float32(0.0))
+        st1, m1 = tr.step_fn(tr.state, b0, jax.random.fold_in(tr.key, 0),
+                             jnp.float32(0.0))
         snaps[mode] = _snapshot(st1, m1)
         del st1, m1
         tr.state = jax.jit(ts.init_state, static_argnums=1)(init_key, cfg)
         held = {k: jnp.asarray(v)
                 for k, v in tr.source.global_batch(10_000).items()}
         before = [float(eval_loss(tr.state["params"], x)) for x in (b0, held)]
-        t0 = time.perf_counter()
         hist = tr.run(steps)
-        jax.block_until_ready(tr.state)
-        steady = (time.perf_counter() - t0) / steps
         after = [float(eval_loss(tr.state["params"], x)) for x in (b0, held)]
         loss = [float(x) for x in hist["loss"]]
         falls = bool(np.all(np.isfinite(loss + before + after))
@@ -197,8 +186,6 @@ def train_phase(cfg: ModelConfig, *, seq: int, batch: int, steps: int = 5,
                      "heldout_batch_loss_before_after": [before[1], after[1]],
                      "finite_and_falling": falls,
                      "step0_near_ln_vocab": near,
-                     "first_step_s_incl_compile": compile_s,
-                     "steady_step_s": steady,
                      "peak_bytes_in_use": _peak_bytes()[0]}
         ok = ok and falls and near
         del tr
@@ -223,9 +210,9 @@ def engine_phase(n_nodes: int, *, n_pods: int = 4, n_rounds: int = 20,
                 timeout_scale=timeout_scale,
                 base=topology.hier_params(n_pods, base=base,
                                           dci_oversubscription=8.0))
-    res_np, numpy_s = _timed(sweep, BatchedSimParams(**grid))
-    res_j, first_s = _timed(sweep, BatchedSimParams(backend="jax", **grid))
-    res_j2, steady_s = _timed(sweep, BatchedSimParams(backend="jax", **grid))
+    res_np = sweep(BatchedSimParams(**grid))
+    res_j = sweep(BatchedSimParams(backend="jax", **grid))
+    res_j2 = sweep(BatchedSimParams(backend="jax", **grid))
     worst = {"p99": 0.0, "recv_frac": 0.0, "tier_recv_frac": 0.0}
     ok = res_j.stats.keys() == res_np.stats.keys()
     for k, a in res_np.stats.items():
@@ -242,10 +229,7 @@ def engine_phase(n_nodes: int, *, n_pods: int = 4, n_rounds: int = 20,
             "seeds": list(seeds), "designs": list(designs.DESIGNS),
             "max_rel_diff_vs_numpy": worst, "rtol": 1e-5,
             "celeris_p99_ms": [st.p99 / 1e3 for st in cel],
-            "celeris_loss": [st.mean_loss for st in cel],
-            "numpy_wall_s": numpy_s,
-            "jax_first_wall_s_incl_compile": first_s,
-            "jax_steady_wall_s": steady_s, "ok": bool(ok)}
+            "celeris_loss": [st.mean_loss for st in cel], "ok": bool(ok)}
 
 
 def kernels_phase(shapes=((256, 4096), (8192, 4096)), seed: int = 0) -> dict:
@@ -281,10 +265,6 @@ def kernels_phase(shapes=((256, 4096), (8192, 4096)), seed: int = 0) -> dict:
             jf = jax.jit(fn)
             custom = "tpu_custom_call" in jf.lower(*args).as_text()
             got = jax.block_until_ready(jf(*args))
-            t0 = time.perf_counter()
-            for _ in range(3):
-                jax.block_until_ready(jf(*args))
-            us = (time.perf_counter() - t0) / 3 * 1e6
             if name == "fwht":
                 err = float(jnp.max(jnp.abs(got - rot_ref)))
                 good = err <= 1e-4 * float(jnp.max(jnp.abs(rot_ref)))
@@ -310,7 +290,7 @@ def kernels_phase(shapes=((256, 4096), (8192, 4096)), seed: int = 0) -> dict:
             good = bool(good and custom == compiled)
             out[f"{name}_{rows}x{n}"] = {"max_abs_err": err,
                                          "tpu_custom_call": custom,
-                                         "us_per_call": us, "ok": good}
+                                         "ok": good}
             ok = ok and good
     out["ok"] = bool(ok)
     return out
@@ -326,15 +306,14 @@ def serve_phase(cfg: ModelConfig, *, batch: int = 4, prompt_len: int = 48,
     prompt = jax.random.randint(jax.random.PRNGKey(seed + 1),
                                 (batch, prompt_len), 0, cfg.vocab_size)
     prefill = serve_step.make_prefill(cfg, prompt_len + gen)
-    (logits, caches), prefill_s = _timed(prefill, params, {"tokens": prompt})
+    logits, caches = prefill(params, {"tokens": prompt})
     first = jnp.argmax(logits, -1)[:, None]
     clean = jax.tree.map(jnp.copy, caches)
     mask = jnp.asarray(coupling.kv_hole_masks(np.array([kv_frac]), n_rows,
                                               seed=seed)[0])
-    deg, degrade_s = _timed(jax.jit(serve_step.degrade_caches), caches,
-                            mask, jax.random.PRNGKey(seed + 2))
-    toks, decode_s = _timed(serve_step.greedy_decode, cfg, params, deg,
-                            first, prompt_len, gen)
+    deg = jax.jit(serve_step.degrade_caches)(caches, mask,
+                                             jax.random.PRNGKey(seed + 2))
+    toks = serve_step.greedy_decode(cfg, params, deg, first, prompt_len, gen)
     toks_clean = serve_step.greedy_decode(cfg, params, clean, first,
                                           prompt_len, gen)
     t = np.asarray(toks)
@@ -346,8 +325,7 @@ def serve_phase(cfg: ModelConfig, *, batch: int = 4, prompt_len: int = 48,
             "wire_rows_lost": int(n_rows - int(mask.sum())),
             "tokens_row0": t[0].tolist(),
             "agree_with_clean_kv": float(np.mean(t == np.asarray(toks_clean))),
-            "prefill_s_incl_compile": prefill_s, "degrade_s": degrade_s,
-            "decode_s_incl_compile": decode_s, "ok": bool(ok)}
+            "ok": bool(ok)}
 
 
 def multichip_phase(cfg: ModelConfig, *, seq: int, batch: int,
@@ -376,10 +354,8 @@ def multichip_phase(cfg: ModelConfig, *, seq: int, batch: int,
             specs = rules.batch_specs(mesh, host)
             b = {k: jax.device_put(v, jax.sharding.NamedSharding(
                 mesh, specs[k])) for k, v in host.items()}
-        (st1, m1), s = _timed(step, state, b, jax.random.fold_in(key, 1),
-                              jnp.float32(0.0))
+        st1, m1 = step(state, b, jax.random.fold_in(key, 1), jnp.float32(0.0))
         snap = _snapshot(st1, m1)
-        snap["first_step_s_incl_compile"] = s
         del st1, state
         gc.collect()
         return snap
@@ -397,8 +373,6 @@ def multichip_phase(cfg: ModelConfig, *, seq: int, batch: int,
         cmp_ = agreement(snaps[(name, "exact")], snaps[(name, coded)],
                          OPT.lr)
         out[f"{name}_{coded}_vs_exact"] = cmp_
-        out[f"{name}_step_s_incl_compile"] = {
-            m: snaps[(name, m)]["first_step_s_incl_compile"] for m in modes}
         ok = ok and cmp_["match"]
     out["peak_bytes_in_use_per_device"] = _peak_bytes(jax.devices()[:4])
     one = one_step(None, "exact")
@@ -465,13 +439,11 @@ def main(argv=None) -> int:
                       "n_devices": len(jax.devices())}), flush=True)
     failed = []
     for name, run in _phases(args.chips, args.smoke):
-        t0 = time.perf_counter()
         try:
             res = run()
         except Exception:   # noqa: BLE001 - report and go on to the next
             traceback.print_exc()
             res = {"ok": False, "error": traceback.format_exc(limit=3)}
-        res["phase_wall_s"] = time.perf_counter() - t0
         print(json.dumps({"phase": name, **res}, default=float), flush=True)
         if not res.get("ok"):
             failed.append(name)

@@ -60,9 +60,13 @@ def global_norm(tree: Params) -> jax.Array:
                         for l in jax.tree.leaves(tree)))
 
 
+@jax.named_scope("optimizer")
 def apply_updates(params: Params, grads: Params, state: Dict[str, Any],
                   cfg: OptConfig):
-    """Returns (new_params (compute dtype), new_state, metrics)."""
+    """Returns (new_params (compute dtype), new_state, metrics).
+
+    Runs under the ``optimizer`` name scope: clip norm, moments, master
+    update and the cast back all carry it in their HLO metadata."""
     count = state["count"] + 1
     gnorm = global_norm(grads)
     scale = jnp.minimum(1.0, cfg.clip_norm / jnp.maximum(gnorm, 1e-9))

@@ -49,6 +49,15 @@ under plain GSPMD.  The factory precomputes the per-leaf Hadamard
 coding plans from the static param shapes (block counts padded to the
 TP degree).
 
+Each layer of the step runs under one ``jax.named_scope``, which XLA
+keeps in every HLO op's ``op_name`` metadata, so a device trace can be
+split by layer: ``fwd_bwd`` (value_and_grad of the loss, microbatch
+accumulation included; backward ops carry ``transpose(jvp(...))``
+below it), ``grad_sync`` (every lossy or coded sync, with children
+``encode``, ``mask``, ``decode`` and ``psum`` where a collective runs;
+the exact GSPMD path has no op of its own) and ``optimizer``
+(``adamw.apply_updates``).  Scopes change op metadata only.
+
 The ``drop_rate`` step input is where the transport engine couples in:
 ``Trainer`` walks an engine-derived ``DropSchedule`` (or the standalone
 straggler model) and feeds one scalar per step.
@@ -117,11 +126,17 @@ class CelerisConfig:
                 else CollectiveMode.EXACT)
 
 
-def _sync_grads_exact(grads, dp):
-    # reduce in f32: uniform collective dtype (XLA CPU's AllReducePromotion
-    # crashes on mixed-dtype variadic all-reduce) and better accumulation.
-    sync = lambda g: jax.lax.pmean(g.astype(jnp.float32), dp).astype(g.dtype)
-    return jax.tree.map(sync, grads), jnp.float32(1.0)
+def _pmean32(g, axes):
+    """Exact mean over ``axes``, reduced in f32 and cast back: a uniform
+    collective dtype (XLA CPU's AllReducePromotion crashes on mixed-dtype
+    variadic all-reduce) and better accumulation."""
+    with jax.named_scope("psum"):
+        return jax.lax.pmean(g.astype(jnp.float32), axes).astype(g.dtype)
+
+
+def _psum(x, axes):
+    with jax.named_scope("psum"):
+        return jax.lax.psum(x, axes)
 
 
 def _dp_size(dp, mesh):
@@ -155,37 +170,44 @@ def _sync_grads_celeris(grads, dp, plans, key, drop_rate, celeris, mesh,
     n_lossy = _dp_size(lossy_axes, mesh)
     out, fracs = [], []
     for i, (g, plan) in enumerate(zip(flat, plans)):
-        if plan is None:   # small leaf: exact sync (f32, see exact path)
-            out.append(jax.lax.pmean(g.astype(jnp.float32), dp)
-                       .astype(g.dtype))
+        if plan is None:   # small leaf: exact sync
+            out.append(_pmean32(g, dp))
             continue
         if exact_axes:     # intra-pod reduction: exact, f32
-            g = jax.lax.pmean(g.astype(jnp.float32), exact_axes)
-        signs = coding.rademacher_nd(jax.random.fold_in(key, 2 * i), plan)
-        tiles = coding.encode_nd(g, signs, plan)
-        mask = _leaf_mask(key, i, peer_id, plan.n_rot, drop_rate)
-        contrib = tiles * mask[None, :, None].astype(tiles.dtype)
+            with jax.named_scope("psum"):
+                g = jax.lax.pmean(g.astype(jnp.float32), exact_axes)
+        with jax.named_scope("encode"):
+            signs = coding.rademacher_nd(jax.random.fold_in(key, 2 * i),
+                                         plan)
+            tiles = coding.encode_nd(g, signs, plan)
+        with jax.named_scope("mask"):
+            mask = _leaf_mask(key, i, peer_id, plan.n_rot, drop_rate)
+            contrib = tiles * mask[None, :, None].astype(tiles.dtype)
         if celeris.quantize_wire:
             # shared scale per wire row: psum-max of |contrib| so every
             # peer's int8 payload lives on one grid (tiny f32 pre-pass:
             # n_rot scalars per leaf)
-            absmax = jax.lax.pmax(
-                jnp.max(jnp.abs(contrib), axis=(0, 2)), lossy_axes)
-            scale = jnp.where(absmax > 0, absmax / 127.0, 1.0)
-            noise = jax.random.uniform(
-                jax.random.fold_in(key, 3 * i + 2), contrib.shape)
-            q = jnp.clip(jnp.floor(contrib / scale[None, :, None] + noise),
-                         -127, 127).astype(jnp.int16)
-            tiles_sum = (jax.lax.psum(q, lossy_axes).astype(jnp.float32)
+            with jax.named_scope("psum"):
+                absmax = jax.lax.pmax(
+                    jnp.max(jnp.abs(contrib), axis=(0, 2)), lossy_axes)
+            with jax.named_scope("encode"):
+                scale = jnp.where(absmax > 0, absmax / 127.0, 1.0)
+                noise = jax.random.uniform(
+                    jax.random.fold_in(key, 3 * i + 2), contrib.shape)
+                q = jnp.clip(jnp.floor(contrib / scale[None, :, None]
+                                       + noise),
+                             -127, 127).astype(jnp.int16)
+            tiles_sum = (_psum(q, lossy_axes).astype(jnp.float32)
                          * scale[None, :, None])
         else:
             contrib = contrib.astype(jnp.dtype(celeris.wire_dtype))
-            tiles_sum = jax.lax.psum(contrib, lossy_axes).astype(jnp.float32)
-        counts = jax.lax.psum(mask.astype(jnp.float32), lossy_axes)
-        est = coding.decode_nd(tiles_sum, counts, signs, plan,
-                               total_peers=n_lossy)
-        out.append((est / n_lossy).astype(g.dtype))
-        fracs.append(jnp.sum(counts) / (n_lossy * plan.n_rot))
+            tiles_sum = _psum(contrib, lossy_axes).astype(jnp.float32)
+        counts = _psum(mask.astype(jnp.float32), lossy_axes)
+        with jax.named_scope("decode"):
+            est = coding.decode_nd(tiles_sum, counts, signs, plan,
+                                   total_peers=n_lossy)
+            out.append((est / n_lossy).astype(g.dtype))
+            fracs.append(jnp.sum(counts) / (n_lossy * plan.n_rot))
     frac = jnp.stack(fracs).mean() if fracs else jnp.float32(1.0)
     return jax.tree_util.tree_unflatten(treedef, out), frac
 
@@ -201,17 +223,19 @@ def _sync_grads_plain_island(grads, dp, plans, key, drop_rate, mesh,
     out, fracs = [], []
     for i, (g, plan) in enumerate(zip(flat, plans)):
         if plan is None:
-            out.append(jax.lax.pmean(g.astype(jnp.float32), dp)
-                       .astype(g.dtype))
+            out.append(_pmean32(g, dp))
             continue
-        tiles = coding.to_tiles_nd(g.astype(jnp.float32), plan)
-        mask = _leaf_mask(key, i, peer_id, plan.n_rot, drop_rate)
-        masked = tiles * mask[None, :, None].astype(tiles.dtype)
-        tiles_sum = jax.lax.psum(masked, dp)
-        counts = jax.lax.psum(mask.astype(jnp.float32), dp)
-        out.append(coding.from_tiles_nd(tiles_sum / n_dp, plan)
-                   .astype(g.dtype))
-        fracs.append(jnp.sum(counts) / (n_dp * plan.n_rot))
+        with jax.named_scope("encode"):
+            tiles = coding.to_tiles_nd(g.astype(jnp.float32), plan)
+        with jax.named_scope("mask"):
+            mask = _leaf_mask(key, i, peer_id, plan.n_rot, drop_rate)
+            masked = tiles * mask[None, :, None].astype(tiles.dtype)
+        tiles_sum = _psum(masked, dp)
+        counts = _psum(mask.astype(jnp.float32), dp)
+        with jax.named_scope("decode"):
+            out.append(coding.from_tiles_nd(tiles_sum / n_dp, plan)
+                       .astype(g.dtype))
+            fracs.append(jnp.sum(counts) / (n_dp * plan.n_rot))
     frac = jnp.stack(fracs).mean() if fracs else jnp.float32(1.0)
     return jax.tree_util.tree_unflatten(treedef, out), frac
 
@@ -231,11 +255,41 @@ def _mask_grads_plain(grads, plans, key, drop_rate):
         if plan is None:
             out.append(g)
             continue
-        mask = _leaf_mask(key, i, 0, plan.n_rot, drop_rate)
-        tiles = coding.to_tiles_nd(g.astype(jnp.float32), plan)
-        masked = tiles * mask[None, :, None].astype(tiles.dtype)
-        out.append(coding.from_tiles_nd(masked, plan).astype(g.dtype))
-        fracs.append(mask.mean())
+        with jax.named_scope("mask"):
+            mask = _leaf_mask(key, i, 0, plan.n_rot, drop_rate)
+        with jax.named_scope("encode"):
+            tiles = coding.to_tiles_nd(g.astype(jnp.float32), plan)
+        with jax.named_scope("mask"):
+            masked = tiles * mask[None, :, None].astype(tiles.dtype)
+        with jax.named_scope("decode"):
+            out.append(coding.from_tiles_nd(masked, plan).astype(g.dtype))
+            fracs.append(mask.mean())
+    frac = jnp.stack(fracs).mean() if fracs else jnp.float32(1.0)
+    return jax.tree_util.tree_unflatten(treedef, out), frac
+
+
+def _emulate_coded_one(grads, plans, key, drop_rate):
+    """Coded sync on one node: single-peer encode -> receiver-window
+    mask -> unbiased decode of each coded leaf."""
+    flat, treedef = jax.tree_util.tree_flatten(grads)
+    out, fracs = [], []
+    for i, (g, plan) in enumerate(zip(flat, plans)):
+        if plan is None:
+            out.append(g)
+            continue
+        with jax.named_scope("mask"):
+            mask = _leaf_mask(key, i, 0, plan.n_rot, drop_rate)
+        with jax.named_scope("encode"):
+            signs = coding.rademacher_nd(jax.random.fold_in(key, 2 * i),
+                                         plan)
+            tiles = coding.encode_nd(g, signs, plan)
+        with jax.named_scope("mask"):
+            masked = tiles * mask[None, :, None].astype(tiles.dtype)
+        with jax.named_scope("decode"):
+            est = coding.decode_nd(masked, mask.astype(jnp.float32), signs,
+                                   plan, total_peers=1)
+            out.append(est.astype(g.dtype))
+            fracs.append(mask.mean())
     frac = jnp.stack(fracs).mean() if fracs else jnp.float32(1.0)
     return jax.tree_util.tree_unflatten(treedef, out), frac
 
@@ -285,6 +339,7 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
             f"DCI phase must be the lowest (cut-first) priority class; "
             f"HierarchicalSchedule.PRIORITY is {prio}")
 
+    @jax.named_scope("fwd_bwd")
     def _grads_one(params, batch, key, drop_rate):
         # the MoE all-to-all coin expects one scalar; hierarchical mode
         # feeds a (2,) [intra, cross] vector — expert exchange crosses
@@ -300,41 +355,48 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
 
     def _accum_grads(params, batch, key, drop_rate):
         if microbatches > 1:
-            mb = jax.tree.map(
-                lambda a: a.reshape((microbatches,
-                                     a.shape[0] // microbatches)
-                                    + a.shape[1:]), batch)
+            with jax.named_scope("fwd_bwd"):
+                return _accum_microbatches(params, batch, key, drop_rate)
+        (loss, (nll, aux)), grads = _grads_one(params, batch, key, drop_rate)
+        return loss, nll, aux, grads
 
-            def mb_step(carry, xs):
-                gacc, lacc, nacc, aacc = carry
-                b_i, i = xs
-                (l, (n, a_)), g = _grads_one(
-                    params, b_i, jax.random.fold_in(key, i), drop_rate)
-                gacc = jax.tree.map(
-                    lambda x, y: x + y.astype(jnp.float32), gacc, g)
-                return (gacc, lacc + l, nacc + n, aacc + a_), None
+    def _accum_microbatches(params, batch, key, drop_rate):
+        mb = jax.tree.map(
+            lambda a: a.reshape((microbatches,
+                                 a.shape[0] // microbatches)
+                                + a.shape[1:]), batch)
 
-            g0 = jax.tree.map(
-                lambda p_: jnp.zeros(p_.shape, jnp.float32), params)
-            z = jnp.zeros((), jnp.float32)
-            (gsum, loss, nll, aux), _ = jax.lax.scan(
-                mb_step, (g0, z, z, z), (mb, jnp.arange(microbatches)))
-            inv = 1.0 / microbatches
-            grads = jax.tree.map(
-                lambda g_, p_: (g_ * inv).astype(p_.dtype), gsum, params)
-            loss, nll, aux = loss * inv, nll * inv, aux * inv
-        else:
-            (loss, (nll, aux)), grads = _grads_one(params, batch, key,
-                                                   drop_rate)
+        def mb_step(carry, xs):
+            gacc, lacc, nacc, aacc = carry
+            b_i, i = xs
+            (l, (n, a_)), g = _grads_one(
+                params, b_i, jax.random.fold_in(key, i), drop_rate)
+            gacc = jax.tree.map(
+                lambda x, y: x + y.astype(jnp.float32), gacc, g)
+            return (gacc, lacc + l, nacc + n, aacc + a_), None
+
+        g0 = jax.tree.map(
+            lambda p_: jnp.zeros(p_.shape, jnp.float32), params)
+        z = jnp.zeros((), jnp.float32)
+        (gsum, loss, nll, aux), _ = jax.lax.scan(
+            mb_step, (g0, z, z, z), (mb, jnp.arange(microbatches)))
+        inv = 1.0 / microbatches
+        grads = jax.tree.map(
+            lambda g_, p_: (g_ * inv).astype(p_.dtype), gsum, params)
+        loss, nll, aux = loss * inv, nll * inv, aux * inv
         return loss, nll, aux, grads
 
     pod_axes = tuple(a for a in dp if a == shd.POD_AXIS)
     data_axes = tuple(a for a in dp if a != shd.POD_AXIS)
 
     def island(params, batch, key, drop_rate, plans, peer):
-        peer_id = peer[0]     # this shard's index along the dp axes
         loss, nll, aux, grads = _accum_grads(params, batch, key, drop_rate)
+        return _island_sync(loss, nll, aux, grads, key, drop_rate, plans,
+                            peer)
 
+    @jax.named_scope("grad_sync")
+    def _island_sync(loss, nll, aux, grads, key, drop_rate, plans, peer):
+        peer_id = peer[0]     # this shard's index along the dp axes
         if mode is CollectiveMode.HIERARCHICAL:
             # intra-pod exact, cross-pod coded-lossy: every data shard
             # in a pod shares the pod's wire, so the mask peer is the
@@ -369,9 +431,10 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
             grads, frac = _sync_grads_celeris(grads, dp, plans, key,
                                               drop_rate, celeris, mesh,
                                               peer_id)
-        loss = jax.lax.pmean(loss, dp)
-        nll = jax.lax.pmean(nll, dp)
-        aux = jax.lax.pmean(aux, dp)
+        with jax.named_scope("psum"):
+            loss = jax.lax.pmean(loss, dp)
+            nll = jax.lax.pmean(nll, dp)
+            aux = jax.lax.pmean(aux, dp)
         return loss, nll, aux, grads, frac
 
     def train_step(state, batch, key, drop_rate):
@@ -425,44 +488,24 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
                                                  drop_rate)
             frac = jnp.float32(1.0)
         else:   # single-device / no-dp path
-            lossy_ctx = M.LossyCtx(enabled=celeris.lossy_moe, key=key,
-                                   drop_rate=jnp.reshape(drop_rate,
-                                                         (-1,))[-1])
-            (loss, (nll, aux)), grads = jax.value_and_grad(
-                lambda p: M.lm_loss(p, cfg, batch, lossy=lossy_ctx),
-                has_aux=True)(params)
-            if mode.coded:
-                # no dp axis to lose data across, but the node itself
-                # still receives only (1 - drop_rate) of each collective
-                # payload inside its bounded window: emulate via
-                # single-peer encode -> mask -> unbiased decode (this is
-                # what the Fig.-1 loss-tolerance benchmark measures).
-                # Hierarchical mode loses only on the cross-pod axis, so
-                # its emulation rate is the vector's cross component.
-                rate = jnp.reshape(drop_rate, (-1,))[-1]
-                flat, tdef = jax.tree_util.tree_flatten(grads)
-                out, fr = [], []
-                for i, (g, plan) in enumerate(zip(flat, plans)):
-                    if plan is None:
-                        out.append(g)
-                        continue
-                    mask = _leaf_mask(key, i, 0, plan.n_rot, rate)
-                    signs = coding.rademacher_nd(
-                        jax.random.fold_in(key, 2 * i), plan)
-                    tiles = coding.encode_nd(g, signs, plan)
-                    est = coding.decode_nd(
-                        tiles * mask[None, :, None].astype(tiles.dtype),
-                        mask.astype(jnp.float32), signs, plan,
-                        total_peers=1)
-                    out.append(est.astype(g.dtype))
-                    fr.append(mask.mean())
-                grads = jax.tree_util.tree_unflatten(tdef, out)
-                frac = jnp.stack(fr).mean() if fr else jnp.float32(1.0)
-            elif mode is CollectiveMode.LOSSY:
-                grads, frac = _mask_grads_plain(grads, plans, key,
-                                                drop_rate)
-            else:
-                frac = jnp.float32(1.0)
+            (loss, (nll, aux)), grads = _grads_one(params, batch, key,
+                                                   drop_rate)
+            with jax.named_scope("grad_sync"):
+                if mode.coded:
+                    # no dp axis to lose data across, but the node itself
+                    # still receives only (1 - drop_rate) of each
+                    # collective payload inside its bounded window: the
+                    # single-peer emulation (what the Fig.-1
+                    # loss-tolerance benchmark measures).  Hierarchical
+                    # mode loses only on the cross-pod axis, so its
+                    # emulation rate is the vector's cross component.
+                    grads, frac = _emulate_coded_one(
+                        grads, plans, key, jnp.reshape(drop_rate, (-1,))[-1])
+                elif mode is CollectiveMode.LOSSY:
+                    grads, frac = _mask_grads_plain(grads, plans, key,
+                                                    drop_rate)
+                else:
+                    frac = jnp.float32(1.0)
 
         new_params, new_opt, om = adamw.apply_updates(
             params, grads, state["opt"], opt_cfg)

@@ -62,6 +62,13 @@ class StragglerModel:
         return float(np.clip(p_late, 0.0, 0.5))
 
 
+def _span(name: str, step: int):
+    """A profiler span of the host loop, tagged with its step: all spans
+    of one step share ``step``.  With no profiler active it costs about
+    a microsecond."""
+    return jax.profiler.TraceAnnotation(name, step=step)
+
+
 class Trainer:
     def __init__(self, cfg: ModelConfig, *,
                  data_cfg: data_pipe.DataConfig,
@@ -132,7 +139,24 @@ class Trainer:
         history: Dict[str, list] = {"loss": [], "nll": [], "recv_frac": [],
                                     "drop_rate": [], "timeout": []}
         for step in range(self.start_step, self.start_step + n_steps):
+            with _span("trainer.step", step):
+                self._run_step(step, history, on_metrics)
+            if simulate_fault_at is not None and step == simulate_fault_at:
+                if self._pending_ckpt is not None:
+                    self._pending_ckpt.result()
+                raise RuntimeError(f"simulated node failure at step {step}")
+
+        if self._pending_ckpt is not None:
+            self._pending_ckpt.result()
+        self.start_step += n_steps
+        return history
+
+    def _run_step(self, step: int, history: Dict[str, list],
+                  on_metrics: Optional[Callable[[int, Dict], None]]):
+        """One step of ``run``, each host phase under its own span."""
+        with _span("trainer.batch", step):
             batch = self._put_batch(step)
+        with _span("trainer.drop", step):
             if self.celeris.collective_mode().lossy or self.celeris.lossy_moe:
                 # scalar for the flat modes; a (2,) [intra, cross] axis
                 # vector when a HierStragglerModel drives hierarchical
@@ -142,13 +166,18 @@ class Trainer:
                                                 self.rng)
             else:
                 drop = 0.0
+            # wall_s runs from here to the metrics read, as it always has
             t0 = time.perf_counter()
+            drop_rate = jnp.asarray(drop, dtype=jnp.float32)
+        with _span("trainer.dispatch", step):
             self.state, metrics = self.step_fn(
                 self.state, batch, jax.random.fold_in(self.key, step),
-                jnp.asarray(drop, dtype=jnp.float32))
+                drop_rate)
+        with _span("trainer.read_metrics", step):
             metrics = {k: float(v) for k, v in metrics.items()}
-            wall = time.perf_counter() - t0
+        wall = time.perf_counter() - t0
 
+        with _span("trainer.controller", step):
             # --- Celeris software stack: bounded-window adaptation.
             # duration is the emulated step latency: stragglers that got
             # dropped no longer extend it (min with the timeout).
@@ -161,29 +190,20 @@ class Trainer:
                 [local * (1 + self.rng.normal(0, 0.01)) for _ in range(8)])
             self.controller.adopt(agreed)
 
-            history["loss"].append(metrics["loss"])
-            history["nll"].append(metrics["nll"])
-            history["recv_frac"].append(metrics["recv_frac"])
-            history["drop_rate"].append(drop)
-            history["timeout"].append(self.controller.timeout)
-            if on_metrics:
-                on_metrics(step, {**metrics, "wall_s": wall,
-                                  "drop_rate": drop})
+        history["loss"].append(metrics["loss"])
+        history["nll"].append(metrics["nll"])
+        history["recv_frac"].append(metrics["recv_frac"])
+        history["drop_rate"].append(drop)
+        history["timeout"].append(self.controller.timeout)
+        if on_metrics:
+            on_metrics(step, {**metrics, "wall_s": wall,
+                              "drop_rate": drop})
 
-            if self.ckpt_dir and (step + 1) % self.ckpt_every == 0:
+        if self.ckpt_dir and (step + 1) % self.ckpt_every == 0:
+            with _span("trainer.checkpoint", step):
                 if self._pending_ckpt is not None:
                     self._pending_ckpt.result()
                 self._pending_ckpt = ckpt.save_async(
                     self.ckpt_dir, step + 1, self.state,
                     extra={"timeout": self.controller.timeout,
                            "arch": self.cfg.name})
-
-            if simulate_fault_at is not None and step == simulate_fault_at:
-                if self._pending_ckpt is not None:
-                    self._pending_ckpt.result()
-                raise RuntimeError(f"simulated node failure at step {step}")
-
-        if self._pending_ckpt is not None:
-            self._pending_ckpt.result()
-        self.start_step += n_steps
-        return history
