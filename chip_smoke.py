@@ -14,11 +14,12 @@ point a user calls:
   151936), seq 512 x global batch 8, five steps in ``exact`` and five in
   ``lossy_hadamard`` mode (the loss on the step-0 batch must fall),
   plus a zero-drop step from the same state in both modes, whose
-  results must agree (coding is the identity there);
+  results must agree (coding is the identity there); every coded leaf
+  must sync through the ``coded_roundtrip`` kernel (``coded_sync_paths``);
 - ``engine``: a fig6-style 1024-node, 4-pod per-rail cell with the
   per-phase window, all four designs, through ``sweep(backend="jax")``,
   checked against ``backend="numpy"`` (rtol 1e-5);
-- ``kernels``: the four Pallas kernels, compiled, against
+- ``kernels``: the five Pallas kernels, compiled, against
   ``repro.kernels.ref``;
 - ``serve``: prefill plus 16 greedy tokens from KV caches shipped
   through the coded lossy wire at delivered fraction 0.9.
@@ -187,6 +188,11 @@ def train_phase(cfg: ModelConfig, *, seq: int, batch: int, steps: int = 5,
                      "finite_and_falling": falls,
                      "step0_near_ln_vocab": near,
                      "peak_bytes_in_use": _peak_bytes()[0]}
+        if mode == "lossy_hadamard":
+            # one device: every coded leaf of this model takes the kernel
+            paths = ts.coded_sync_paths(tr.state["params"], tr.celeris, None)
+            res[mode]["coded_sync_paths"] = paths
+            ok = ok and paths["fused"] > 0 and paths["xla"] == 0
         ok = ok and falls and near
         del tr
         gc.collect()
@@ -233,7 +239,7 @@ def engine_phase(n_nodes: int, *, n_pods: int = 4, n_rounds: int = 20,
 
 
 def kernels_phase(shapes=((256, 4096), (8192, 4096)), seed: int = 0) -> dict:
-    """The four Pallas kernels through ``repro.kernels.ops`` against the
+    """The five Pallas kernels through ``repro.kernels.ops`` against the
     ``ref`` oracles; off the CPU they must lower to ``tpu_custom_call``."""
     compiled = jax.default_backend() != "cpu"
     out = {"compiled": compiled, "shapes": [list(s) for s in shapes]}
@@ -252,6 +258,9 @@ def kernels_phase(shapes=((256, 4096), (8192, 4096)), seed: int = 0) -> dict:
         q_ref, s_ref = jax.jit(ref.quantize_int8)(x, noise)
         qr_ref, sr_ref = jax.jit(ref.quantize_int8)(rot_ref, noise)
         u_ref = jax.jit(lambda y, c: ref.masked_unbias(y, c, 4))(x, counts)
+        mask = jax.random.uniform(jax.random.fold_in(key, 4), (n,)) >= 0.1
+        colscale = mask * (n / jnp.sum(mask))
+        rt_ref = jax.jit(ref.coded_roundtrip)(x, signs, colscale)
         calls = {
             "fwht": (lambda a, s: ops.fwht(a, signs=s, scale=scale),
                      (x, signs)),
@@ -260,6 +269,7 @@ def kernels_phase(shapes=((256, 4096), (8192, 4096)), seed: int = 0) -> dict:
             "quantize_int8": (ops.quantize_int8, (x, noise)),
             "masked_unbias": (lambda y, c: ops.masked_unbias(y, c, total=4),
                               (x, counts)),
+            "coded_roundtrip": (ops.coded_roundtrip, (x, signs, colscale)),
         }
         for name, (fn, args) in calls.items():
             jf = jax.jit(fn)
@@ -271,6 +281,9 @@ def kernels_phase(shapes=((256, 4096), (8192, 4096)), seed: int = 0) -> dict:
             elif name == "masked_unbias":
                 err = float(jnp.max(jnp.abs(got - u_ref)))
                 good = err <= 1e-6 * float(jnp.max(jnp.abs(u_ref)))
+            elif name == "coded_roundtrip":
+                err = float(jnp.max(jnp.abs(got - rt_ref)))
+                good = err <= 1e-5 * float(jnp.max(jnp.abs(rt_ref)))
             else:
                 # int8 codes may differ by one where the kernel's f32
                 # arithmetic lands on the other side of a rounding edge;

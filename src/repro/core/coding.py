@@ -352,3 +352,26 @@ def decode_nd(tiles_sum: jax.Array, counts: jax.Array, signs: jax.Array,
     est = est * jnp.where(k > 0, plan.n_rot / jnp.maximum(k, 1), 0.0)
     est = _fwht_axis1(est) * (plan.n_rot ** -0.5) * signs[None, :, None]
     return _from_tiles(est, plan)
+
+
+def one_peer_colscale(mask: jax.Array, plan: NdPlan) -> jax.Array:
+    """decode_nd's two unbias stages for one peer as one (n_rot,) f32
+    vector: ``mask[j] * n_rot / k``, 0 everywhere when ``k == 0``."""
+    k = jnp.sum(mask)
+    return mask.astype(jnp.float32) * jnp.where(
+        k > 0, plan.n_rot / jnp.maximum(k, 1), 0.0)
+
+
+def roundtrip_nd(g: jax.Array, signs: jax.Array, colscale: jax.Array,
+                 plan: NdPlan) -> jax.Array:
+    """One peer's ``decode_nd(encode_nd(g) * mask, ...)`` of a leaf whose
+    tiles are flat rows (``plan.sharded_dim is None``, ``n_rot >= 128``)
+    as one Pallas kernel (``ops.coded_roundtrip``), in the leaf's dtype;
+    ``colscale`` is :func:`one_peer_colscale` of the mask."""
+    rows = ops.coded_roundtrip(_to_tiles(g, plan)[..., 0], signs, colscale)
+    # The barrier keeps the relayout back to the leaf's shape here, in
+    # the leaf's dtype.  Without it XLA sinks the reshape into the
+    # consumers: AdamW then updates its f32 moments in the (tiles,
+    # n_rot) layout and relayouts those, twice the bytes (+20 ms a step
+    # for qwen2-0.5b on a v5e).
+    return jax.lax.optimization_barrier(_from_tiles(rows[..., None], plan))

@@ -75,6 +75,18 @@ def fwht_quantize(x: jax.Array, noise: jax.Array, *,
     return q[:rows0], s[:rows0]
 
 
+def coded_roundtrip(x: jax.Array, signs: jax.Array, colscale: jax.Array, *,
+                    block_rows: int | None = None) -> jax.Array:
+    """One peer's coded sync of (rows, n) tiles in one kernel: rotate
+    (``signs`` (n,), normalised FWHT), scale column j by ``colscale[j]``
+    (n,), rotate back, in x's dtype.  ``n`` is a power of two >= 128.
+    Rows need no padding: the kernel's grid takes a partial last block.
+    """
+    return _fwht.coded_roundtrip_pallas(x, signs, colscale,
+                                        block_rows=block_rows,
+                                        interpret=_interpret())
+
+
 def quantize_int8(x: jax.Array, noise: jax.Array, *, use_pallas: bool = True,
                   block_rows: int = 128):
     if not use_pallas:

@@ -66,3 +66,14 @@ def masked_unbias(y_sum: jax.Array, counts: jax.Array, total: int) -> jax.Array:
         counts = counts[..., None]
     safe = jnp.maximum(counts, 1)
     return jnp.where(counts > 0, y_sum * (total / safe), 0.0)
+
+
+def coded_roundtrip(x: jax.Array, signs: jax.Array,
+                    colscale: jax.Array) -> jax.Array:
+    """One peer's coded sync of (rows, n) tiles: rotate each row
+    (``signs``, then the normalised FWHT), scale column j by
+    ``colscale[j]``, rotate back; in f32, cast to ``x``'s dtype."""
+    n = x.shape[-1]
+    y = fwht(x.astype(jnp.float32) * signs) * n ** -0.5
+    y = fwht(y * colscale) * n ** -0.5
+    return (y * signs).astype(x.dtype)

@@ -55,7 +55,10 @@ split by layer: ``fwd_bwd`` (value_and_grad of the loss, microbatch
 accumulation included; backward ops carry ``transpose(jvp(...))``
 below it), ``grad_sync`` (every lossy or coded sync, with children
 ``encode``, ``mask``, ``decode`` and ``psum`` where a collective runs;
-the exact GSPMD path has no op of its own) and ``optimizer``
+on one device a coded leaf's whole rotate-drop-unbias-rotate-back is
+one Pallas kernel under ``roundtrip``, with the sign draw under
+``encode`` and the mask under ``mask``; the exact GSPMD path has no op
+of its own) and ``optimizer``
 (``adamw.apply_updates``).  Scopes change op metadata only.
 
 The ``drop_rate`` step input is where the transport engine couples in:
@@ -268,9 +271,17 @@ def _mask_grads_plain(grads, plans, key, drop_rate):
     return jax.tree_util.tree_unflatten(treedef, out), frac
 
 
+def _fused(plan) -> bool:
+    """Whether a coded leaf's one-device sync is one Pallas kernel
+    (``coding.roundtrip_nd``): its tiles are flat rows at least one lane
+    (128) wide.  Other coded leaves take ``encode_nd``/``decode_nd``."""
+    return plan.sharded_dim is None and plan.n_rot >= 128
+
+
 def _emulate_coded_one(grads, plans, key, drop_rate):
     """Coded sync on one node: single-peer encode -> receiver-window
-    mask -> unbiased decode of each coded leaf."""
+    mask -> unbiased decode of each coded leaf, as one Pallas kernel
+    where :func:`_fused` holds, else through XLA."""
     flat, treedef = jax.tree_util.tree_flatten(grads)
     out, fracs = [], []
     for i, (g, plan) in enumerate(zip(flat, plans)):
@@ -282,6 +293,14 @@ def _emulate_coded_one(grads, plans, key, drop_rate):
         with jax.named_scope("encode"):
             signs = coding.rademacher_nd(jax.random.fold_in(key, 2 * i),
                                          plan)
+        fracs.append(mask.mean())
+        if _fused(plan):
+            with jax.named_scope("mask"):
+                colscale = coding.one_peer_colscale(mask, plan)
+            with jax.named_scope("roundtrip"):
+                out.append(coding.roundtrip_nd(g, signs, colscale, plan))
+            continue
+        with jax.named_scope("encode"):
             tiles = coding.encode_nd(g, signs, plan)
         with jax.named_scope("mask"):
             masked = tiles * mask[None, :, None].astype(tiles.dtype)
@@ -289,9 +308,41 @@ def _emulate_coded_one(grads, plans, key, drop_rate):
             est = coding.decode_nd(masked, mask.astype(jnp.float32), signs,
                                    plan, total_peers=1)
             out.append(est.astype(g.dtype))
-            fracs.append(mask.mean())
     frac = jnp.stack(fracs).mean() if fracs else jnp.float32(1.0)
     return jax.tree_util.tree_unflatten(treedef, out), frac
+
+
+def _leaf_plans(params, celeris: CelerisConfig, mesh) -> list:
+    """Each leaf's ND coding plan (rotation along its unsharded axes),
+    or None for a leaf small enough to sync exactly."""
+    flat = jax.tree_util.tree_leaves(params)
+    if mesh is not None:
+        flat_specs = jax.tree_util.tree_leaves(
+            rules.param_specs(params, mesh),
+            is_leaf=lambda x: isinstance(x, P))
+    else:
+        flat_specs = [P()] * len(flat)
+
+    def sharded_dim(leaf, spec):
+        for i, sname in enumerate(spec):
+            if sname == shd.MODEL_AXIS and i < leaf.ndim:
+                return i
+        return None
+
+    return [coding.plan_nd(l.shape, sharded_dim(l, sp), celeris.n_rot)
+            if l.size >= celeris.min_coded_size else None
+            for l, sp in zip(flat, flat_specs)]
+
+
+def coded_sync_paths(params, celeris: CelerisConfig, mesh) -> dict:
+    """How many coded leaves the step syncs through the one-kernel path
+    (``fused``: one device, :func:`_fused`) and how many through XLA
+    ``encode_nd``/``decode_nd`` (``xla``); ``params`` may be shapes."""
+    plans = [p for p in _leaf_plans(params, celeris, mesh) if p is not None]
+    if not celeris.collective_mode().coded:
+        return {"fused": 0, "xla": 0}
+    fused = 0 if shd.dp_axes(mesh) else sum(map(_fused, plans))
+    return {"fused": fused, "xla": len(plans) - fused}
 
 
 def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
@@ -439,23 +490,7 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
 
     def train_step(state, batch, key, drop_rate):
         params = state["params"]
-        flat = jax.tree_util.tree_leaves(params)
-        if mesh is not None:
-            pspecs = rules.param_specs(params, mesh)
-            flat_specs = jax.tree_util.tree_leaves(
-                pspecs, is_leaf=lambda x: isinstance(x, P))
-        else:
-            flat_specs = [P()] * len(flat)
-
-        def sharded_dim(leaf, spec):
-            for i, sname in enumerate(spec):
-                if sname == shd.MODEL_AXIS and i < leaf.ndim:
-                    return i
-            return None
-
-        plans = [coding.plan_nd(l.shape, sharded_dim(l, sp), celeris.n_rot)
-                 if l.size >= celeris.min_coded_size else None
-                 for l, sp in zip(flat, flat_specs)]
+        plans = _leaf_plans(params, celeris, mesh)
 
         island_modes = {CollectiveMode.LOSSY_HADAMARD, CollectiveMode.LOSSY}
         if pod_axes:

@@ -142,3 +142,45 @@ def test_encode_quantized_roundtrip():
     tol = float(jnp.max(scales)) * np.sqrt(code.n_rot) * 1.5
     np.testing.assert_allclose(np.asarray(out), np.asarray(x), atol=tol)
     assert float(jnp.max(jnp.abs(out - x))) < 0.2
+
+
+# ------------------------------------------------ fused coded round trip
+
+@pytest.mark.parametrize("dtype,rows,n,drop", [
+    (jnp.float32, 40, 128, 0.1),      # 40 rows: 2.5 blocks of 16
+    (jnp.bfloat16, 40, 512, 0.1),
+    (jnp.bfloat16, 20, 4096, 0.1),
+    (jnp.float32, 24, 4096, 0.1),
+    (jnp.bfloat16, 40, 512, 1.0),     # every row lost: zeros
+    (jnp.float32, 40, 512, 0.0),      # none lost: the input back
+    (jnp.bfloat16, 16, 4096, 0.0),
+])
+def test_coded_roundtrip_matches_encode_mask_decode(dtype, rows, n, drop):
+    """ops.coded_roundtrip is decode_nd(encode_nd(x) * mask, mask) of one
+    peer: the same f32 arithmetic up to summation order, then the cast
+    to x's dtype (one rounding of bf16 apart at most)."""
+    from repro.core import coding
+    key = jax.random.PRNGKey(rows * n)
+    x = jax.random.normal(key, (rows, n), dtype)
+    plan = coding.plan_nd((rows * n,), None, n)
+    signs = coding.rademacher_nd(jax.random.fold_in(key, 1), plan)
+    mask = jax.random.uniform(jax.random.fold_in(key, 2), (n,)) >= drop
+    got = ops.coded_roundtrip(x, signs, coding.one_peer_colscale(mask, plan),
+                              block_rows=16)
+    tiles = coding.encode_nd(x, signs, plan)
+    want = coding.decode_nd(tiles * mask[None, :, None],
+                            mask.astype(jnp.float32), signs,
+                            plan).reshape(rows, n)
+    oracle = np.asarray(ref.coded_roundtrip(
+        x, signs, coding.one_peer_colscale(mask, plan)), np.float32)
+    assert got.dtype == dtype and got.shape == (rows, n)
+    got, want = np.asarray(got, np.float32), np.asarray(want)
+    f32 = 4e-7 * np.sqrt(n) * np.abs(want).max()
+    cast = 2.0 ** -8 * np.abs(want) if dtype == jnp.bfloat16 else 0.0
+    assert np.all(np.abs(got - want) <= f32 + cast)
+    assert np.all(np.abs(oracle - want) <= f32 + cast)
+    if drop == 1.0:
+        assert not np.any(got)
+    if drop == 0.0:
+        np.testing.assert_allclose(got, np.asarray(x, np.float32),
+                                   rtol=2.0 ** -7, atol=f32)

@@ -28,7 +28,9 @@ from repro.train import train_step as ts
 from repro.train.trainer import Trainer
 
 SCOPES = ("fwd_bwd", "grad_sync", "optimizer")
-SYNC_CHILDREN = ("encode", "mask", "decode")
+# one device: each coded leaf is one kernel under ``roundtrip``; the
+# sign draw stays under ``encode``, the mask under ``mask``
+SYNC_CHILDREN = ("encode", "mask", "roundtrip")
 HOST_PHASES = ("trainer.batch", "trainer.drop", "trainer.dispatch",
                "trainer.read_metrics", "trainer.controller")
 # ops that need no scope: they carry no data
@@ -119,6 +121,7 @@ def test_lowered_step_carries_layer_scopes(mode):
     if mode == "lossy_hadamard":
         for child in SYNC_CHILDREN:
             assert any(f"/grad_sync/{child}/" in n for n in names), child
+        assert not any("/grad_sync/decode/" in n for n in names)
     else:   # one device, exact: no sync op of its own
         assert not any("/grad_sync/" in n for n in names)
     # Outside every scope: constant tables JAX hoists out of the loss

@@ -58,6 +58,12 @@ KERNELS = {
         x, z, interpret=False),
     "masked_unbias": lambda x, z, s, n: unbias.masked_unbias_pallas(
         x, z[:, 0], total=4, interpret=False),
+    # the train step's one-chip coded sync: a leaf's rows, bf16 or f32,
+    # at the default tile (which has to fit the scoped VMEM)
+    "coded_roundtrip": lambda x, z, s, n: fwht.coded_roundtrip_pallas(
+        x.astype(jnp.bfloat16), s, z[0], interpret=False),
+    "coded_roundtrip_f32": lambda x, z, s, n: fwht.coded_roundtrip_pallas(
+        x, s, z[0], interpret=False),
 }
 CASES = [(name, 4096) for name in KERNELS] + [("fwht", 1024)]
 

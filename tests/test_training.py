@@ -126,3 +126,77 @@ def test_train_step_microbatched_matches_full():
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32),
                                    rtol=3e-2, atol=3e-4)
+
+
+def _smoke_params_and_grads():
+    from repro.models import model as M
+    from repro.train import train_step as ts
+    cfg = C.get_smoke("qwen2-0.5b")
+    src = make_source(DataConfig(vocab_size=cfg.vocab_size, seq_len=16,
+                                 global_batch=4, seed=2))
+    batch = {k: jnp.asarray(v) for k, v in src.global_batch(0).items()}
+    params = ts.init_state(jax.random.PRNGKey(0), cfg)["params"]
+    grads = jax.grad(lambda p: M.lm_loss(p, cfg, batch)[0])(params)
+    return params, grads
+
+
+def test_fused_coded_sync_matches_xla_encode_decode(monkeypatch):
+    """One device, lossy_hadamard: the one-kernel sync of each coded leaf
+    gives the gradients of the XLA encode_nd -> mask -> decode_nd path
+    from the same key and drop rate, to one bf16 rounding (f32 leaves:
+    to f32 rounding); leaves too small to code pass through."""
+    from repro.train import train_step as ts
+    params, grads = _smoke_params_and_grads()
+    # 64: coded leaves of n_rot 128 to 4096, bf16 and f32 (norm scales),
+    # padded tiles among them (bq: 576 elements in two tiles of 512)
+    plans = ts._leaf_plans(params, CelerisConfig(mode="lossy_hadamard",
+                                                 min_coded_size=64), None)
+    assert all(ts._fused(p) for p in plans if p is not None)
+    key, drop = jax.random.PRNGKey(7), jnp.float32(0.1)
+
+    def sync():
+        return jax.jit(lambda g: ts._emulate_coded_one(g, plans, key,
+                                                       drop))(grads)
+
+    got, frac = sync()
+    monkeypatch.setattr(ts, "_fused", lambda plan: False)
+    want, frac_xla = sync()
+    assert float(frac) == float(frac_xla) < 1.0
+    for g, a, b, plan in zip(jax.tree.leaves(grads), jax.tree.leaves(got),
+                             jax.tree.leaves(want), plans):
+        assert a.dtype == b.dtype == g.dtype and a.shape == g.shape
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if plan is None:
+            np.testing.assert_array_equal(a, np.asarray(g, np.float32))
+            continue
+        f32 = 4e-7 * np.sqrt(plan.n_rot) * np.abs(b).max()
+        cast = 2.0 ** -7 * np.abs(b) if g.dtype == jnp.bfloat16 else 0.0
+        assert np.all(np.abs(a - b) <= f32 + cast), plan
+
+
+@pytest.mark.parametrize("mode,min_coded_size,mesh_axes,want", [
+    ("lossy_hadamard", 65536, None, "all_fused"),
+    ("hierarchical", 65536, None, "all_fused"),
+    ("lossy_hadamard", 32, None, "narrow_xla"),   # bk, bv: n_rot 32
+    ("lossy_hadamard", 65536, ("data", "model"), "all_xla"),
+    ("exact", 65536, None, "none"),
+])
+def test_coded_sync_paths(mode, min_coded_size, mesh_axes, want):
+    """coded_sync_paths splits the coded leaves by the path the step's
+    sync takes: the kernel on one device where n_rot >= 128, XLA for
+    narrower leaves and on a dp mesh, none without coding."""
+    from repro.train import train_step as ts
+    cfg = C.get_smoke("qwen2-0.5b")
+    shapes = jax.eval_shape(lambda k: ts.init_state(k, cfg),
+                            jax.random.PRNGKey(0))["params"]
+    mesh = (jax.make_mesh((1, 1), mesh_axes) if mesh_axes else None)
+    cel = CelerisConfig(mode=mode, min_coded_size=min_coded_size)
+    got = ts.coded_sync_paths(shapes, cel, mesh)
+    plans = [p for p in ts._leaf_plans(shapes, cel, mesh) if p is not None]
+    narrow = sum(p.n_rot < 128 for p in plans)
+    expect = {"all_fused": {"fused": len(plans), "xla": 0},
+              "narrow_xla": {"fused": len(plans) - 2, "xla": 2},
+              "all_xla": {"fused": 0, "xla": len(plans)},
+              "none": {"fused": 0, "xla": 0}}[want]
+    assert got == expect and len(plans) > 2
+    assert narrow == (2 if want == "narrow_xla" else 0)
