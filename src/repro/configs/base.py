@@ -22,6 +22,10 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_z_weight: float = 1e-3   # router z-loss
     aux_weight: float = 1e-2        # load-balance aux loss
+    # routed experts are zero-padded to a multiple of this (dummy experts
+    # are unroutable): 16 lets a 16-way model axis hold them evenly
+    # (expert parallelism); 1 holds exactly the published experts
+    expert_pad_multiple: int = 16
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,6 +67,15 @@ class ModelConfig:
 
     norm_eps: float = 1e-6
     post_norm: bool = False         # gemma2: extra post-block norms
+
+    # scaled paths (Granite's muP-style multipliers).  The defaults are
+    # the plain decoder's and skip their multiply: embeddings x
+    # sqrt(d_model), attention scores x 1/sqrt(head_dim), residual
+    # branches and logits unscaled.
+    embedding_multiplier: Optional[float] = None   # None: sqrt(d_model)
+    attention_multiplier: Optional[float] = None   # None: head_dim ** -0.5
+    residual_multiplier: float = 1.0   # x + m * f(x) on every branch
+    logits_scaling: float = 1.0        # logits / s
     tie_embeddings: bool = True
     dtype: str = "bfloat16"
 

@@ -1,4 +1,6 @@
-"""Granite-3.0 MoE [hf:ibm-granite]: 40 routed experts top-8, d_expert=512."""
+"""Granite-3.0 MoE 3B-A800M [hf:ibm-granite/granite-3.0-3b-a800m-base]:
+40 routed experts top-8 (dropless), d_expert=512, and Granite's scaled
+embedding, attention, residual and logit paths."""
 from repro.configs.base import ModelConfig, MoEConfig
 
 CONFIG = ModelConfig(
@@ -7,7 +9,9 @@ CONFIG = ModelConfig(
     d_ff=512, vocab_size=49155,
     block_pattern=("moe",), mlp_type="swiglu",
     moe=MoEConfig(n_experts=40, top_k=8, d_expert=512, n_shared=0),
-    tie_embeddings=True,
+    rope_theta=10_000.0, norm_eps=1e-6, tie_embeddings=True,
+    embedding_multiplier=12.0, attention_multiplier=0.015625,
+    residual_multiplier=0.22, logits_scaling=6.0,
 )
 
 SMOKE = ModelConfig(
@@ -16,4 +20,6 @@ SMOKE = ModelConfig(
     d_ff=128, vocab_size=512,
     block_pattern=("moe",), mlp_type="swiglu",
     moe=MoEConfig(n_experts=8, top_k=2, d_expert=64, n_shared=0),
+    embedding_multiplier=12.0, attention_multiplier=1 / 32,
+    residual_multiplier=0.22, logits_scaling=6.0,
 )

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import ops as _megablox
 
 from repro.kernels import fwht as _fwht
 from repro.kernels import quantize as _quant
@@ -85,6 +86,29 @@ def coded_roundtrip(x: jax.Array, signs: jax.Array, colscale: jax.Array, *,
     return _fwht.coded_roundtrip_pallas(x, signs, colscale,
                                         block_rows=block_rows,
                                         interpret=_interpret())
+
+
+GMM_TILE = 512   # rows, contraction and output tile of the grouped matmul
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array,
+                   group_sizes: jax.Array) -> jax.Array:
+    """Rows of ``lhs`` (m, k), grouped contiguously (group g is the next
+    ``group_sizes[g]`` rows), each times its group's (k, n) matrix of
+    ``rhs`` (G, k, n): (m, n) in lhs's dtype, accumulated in float32.
+    The Pallas grouped matmul of JAX's megablox library, differentiable
+    (its backward is two more grouped matmuls); rows are padded to a
+    whole number of row tiles.  Rows past the groups' total come out
+    unspecified."""
+    m, k = lhs.shape
+    tm = min(GMM_TILE, -(-m // 8) * 8)
+    pad = -m % tm
+    if pad:
+        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+    out = _megablox.gmm(lhs, rhs, group_sizes.astype(jnp.int32), lhs.dtype,
+                        (tm, min(GMM_TILE, k), min(GMM_TILE, rhs.shape[2])),
+                        None, None, False, _interpret())
+    return out[:m]
 
 
 def quantize_int8(x: jax.Array, noise: jax.Array, *, use_pallas: bool = True,

@@ -228,6 +228,14 @@ def _cache_decode(cache: "AttnCache", k, v, index) -> "AttnCache":
 FLASH_THRESHOLD = 4 * 1024 * 1024   # s_q * s_kv above which we tile
 
 
+def attention_scale(cfg: ModelConfig, head_dim: int) -> float:
+    """What attention scores are multiplied by: the configuration's
+    ``attention_multiplier`` (Granite: 1/head_dim), else 1/sqrt(head_dim)."""
+    if cfg.attention_multiplier is not None:
+        return cfg.attention_multiplier
+    return head_dim ** -0.5
+
+
 def _flash_attention(q, k, v, *, qpos, kpos, kind: str, cfg: ModelConfig,
                      causal: bool, q_blk: int = 1024, kv_blk: int = 1024):
     """Memory-efficient attention (Rabe–Staats style, mask-aware).
@@ -243,7 +251,7 @@ def _flash_attention(q, k, v, *, qpos, kpos, kind: str, cfg: ModelConfig,
     kv_blk = min(kv_blk, skv)
     assert sq % q_blk == 0 and skv % kv_blk == 0, (sq, q_blk, skv, kv_blk)
     nq, nk = sq // q_blk, skv // kv_blk
-    scale = d ** -0.5
+    scale = attention_scale(cfg, d)
 
     qr = q.reshape(b, nq, q_blk, h, d).swapaxes(0, 1)     # (nq,B,qb,H,D)
     kr = k.reshape(b, nk, kv_blk, h, d).swapaxes(0, 1)
@@ -366,7 +374,7 @@ def attention(p: Params, cfg: ModelConfig, x: jax.Array, *,
 
     # dense path: grouped-GQA einsums against the UNREPEATED kv (a
     # materialized repeat of a 32k-token cache would cost GBs at decode)
-    scale = hd ** -0.5
+    scale = attention_scale(cfg, hd)
     qg = q.reshape(b, s, kv, rep, hd)
     logits = jnp.einsum("bqkrd,bskd->bkrqs", qg, kq,
                         preferred_element_type=jnp.float32) * scale
@@ -440,7 +448,8 @@ def init_embedding(key: jax.Array, cfg: ModelConfig) -> Params:
 
 def embed(p: Params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
     x = jnp.take(p["table"], tokens, axis=0)
-    return x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    m = cfg.embedding_multiplier
+    return x * jnp.asarray(cfg.d_model ** 0.5 if m is None else m, x.dtype)
 
 
 def unembed(p: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
@@ -448,12 +457,15 @@ def unembed(p: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
 
     Keeping (B,S,V) out of f32/replicated is what keeps the train step's
     temp memory sane at 256k vocabs — the loss does its reductions in
-    f32 without materializing a full-precision logits tensor.
+    f32 without materializing a full-precision logits tensor.  Logits
+    are divided by ``cfg.logits_scaling`` (Granite) where it is not 1.
     """
     if cfg.tie_embeddings:
         logits = x @ p["table"].T
     else:
         logits = x @ p["unembed"]
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     if cfg.logit_softcap is not None:
         logits = _softcap(logits.astype(jnp.float32),
                           cfg.logit_softcap).astype(x.dtype)

@@ -78,13 +78,27 @@ def init_block(key: jax.Array, kind: str, cfg: ModelConfig,
     return p
 
 
+def _residual(cfg: ModelConfig, h: jax.Array) -> jax.Array:
+    """A residual branch's output as it is added to the stream: times
+    ``cfg.residual_multiplier`` (Granite) where that is not 1, in float32
+    and rounded once (0.22 has no exact bf16 value)."""
+    m = cfg.residual_multiplier
+    if m == 1.0:
+        return h
+    return (h.astype(jnp.float32) * m).astype(h.dtype)
+
+
 def apply_block(p: Params, kind: str, cfg: ModelConfig, x: jax.Array, *,
                 positions, cache=None, cache_index=None, memory=None,
                 causal: bool = True, lossy: Optional[LossyCtx] = None,
-                layer_key: Optional[jax.Array] = None):
-    """Returns (x, new_cache, aux_loss)."""
+                layer_key: Optional[jax.Array] = None,
+                routes: bool = False):
+    """Returns (x, new_cache, aux_loss, stats); ``stats`` holds an MoE
+    block's counters (``moe.STATS``, and with ``routes`` its expert ids)
+    and is empty for every other block."""
     eps = cfg.norm_eps
     aux = jnp.zeros((), jnp.float32)
+    stats = {}
 
     if kind in ("global", "local", "moe"):
         h = L.seq_unpin(L.rmsnorm(p["ln1"], x, eps))
@@ -95,49 +109,52 @@ def apply_block(p: Params, kind: str, cfg: ModelConfig, x: jax.Array, *,
             cache=a_cache, cache_index=cache_index)
         if cfg.post_norm:
             h = L.rmsnorm(p["pn1"], h, eps)
-        x = x + h
+        x = x + _residual(cfg, h)
 
         if "xattn" in p and memory is not None:
             h = L.rmsnorm(p["lnx"], x, eps)
             h, _ = L.attention(p["xattn"], cfg, h, memory=memory,
                                positions=positions)
-            x = x + h
+            x = x + _residual(cfg, h)
 
         h = L.seq_unpin(L.rmsnorm(p["ln2"], x, eps))
         if kind == "moe":
-            h, aux = MOE.moe_block(
+            h, aux, stats = MOE.moe_block(
                 p["moe"], cfg, h,
                 lossy=bool(lossy and lossy.enabled),
                 key=(layer_key if lossy and lossy.enabled else None),
-                drop_rate=(lossy.drop_rate if lossy else 0.0))
+                drop_rate=(lossy.drop_rate if lossy else 0.0),
+                routes=routes)
         else:
             h = L.mlp(p["mlp"], cfg, h)
         if cfg.post_norm:
             h = L.rmsnorm(p["pn2"], h, eps)
-        x = x + h
+        x = x + _residual(cfg, h)
         new_cache = {"attn": new_attn_cache} if new_attn_cache else None
-        return x, new_cache, aux
+        return x, new_cache, aux, stats
 
     if kind == "rglru":
         h = L.seq_unpin(L.rmsnorm(p["ln1"], x, eps))
         h, new_rg = RG.rglru_block(p["rglru"], cfg, h,
                                    cache=cache.get("rglru") if cache else None)
-        x = x + h
+        x = x + _residual(cfg, h)
         h = L.seq_unpin(L.rmsnorm(p["ln2"], x, eps))
-        x = x + L.mlp(p["mlp"], cfg, h)
-        return x, ({"rglru": new_rg} if new_rg else None), aux
+        x = x + _residual(cfg, L.mlp(p["mlp"], cfg, h))
+        return x, ({"rglru": new_rg} if new_rg else None), aux, stats
 
     if kind == "mlstm":
         h = L.rmsnorm(p["ln1"], x, eps)
         h, new_c = XL.mlstm_block(p["mlstm"], cfg, h,
                                   cache=cache.get("mlstm") if cache else None)
-        return x + h, ({"mlstm": new_c} if new_c else None), aux
+        return (x + _residual(cfg, h), ({"mlstm": new_c} if new_c else None),
+                aux, stats)
 
     if kind == "slstm":
         h = L.rmsnorm(p["ln1"], x, eps)
         h, new_c = XL.slstm_block(p["slstm"], cfg, h,
                                   cache=cache.get("slstm") if cache else None)
-        return x + h, ({"slstm": new_c} if new_c else None), aux
+        return (x + _residual(cfg, h), ({"slstm": new_c} if new_c else None),
+                aux, stats)
 
     raise ValueError(kind)
 
@@ -165,8 +182,13 @@ def _apply_stack(stack: Params, cfg: ModelConfig, n_layers: int,
                  x: jax.Array, *,
                  positions, caches=None, cache_index=None, memory=None,
                  causal: bool = True, lossy: Optional[LossyCtx] = None,
-                 base_key: Optional[jax.Array] = None, remat: bool = True):
-    """caches: {"groups": [stacked per position], "tail": [per layer]}."""
+                 base_key: Optional[jax.Array] = None, remat: bool = True,
+                 routes: bool = False):
+    """caches: {"groups": [stacked per position], "tail": [per layer]}.
+
+    Returns (x, new_caches, aux, stats): the blocks' counters merged
+    over layers (``moe.merge_stats``); with ``routes``, ``stats`` also
+    holds ``moe_routes``, every MoE layer's expert ids stacked (L, G, k)."""
     plen = len(cfg.block_pattern)
     n_groups = n_layers // plen
     tail_kinds = cfg.block_pattern[: n_layers % plen]
@@ -192,35 +214,41 @@ def _apply_stack(stack: Params, cfg: ModelConfig, n_layers: int,
         seq_pin = lambda t: jax.lax.with_sharding_constraint(t, nsp)
 
     def unit(x, slices, caches_slice, idx):
-        new_caches, aux = [], jnp.zeros((), jnp.float32)
+        new_caches, aux, stats, ids = [], jnp.zeros((), jnp.float32), {}, []
         if seq_pin is not None:
             x = seq_pin(x)
         for j, kind in enumerate(cfg.block_pattern):
             c = caches_slice[j] if caches_slice is not None else None
             lk = jax.random.fold_in(base_key, idx * plen + j)
-            x, nc, a = apply_block(
+            x, nc, a, st = apply_block(
                 slices[j], kind, cfg, x, positions=positions, cache=c,
                 cache_index=cache_index, memory=memory, causal=causal,
-                lossy=lossy, layer_key=lk)
+                lossy=lossy, layer_key=lk, routes=routes)
             new_caches.append(nc)
             aux = aux + a
+            if "moe_routes" in st:
+                ids.append(st.pop("moe_routes"))
+            stats = MOE.merge_stats(stats, st)
         if seq_pin is not None:
             x = seq_pin(x)
-        return x, new_caches, aux
+        return x, new_caches, aux, stats, ids
 
+    stats, ids = {}, []
     if n_groups:
         unit_fn = jax.checkpoint(unit) if remat else unit
 
         def body(carry, inp):
-            x, aux = carry
+            x, aux, st = carry
             slices, cache_slice, idx = inp
-            x, ncs, a = unit_fn(x, slices, cache_slice, idx)
-            return (x, aux + a), ncs
+            x, ncs, a, s_, i_ = unit_fn(x, slices, cache_slice, idx)
+            return (x, aux + a, MOE.merge_stats(st, s_)), (ncs, i_)
 
         group_caches = caches["groups"] if caches is not None else None
         xs = (stack["groups"], group_caches, jnp.arange(n_groups))
-        (x, aux_total), new_group_caches = jax.lax.scan(
-            body, (x, aux_total), xs)
+        (x, aux_total, stats), (new_group_caches, g_ids) = jax.lax.scan(
+            body, (x, aux_total, MOE.zero_stats(cfg)), xs)
+        if g_ids:   # (groups, MoE positions, G, k) -> layers in order
+            ids = [jnp.stack(g_ids, 1).reshape((-1,) + g_ids[0].shape[1:])]
     else:
         new_group_caches = None
 
@@ -232,17 +260,22 @@ def _apply_stack(stack: Params, cfg: ModelConfig, n_layers: int,
             return apply_block(p_, _kind, cfg, x_, positions=positions,
                                cache=_c, cache_index=cache_index,
                                memory=memory, causal=causal, lossy=lossy,
-                               layer_key=_lk)
+                               layer_key=_lk, routes=routes)
         if remat:
             blk = jax.checkpoint(blk)
-        x, nc, a = blk(stack["tail"][i], x)
+        x, nc, a, st = blk(stack["tail"][i], x)
         new_tail.append(nc)
         aux_total = aux_total + a
+        if "moe_routes" in st:
+            ids.append(st.pop("moe_routes")[None])
+        stats = MOE.merge_stats(stats, st)
 
+    if ids:
+        stats["moe_routes"] = jnp.concatenate(ids, 0)
     new_caches = None
     if caches is not None:
         new_caches = {"groups": new_group_caches, "tail": new_tail}
-    return x, new_caches, aux_total
+    return x, new_caches, aux_total, stats
 
 
 # ----------------------------------------------------------------------
@@ -275,16 +308,19 @@ def _encode(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array]):
     frames = batch["frame_embeds"]                      # (B, S_enc, F)
     x = frames.astype(jnp.dtype(cfg.dtype)) @ params["frontend_proj"]
     pos = jnp.arange(x.shape[1], dtype=jnp.int32)[None, :]
-    x, _, _ = _apply_stack(params["encoder"], cfg, cfg.encoder_layers, x,
-                           positions=pos, causal=False)
+    x, _, _, _ = _apply_stack(params["encoder"], cfg, cfg.encoder_layers, x,
+                              positions=pos, causal=False)
     return L.rmsnorm(params["enc_norm"], x, cfg.norm_eps)
 
 
 def forward(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
             caches=None, cache_index=None, memory=None,
             lossy: Optional[LossyCtx] = None, remat: bool = True,
-            positions: Optional[jax.Array] = None, last_only: bool = False):
-    """Returns (logits, new_caches, aux_loss).
+            positions: Optional[jax.Array] = None, last_only: bool = False,
+            routes: bool = False):
+    """Returns (logits, new_caches, aux_loss, stats): ``stats`` are the
+    MoE counters (``moe.STATS``; empty without MoE layers), with
+    ``routes`` also every MoE layer's expert ids (``moe_routes``).
 
     batch keys: "tokens" (B,S) always; "image_embeds" (vlm);
     "frame_embeds" (audio, encoder side — triggers encoder unless
@@ -304,22 +340,24 @@ def forward(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
     if positions is None:
         positions = jnp.arange(s, dtype=jnp.int32)[None, :]
 
-    x, new_caches, aux = _apply_stack(
+    x, new_caches, aux, stats = _apply_stack(
         params["decoder"], cfg, cfg.n_layers, x, positions=positions,
         caches=caches, cache_index=cache_index, memory=memory, lossy=lossy,
-        remat=remat)
+        remat=remat, routes=routes)
 
     if last_only:   # prefill: only the last position's logits are used
         x = x[:, -1:]
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = L.unembed(params["embed"], cfg, x)
-    return logits, new_caches, aux
+    return logits, new_caches, aux, stats
 
 
 def lm_loss(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
             lossy: Optional[LossyCtx] = None, remat: bool = True):
-    """Next-token cross-entropy (+ MoE aux).  Loss only on text tokens."""
-    logits, _, aux = forward(params, cfg, batch, lossy=lossy, remat=remat)
+    """Next-token cross-entropy (+ MoE aux), with the MoE counters:
+    (loss, (nll, aux, stats)).  Loss only on text tokens."""
+    logits, _, aux, stats = forward(params, cfg, batch, lossy=lossy,
+                                    remat=remat)
     labels = batch["labels"]
     n_txt = labels.shape[1]
     logits = logits[:, -n_txt:][:, :-1]            # skip frontend positions
@@ -333,7 +371,7 @@ def lm_loss(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
     onehot = jax.nn.one_hot(tgt, logits.shape[-1], dtype=logits.dtype)
     label_logit = jnp.sum(logits * onehot, axis=-1).astype(jnp.float32)
     nll = lse - label_logit
-    return nll.mean() + aux, (nll.mean(), aux)
+    return nll.mean() + aux, (nll.mean(), aux, stats)
 
 
 # ----------------------------------------------------------------------

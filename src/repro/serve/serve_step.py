@@ -35,9 +35,9 @@ def make_prefill(cfg: ModelConfig, s_max: int):
                    if cfg.frontend == "vision_stub" else 0)
         positions = jnp.arange(tokens.shape[1] + n_front,
                                dtype=jnp.int32)[None, :]
-        logits, caches, _ = M.forward(params, cfg, batch, caches=caches,
-                                      positions=positions, remat=False,
-                                      last_only=True)
+        logits, caches, _, _ = M.forward(params, cfg, batch, caches=caches,
+                                         positions=positions, remat=False,
+                                         last_only=True)
         return logits[:, -1], caches
     return jax.jit(prefill)
 
@@ -48,7 +48,7 @@ def make_decode(cfg: ModelConfig):
         positions = jnp.full((batch["tokens"].shape[0], 1), index,
                              dtype=jnp.int32)
         memory = batch.get("memory")       # enc-dec cross-attention
-        logits, caches, _ = M.forward(
+        logits, caches, _, _ = M.forward(
             params, cfg, {"tokens": batch["tokens"]}, caches=caches,
             cache_index=index, positions=positions, memory=memory,
             remat=False)

@@ -59,7 +59,10 @@ on one device a coded leaf's whole rotate-drop-unbias-rotate-back is
 one Pallas kernel under ``roundtrip``, with the sign draw under
 ``encode`` and the mask under ``mask``; the exact GSPMD path has no op
 of its own) and ``optimizer``
-(``adamw.apply_updates``).  Scopes change op metadata only.
+(``adamw.apply_updates``).  Scopes change op metadata only.  An MoE
+model's blocks add ``moe`` inside ``fwd_bwd`` (``repro.models.moe``),
+and its step returns their counters (``moe.STATS``: ``moe_load_max``,
+``moe_dropped``) among its metrics.
 
 The ``drop_rate`` step input is where the transport engine couples in:
 ``Trainer`` walks an engine-derived ``DropSchedule`` (or the standalone
@@ -80,6 +83,7 @@ from repro.core import coding
 from repro.core import lossy_collectives as lc
 from repro.core.transport.coupling import MAX_DROP, CollectiveMode
 from repro.models import model as M
+from repro.models import moe as MOE
 from repro.optim import adamw
 from repro.train import sharding_rules as rules
 
@@ -408,8 +412,9 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
         if microbatches > 1:
             with jax.named_scope("fwd_bwd"):
                 return _accum_microbatches(params, batch, key, drop_rate)
-        (loss, (nll, aux)), grads = _grads_one(params, batch, key, drop_rate)
-        return loss, nll, aux, grads
+        (loss, (nll, aux, stats)), grads = _grads_one(params, batch, key,
+                                                      drop_rate)
+        return loss, nll, aux, stats, grads
 
     def _accum_microbatches(params, batch, key, drop_rate):
         mb = jax.tree.map(
@@ -418,35 +423,39 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
                                 + a.shape[1:]), batch)
 
         def mb_step(carry, xs):
-            gacc, lacc, nacc, aacc = carry
+            gacc, lacc, nacc, aacc, sacc = carry
             b_i, i = xs
-            (l, (n, a_)), g = _grads_one(
+            (l, (n, a_, s_)), g = _grads_one(
                 params, b_i, jax.random.fold_in(key, i), drop_rate)
             gacc = jax.tree.map(
                 lambda x, y: x + y.astype(jnp.float32), gacc, g)
-            return (gacc, lacc + l, nacc + n, aacc + a_), None
+            return (gacc, lacc + l, nacc + n, aacc + a_,
+                    MOE.merge_stats(sacc, s_)), None
 
         g0 = jax.tree.map(
             lambda p_: jnp.zeros(p_.shape, jnp.float32), params)
         z = jnp.zeros((), jnp.float32)
-        (gsum, loss, nll, aux), _ = jax.lax.scan(
-            mb_step, (g0, z, z, z), (mb, jnp.arange(microbatches)))
+        (gsum, loss, nll, aux, stats), _ = jax.lax.scan(
+            mb_step, (g0, z, z, z, MOE.zero_stats(cfg)),
+            (mb, jnp.arange(microbatches)))
         inv = 1.0 / microbatches
         grads = jax.tree.map(
             lambda g_, p_: (g_ * inv).astype(p_.dtype), gsum, params)
         loss, nll, aux = loss * inv, nll * inv, aux * inv
-        return loss, nll, aux, grads
+        return loss, nll, aux, stats, grads
 
     pod_axes = tuple(a for a in dp if a == shd.POD_AXIS)
     data_axes = tuple(a for a in dp if a != shd.POD_AXIS)
 
     def island(params, batch, key, drop_rate, plans, peer):
-        loss, nll, aux, grads = _accum_grads(params, batch, key, drop_rate)
-        return _island_sync(loss, nll, aux, grads, key, drop_rate, plans,
-                            peer)
+        loss, nll, aux, stats, grads = _accum_grads(params, batch, key,
+                                                    drop_rate)
+        return _island_sync(loss, nll, aux, stats, grads, key, drop_rate,
+                            plans, peer)
 
     @jax.named_scope("grad_sync")
-    def _island_sync(loss, nll, aux, grads, key, drop_rate, plans, peer):
+    def _island_sync(loss, nll, aux, stats, grads, key, drop_rate, plans,
+                     peer):
         peer_id = peer[0]     # this shard's index along the dp axes
         if mode is CollectiveMode.HIERARCHICAL:
             # intra-pod exact, cross-pod coded-lossy: every data shard
@@ -486,7 +495,8 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
             loss = jax.lax.pmean(loss, dp)
             nll = jax.lax.pmean(nll, dp)
             aux = jax.lax.pmean(aux, dp)
-        return loss, nll, aux, grads, frac
+            stats = MOE.reduce_stats(stats, dp)
+        return loss, nll, aux, stats, grads, frac
 
     def train_step(state, batch, key, drop_rate):
         params = state["params"]
@@ -505,11 +515,11 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
             rep = jax.tree.map(lambda _: P(), params)
             fn = lambda p_, b_, k_, d_, pe_: island(
                 p_, b_, k_, d_, plans, peer=pe_)
-            loss, nll, aux, grads, frac = shd.shard_map(
+            loss, nll, aux, stats, grads, frac = shd.shard_map(
                 fn, mesh=mesh,
                 in_specs=(rep, rules.batch_specs(mesh, batch), P(), P(),
                           P(dp)),
-                out_specs=(P(), P(), P(), rep, P()),
+                out_specs=(P(), P(), P(), P(), rep, P()),
                 axis_names=set(dp), check_vma=False,
             )(params, batch, key, drop_rate,
               jnp.arange(_dp_size(dp, mesh), dtype=jnp.int32))
@@ -519,12 +529,12 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
             # batch dp-sharded, value_and_grad of the global batch-mean
             # loss makes GSPMD insert exactly the lossless all-reduces
             # the island's pmean would.
-            loss, nll, aux, grads = _accum_grads(params, batch, key,
-                                                 drop_rate)
+            loss, nll, aux, stats, grads = _accum_grads(params, batch, key,
+                                                        drop_rate)
             frac = jnp.float32(1.0)
         else:   # single-device / no-dp path
-            (loss, (nll, aux)), grads = _grads_one(params, batch, key,
-                                                   drop_rate)
+            (loss, (nll, aux, stats)), grads = _grads_one(params, batch, key,
+                                                          drop_rate)
             with jax.named_scope("grad_sync"):
                 if mode.coded:
                     # no dp axis to lose data across, but the node itself
@@ -545,7 +555,7 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
         new_params, new_opt, om = adamw.apply_updates(
             params, grads, state["opt"], opt_cfg)
         metrics = {"loss": loss, "nll": nll, "aux": aux,
-                   "recv_frac": frac, **om}
+                   "recv_frac": frac, **stats, **om}
         return {"params": new_params, "opt": new_opt,
                 "step": state["step"] + 1}, metrics
 

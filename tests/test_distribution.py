@@ -108,10 +108,10 @@ def test_moe_ep_on_mesh_matches_single_device():
         x = jax.random.normal(jax.random.PRNGKey(1), (2, 64, cfg.d_model),
                               jnp.float32) * 0.3
         shd.set_global_mesh(None)
-        y_local, aux_local = MOE.moe_block(p, cfg, x)
+        y_local, aux_local, _ = MOE.moe_block(p, cfg, x)
         mesh = shd.make_mesh((4, 2), ('data', 'model'))
         shd.set_global_mesh(mesh)
-        y_ep, aux_ep = jax.jit(lambda p_, x_: MOE.moe_block(p_, cfg, x_))(p, x)
+        y_ep, aux_ep, _ = jax.jit(lambda p_, x_: MOE.moe_block(p_, cfg, x_))(p, x)
         shd.set_global_mesh(None)
         np.testing.assert_allclose(np.asarray(y_ep, np.float32),
                                    np.asarray(y_local, np.float32),

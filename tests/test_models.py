@@ -32,12 +32,12 @@ def test_smoke_forward_train_step(arch):
     key = jax.random.PRNGKey(0)
     params = M.init_params(key, cfg)
     batch = _batch(cfg, key)
-    logits, _, _ = M.forward(params, cfg, batch)
+    logits, _, _, _ = M.forward(params, cfg, batch)
     exp_s = batch["tokens"].shape[1] + (
         cfg.n_frontend_tokens if cfg.frontend == "vision_stub" else 0)
     assert logits.shape == (2, exp_s, cfg.vocab_size)
     assert bool(jnp.isfinite(logits).all())
-    loss, (nll, aux) = M.lm_loss(params, cfg, batch)
+    loss, (nll, aux, _) = M.lm_loss(params, cfg, batch)
     g = jax.grad(lambda p: M.lm_loss(p, cfg, batch)[0])(params)
     assert bool(jnp.isfinite(loss))
     assert all(bool(jnp.isfinite(l).all()) for l in jax.tree.leaves(g))
@@ -56,18 +56,18 @@ def test_prefill_decode_consistency(arch):
     memory = None
     if cfg.is_encdec:
         memory = M._encode(params, cfg, batch)
-    full, _, _ = M.forward(params, cfg, {"tokens": batch["tokens"],
-                                         **({"frame_embeds":
-                                             batch["frame_embeds"]}
-                                            if cfg.is_encdec else {})},
-                           memory=memory)
+    full, _, _, _ = M.forward(params, cfg, {"tokens": batch["tokens"],
+                                            **({"frame_embeds":
+                                                batch["frame_embeds"]}
+                                               if cfg.is_encdec else {})},
+                              memory=memory)
 
     caches = M.init_caches(cfg, b, s + 4)
-    pre, caches, _ = M.forward(
+    pre, caches, _, _ = M.forward(
         params, cfg, {"tokens": batch["tokens"][:, :s - 1]}, caches=caches,
         memory=memory,
         positions=jnp.arange(s - 1, dtype=jnp.int32)[None, :])
-    dec, caches, _ = M.forward(
+    dec, caches, _, _ = M.forward(
         params, cfg, {"tokens": batch["tokens"][:, s - 1:s]},
         caches=caches, cache_index=jnp.int32(s - 1), memory=memory,
         positions=jnp.full((b, 1), s - 1, jnp.int32))

@@ -131,6 +131,30 @@ def test_lowered_step_carries_layer_scopes(mode):
     assert reading == [("add", "jit(train_step)/add")], reading
 
 
+MOE_CHILDREN = ("route", "dispatch", "experts", "combine")
+
+
+def test_moe_scope_and_children_in_granite_step():
+    """Granite's MoE block runs under ``moe`` with its four children,
+    inside ``fwd_bwd``, forward and backward, in the compiled step's
+    op_names; qwen2's step has no ``moe`` op."""
+    cfg = C.get_smoke("granite-moe-3b-a800m")
+    shapes = jax.eval_shape(lambda k: ts.init_state(k, cfg),
+                            jax.random.PRNGKey(0))
+    hlo = _step(cfg, "lossy_hadamard").lower(
+        shapes, _batches(cfg, 1)[0], jax.random.PRNGKey(0),
+        jnp.float32(0.2)).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', hlo)
+    for child in MOE_CHILDREN:
+        mine = [n for n in names if f"/moe/{child}/" in n
+                and "/fwd_bwd/" in n]
+        assert any("transpose(" not in n for n in mine), child
+        assert any("transpose(" in n for n in mine), child
+    qwen = _cfg()
+    assert not any("moe/" in n for n in re.findall(
+        r'op_name="([^"]*)"', _lowered_hlo(_step(qwen, "exact"), qwen)))
+
+
 def _no_scope(monkeypatch):
     class NoScope(contextlib.ContextDecorator):
         def __init__(self, name):

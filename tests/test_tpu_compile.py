@@ -103,3 +103,115 @@ def test_engine_window_assembly_compiles_for_v5e(one_chip):
         compiled = fn.lower(sds(r, steps), sds(r, steps), sds(),
                             [sds(r, steps, 3), sds(r, steps, 4)]).compile()
     assert compiled.memory_analysis() is not None
+
+
+def test_expert_grouped_matmuls_compile_for_v5e(one_chip):
+    """The MoE block's expert SwiGLU on one chip, forward and backward,
+    at Granite's widths (32,768 routed rows, 40 experts, 1536 -> 512)
+    with the grouped matmul's tiles (``ops.grouped_matmul``)."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    from repro.kernels import ops
+
+    rows, e, d, f = 32768, 40, 1536, 512
+    t = ops.GMM_TILE
+
+    def gmm(x, w, sizes):
+        return megablox.gmm(x, w, sizes, x.dtype,
+                            (t, min(t, x.shape[1]), min(t, w.shape[2])),
+                            None, None, False, False)
+
+    def loss(x, wg, wi, wo, sizes):
+        h = jax.nn.silu(gmm(x, wg, sizes)) * gmm(x, wi, sizes)
+        return jnp.sum(gmm(h, wo, sizes).astype(jnp.float32))
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2, 3)),
+                        sds(rows, d), sds(e, d, f), sds(e, d, f),
+                        sds(e, f, d), sds(e, dtype=jnp.int32))
+    assert compiled.as_text().count("tpu_custom_call") >= 6
+
+
+def _granite_on(topo, shape):
+    """The granite smoke model and a (data, model) mesh of described
+    chips, set as the model code's mesh."""
+    import repro.configs as C
+    from repro import sharding as shd
+    mesh = jax.sharding.Mesh(np.array(topo.devices[:4]).reshape(shape),
+                             ("data", "model"))
+    shd.set_global_mesh(mesh)
+    return C.get_smoke("granite-moe-3b-a800m"), mesh
+
+
+def _placed(tree, shardings):
+    return jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        tree, shardings)
+
+
+@pytest.fixture
+def on_chip_kernels(one_chip, monkeypatch):
+    """Kernels compiled as the chip compiles them (Mosaic), not
+    interpreted; the model code's mesh cleared afterwards."""
+    from repro import sharding as shd
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    yield
+    shd.set_global_mesh(None)
+
+
+def test_moe_decode_compiles_on_a_model_sharded_v5e_mesh(topo,
+                                                          on_chip_kernels):
+    """Decode on a (data 2, model 2) mesh: one token is fewer than the
+    model axis, so the MoE block takes its dropless path, in ops that
+    GSPMD partitions (a Mosaic kernel cannot be partitioned)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.models import model as M
+    from repro.serve import serve_step
+    from repro.train import sharding_rules as rules
+
+    cfg, mesh = _granite_on(topo, (2, 2))
+    params = jax.eval_shape(lambda k: M.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    caches = jax.eval_shape(lambda: M.init_caches(cfg, 2, 32))
+    rep = NamedSharding(mesh, P())
+    compiled = serve_step.make_decode(cfg).lower(
+        _placed(params, rules.param_shardings(params, mesh)),
+        _placed(caches, jax.tree.map(lambda s: NamedSharding(mesh, s),
+                                     rules.cache_specs(mesh, caches))),
+        {"tokens": jax.ShapeDtypeStruct((2, 1), jnp.int32, sharding=rep)},
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)).compile()
+    assert compiled.memory_analysis() is not None
+
+
+def test_moe_coded_train_step_compiles_on_a_dp_v5e_mesh(topo,
+                                                        on_chip_kernels):
+    """The coded train step on a data-parallel (data 4, model 1) mesh:
+    the MoE block runs inside the dp-manual island, where a Mosaic
+    kernel cannot be partitioned either."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.optim.adamw import OptConfig
+    from repro.train import sharding_rules as rules
+    from repro.train import train_step as ts
+
+    cfg, mesh = _granite_on(topo, (4, 1))
+    step = ts.make_train_step(
+        cfg, mesh, OptConfig(warmup_steps=1),
+        ts.CelerisConfig(mode="lossy_hadamard", n_rot=256,
+                         min_coded_size=1024), donate=False)
+    state = jax.eval_shape(lambda k: ts.init_state(k, cfg),
+                           jax.random.PRNGKey(0))
+    batch = {k: jax.ShapeDtypeStruct((8, 32), jnp.int32)
+             for k in ("tokens", "labels")}
+    rep = NamedSharding(mesh, P())
+    compiled = step.lower(
+        _placed(state, ts.state_shardings(state, mesh)),
+        _placed(batch, jax.tree.map(lambda s: NamedSharding(mesh, s),
+                                    rules.batch_specs(mesh, batch))),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep),
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=rep)).compile()
+    assert compiled.memory_analysis() is not None
