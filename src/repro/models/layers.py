@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ops
 
 Params = Dict[str, Any]
 
@@ -225,7 +226,7 @@ def _cache_decode(cache: "AttnCache", k, v, index) -> "AttnCache":
     return AttnCache(k=ck, v=cv, pos=pos)
 
 
-FLASH_THRESHOLD = 4 * 1024 * 1024   # s_q * s_kv above which we tile
+FLASH_THRESHOLD = 4 * 1024 * 1024   # s_q * s_kv from which we tile
 
 
 def attention_scale(cfg: ModelConfig, head_dim: int) -> float:
@@ -234,6 +235,29 @@ def attention_scale(cfg: ModelConfig, head_dim: int) -> float:
     if cfg.attention_multiplier is not None:
         return cfg.attention_multiplier
     return head_dim ** -0.5
+
+
+def attention_path(cfg: ModelConfig, s: int, s_kv: int, *, decode: bool,
+                   cross: bool, default_positions: bool,
+                   kind: str = "global", causal: bool = True) -> str:
+    """How ``attention`` computes s queries over s_kv keys: ``"pallas"``
+    (``ops.flash_attention``), ``"tiled"`` (``_flash_attention`` in
+    jnp) or ``"dense"`` (the whole score matrix).
+
+    Long attention (``s * s_kv >= FLASH_THRESHOLD``, not decode) is
+    tiled.  It takes the Pallas kernel where the kernel computes the
+    same thing: no global mesh (Mosaic kernels cannot be partitioned
+    automatically), causal global self-attention without softcap, at
+    the default positions 0..s-1 and a length the kernel's tiles divide.
+    """
+    from repro import sharding as shd
+    if decode or s * s_kv < FLASH_THRESHOLD:
+        return "dense"
+    if (shd.get_global_mesh() is None and not cross and causal
+            and kind == "global" and cfg.attn_softcap is None
+            and default_positions and ops.flash_attention_fits(s)):
+        return "pallas"
+    return "tiled"
 
 
 def _flash_attention(q, k, v, *, qpos, kpos, kind: str, cfg: ModelConfig,
@@ -308,6 +332,7 @@ def attention(p: Params, cfg: ModelConfig, x: jax.Array, *,
               cache: Optional[AttnCache] = None,
               cache_index: Optional[jax.Array] = None,
               memory: Optional[jax.Array] = None,
+              default_positions: bool = False,
               ) -> tuple[jax.Array, Optional[AttnCache]]:
     """GQA attention.
 
@@ -315,6 +340,10 @@ def attention(p: Params, cfg: ModelConfig, x: jax.Array, *,
     - train/prefill: full (B,S,D) in, optional returned cache.
     - decode: S==1 with ``cache``+``cache_index`` (static-shape update).
     - cross-attention: ``memory`` (B,S_enc,D) supplies K/V, no cache/rope.
+
+    ``positions`` None, or ``default_positions``, says they are 0..S-1:
+    only then may long attention take the Pallas kernel
+    (``attention_path``).
     """
     b, s, d = x.shape
     h, kv, hd = cfg.n_heads_padded, cfg.n_kv_heads, cfg.resolved_head_dim
@@ -332,9 +361,10 @@ def attention(p: Params, cfg: ModelConfig, x: jax.Array, *,
     k = k.reshape(b, kv_src.shape[1], kv, hd)
     v = v.reshape(b, kv_src.shape[1], kv, hd)
 
+    if positions is None:
+        positions = jnp.arange(s, dtype=jnp.int32)[None, :]
+        default_positions = True
     if memory is None:   # self-attention: rope
-        if positions is None:
-            positions = jnp.arange(s, dtype=jnp.int32)[None, :]
         cos, sin, rot = rope_tables(positions, hd, cfg.rope_theta,
                                     cfg.rope_fraction)
         q = apply_rope(q, cos, sin, rot)
@@ -356,50 +386,58 @@ def attention(p: Params, cfg: ModelConfig, x: jax.Array, *,
     s_kv = kq.shape[1]
 
     rep = h // kv
-
-    # large attention tiles -> memory-efficient path (never builds the
-    # (Sq,Skv) matrix; required for 32k prefill / 4k train cells).
-    # Cross-attention uses it too (causal=False, all-valid kpos).
-    if not decode and s * s_kv > FLASH_THRESHOLD:
-        kq = _head_shard(jnp.repeat(kq, rep, axis=2))
-        vq = _head_shard(jnp.repeat(vq, rep, axis=2))
-        qpos1 = positions[0] if positions.ndim == 2 else positions
-        kpos_arr = jnp.arange(s_kv, dtype=jnp.int32)
-        out = _flash_attention(
-            q, kq, vq, qpos=qpos1, kpos=kpos_arr,
-            kind=("global" if memory is not None else kind), cfg=cfg,
-            causal=(causal and memory is None))
-        out = out.reshape(b, s, h * hd).astype(x.dtype) @ p["wo"]
-        return out, new_cache
-
-    # dense path: grouped-GQA einsums against the UNREPEATED kv (a
-    # materialized repeat of a 32k-token cache would cost GBs at decode)
+    path = attention_path(cfg, s, s_kv, decode=decode,
+                          cross=memory is not None,
+                          default_positions=default_positions, kind=kind,
+                          causal=causal)
     scale = attention_scale(cfg, hd)
-    qg = q.reshape(b, s, kv, rep, hd)
-    logits = jnp.einsum("bqkrd,bskd->bkrqs", qg, kq,
-                        preferred_element_type=jnp.float32) * scale
-    logits = logits.reshape(b, h, s, s_kv)
-    logits = _softcap(logits, cfg.attn_softcap)
 
-    # masks
-    if memory is None:
-        if decode:
-            kpos = kpos1[None, None, None, :]      # true positions per slot
-            mask = (kpos >= 0) & (kpos <= cache_index)
-            if kind == "local":
-                mask = mask & (kpos > cache_index - cfg.window_size)
+    with jax.named_scope("attention"):
+        if path == "pallas":
+            # rope left q and k in float32: the kernel takes them in the
+            # compute dtype, as the MXU does the dense path's matmuls
+            out = ops.flash_attention(q.astype(x.dtype), kq.astype(x.dtype),
+                                      vq, scale=scale)
+        elif path == "tiled":
+            # memory-efficient path: never builds the (Sq,Skv) matrix;
+            # cross-attention uses it too (causal=False, all-valid kpos)
+            kq = _head_shard(jnp.repeat(kq, rep, axis=2))
+            vq = _head_shard(jnp.repeat(vq, rep, axis=2))
+            qpos1 = positions[0] if positions.ndim == 2 else positions
+            kpos_arr = jnp.arange(s_kv, dtype=jnp.int32)
+            out = _flash_attention(
+                q, kq, vq, qpos=qpos1, kpos=kpos_arr,
+                kind=("global" if memory is not None else kind), cfg=cfg,
+                causal=(causal and memory is None)).astype(x.dtype)
         else:
-            qpos = positions[:, None, :, None]
-            kpos = jnp.arange(s_kv)[None, None, None, :]
-            mask = (kpos <= qpos) if causal else jnp.ones(
-                (1, 1, s, s_kv), bool)
-            if kind == "local":
-                mask = mask & (kpos > qpos - cfg.window_size)
-        logits = jnp.where(mask, logits, -1e30)
+            # dense path: grouped-GQA einsums against the UNREPEATED kv (a
+            # materialized repeat of a 32k-token cache would cost GBs at
+            # decode)
+            qg = q.reshape(b, s, kv, rep, hd)
+            logits = jnp.einsum("bqkrd,bskd->bkrqs", qg, kq,
+                                preferred_element_type=jnp.float32) * scale
+            logits = logits.reshape(b, h, s, s_kv)
+            logits = _softcap(logits, cfg.attn_softcap)
 
-    attn = jax.nn.softmax(logits, axis=-1).astype(vq.dtype)
-    attn_g = attn.reshape(b, kv, rep, s, s_kv)
-    out = jnp.einsum("bkrqs,bskd->bqkrd", attn_g, vq)
+            # masks
+            if memory is None:
+                if decode:
+                    kpos = kpos1[None, None, None, :]   # true slot positions
+                    mask = (kpos >= 0) & (kpos <= cache_index)
+                    if kind == "local":
+                        mask = mask & (kpos > cache_index - cfg.window_size)
+                else:
+                    qpos = positions[:, None, :, None]
+                    kpos = jnp.arange(s_kv)[None, None, None, :]
+                    mask = (kpos <= qpos) if causal else jnp.ones(
+                        (1, 1, s, s_kv), bool)
+                    if kind == "local":
+                        mask = mask & (kpos > qpos - cfg.window_size)
+                logits = jnp.where(mask, logits, -1e30)
+
+            attn = jax.nn.softmax(logits, axis=-1).astype(vq.dtype)
+            attn_g = attn.reshape(b, kv, rep, s, s_kv)
+            out = jnp.einsum("bkrqs,bskd->bqkrd", attn_g, vq)
     out = out.reshape(b, s, h * hd) @ p["wo"]
     return out, new_cache
 

@@ -92,10 +92,11 @@ def apply_block(p: Params, kind: str, cfg: ModelConfig, x: jax.Array, *,
                 positions, cache=None, cache_index=None, memory=None,
                 causal: bool = True, lossy: Optional[LossyCtx] = None,
                 layer_key: Optional[jax.Array] = None,
-                routes: bool = False):
+                routes: bool = False, default_positions: bool = False):
     """Returns (x, new_cache, aux_loss, stats); ``stats`` holds an MoE
     block's counters (``moe.STATS``, and with ``routes`` its expert ids)
-    and is empty for every other block."""
+    and is empty for every other block.  ``default_positions``: the
+    positions are 0..S-1 (``layers.attention``)."""
     eps = cfg.norm_eps
     aux = jnp.zeros((), jnp.float32)
     stats = {}
@@ -106,7 +107,8 @@ def apply_block(p: Params, kind: str, cfg: ModelConfig, x: jax.Array, *,
         h, new_attn_cache = L.attention(
             p["attn"], cfg, h, kind=("local" if kind == "local" else "global"),
             positions=positions, causal=causal,
-            cache=a_cache, cache_index=cache_index)
+            cache=a_cache, cache_index=cache_index,
+            default_positions=default_positions)
         if cfg.post_norm:
             h = L.rmsnorm(p["pn1"], h, eps)
         x = x + _residual(cfg, h)
@@ -183,7 +185,7 @@ def _apply_stack(stack: Params, cfg: ModelConfig, n_layers: int,
                  positions, caches=None, cache_index=None, memory=None,
                  causal: bool = True, lossy: Optional[LossyCtx] = None,
                  base_key: Optional[jax.Array] = None, remat: bool = True,
-                 routes: bool = False):
+                 routes: bool = False, default_positions: bool = False):
     """caches: {"groups": [stacked per position], "tail": [per layer]}.
 
     Returns (x, new_caches, aux, stats): the blocks' counters merged
@@ -223,7 +225,8 @@ def _apply_stack(stack: Params, cfg: ModelConfig, n_layers: int,
             x, nc, a, st = apply_block(
                 slices[j], kind, cfg, x, positions=positions, cache=c,
                 cache_index=cache_index, memory=memory, causal=causal,
-                lossy=lossy, layer_key=lk, routes=routes)
+                lossy=lossy, layer_key=lk, routes=routes,
+                default_positions=default_positions)
             new_caches.append(nc)
             aux = aux + a
             if "moe_routes" in st:
@@ -260,7 +263,8 @@ def _apply_stack(stack: Params, cfg: ModelConfig, n_layers: int,
             return apply_block(p_, _kind, cfg, x_, positions=positions,
                                cache=_c, cache_index=cache_index,
                                memory=memory, causal=causal, lossy=lossy,
-                               layer_key=_lk, routes=routes)
+                               layer_key=_lk, routes=routes,
+                               default_positions=default_positions)
         if remat:
             blk = jax.checkpoint(blk)
         x, nc, a, st = blk(stack["tail"][i], x)
@@ -337,13 +341,14 @@ def forward(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
         memory = _encode(params, cfg, batch)
 
     b, s = x.shape[:2]
-    if positions is None:
+    default_positions = positions is None
+    if default_positions:
         positions = jnp.arange(s, dtype=jnp.int32)[None, :]
 
     x, new_caches, aux, stats = _apply_stack(
         params["decoder"], cfg, cfg.n_layers, x, positions=positions,
         caches=caches, cache_index=cache_index, memory=memory, lossy=lossy,
-        remat=remat, routes=routes)
+        remat=remat, routes=routes, default_positions=default_positions)
 
     if last_only:   # prefill: only the last position's logits are used
         x = x[:, -1:]

@@ -1,4 +1,6 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps + hypothesis."""
+import functools
+
 try:
     import hypothesis
     import hypothesis.strategies as st
@@ -184,3 +186,45 @@ def test_coded_roundtrip_matches_encode_mask_decode(dtype, rows, n, drop):
     if drop == 0.0:
         np.testing.assert_allclose(got, np.asarray(x, np.float32),
                                    rtol=2.0 ** -7, atol=f32)
+
+
+def _dense_causal_attention(q, k, v, scale):
+    """The model's dense path: grouped queries against unrepeated kv,
+    float32 scores and softmax, bf16 probabilities into P.V."""
+    b, s, h, d = q.shape
+    kv = k.shape[2]
+    qg = q.reshape(b, s, kv, h // kv, d)
+    logits = jnp.einsum("bqkrd,bskd->bkrqs", qg, k,
+                        preferred_element_type=jnp.float32) * scale
+    causal = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
+    logits = jnp.where(causal, logits, -1e30)
+    p = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+    return jnp.einsum("bkrqs,bskd->bqkrd", p, v).reshape(b, s, h, d)
+
+
+@pytest.mark.parametrize("scale", [1 / 64, 64 ** -0.5])
+def test_flash_attention_matches_dense(scale, monkeypatch):
+    """The Pallas flash kernel against the dense path, forward and the
+    gradients of q, k and v: 6 query heads over 2 kv heads, head_dim 64,
+    two 128-row tiles each way (one skipped above the diagonal)."""
+    monkeypatch.setattr(ops, "FLASH_BLOCKS",
+                        {k: (128, 128) for k in ops.FLASH_BLOCKS})
+    b, s, h, kv, d = 2, 256, 6, 2, 64
+    kq, kk, kv_, kc = jax.random.split(jax.random.PRNGKey(7), 4)
+    q = jax.random.normal(kq, (b, s, h, d), jnp.bfloat16) * 4
+    k = jax.random.normal(kk, (b, s, kv, d), jnp.bfloat16) * 4
+    v = jax.random.normal(kv_, (b, s, kv, d), jnp.bfloat16)
+    cot = jax.random.normal(kc, (b, s, h, d), jnp.float32)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * cot)
+
+    flash = functools.partial(ops.flash_attention, scale=scale)
+    dense = functools.partial(_dense_causal_attention, scale=scale)
+    got, want = jax.jit(flash)(q, k, v), jax.jit(dense)(q, k, v)
+    assert got.shape == want.shape and got.dtype == jnp.bfloat16
+    grads = [jax.jit(jax.grad(loss(fn), argnums=(0, 1, 2)))(q, k, v)
+             for fn in (flash, dense)]
+    for g, w in [(got, want)] + list(zip(*grads)):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.abs(g - w).max() <= 2e-2 * np.abs(w).max()

@@ -107,6 +107,7 @@ def test_partial_rope_rotates_half():
 
 
 def test_flash_equals_dense():
+    """The jnp tiled path (gemma2's softcap keeps it off the kernel)."""
     import repro.models.layers as ml
     cfg = C.get_smoke("gemma2-9b")
     p = L.init_attention(jax.random.PRNGKey(4), cfg)
@@ -115,6 +116,8 @@ def test_flash_equals_dense():
     old = ml.FLASH_THRESHOLD
     try:
         ml.FLASH_THRESHOLD = 1
+        assert L.attention_path(cfg, 128, 128, decode=False, cross=False,
+                                default_positions=True) == "tiled"
         flash, _ = L.attention(p, cfg, x, kind="global", positions=pos)
         ml.FLASH_THRESHOLD = 10 ** 12
         dense, _ = L.attention(p, cfg, x, kind="global", positions=pos)
@@ -123,6 +126,94 @@ def test_flash_equals_dense():
     np.testing.assert_allclose(np.asarray(flash, np.float32),
                                np.asarray(dense, np.float32),
                                rtol=2e-2, atol=2e-4)
+
+
+# (configuration, s, s_kv, keyword arguments, mesh?) -> path
+ATTENTION_PATHS = {
+    "granite_cell": ("granite-moe-3b-a800m", 2048, 2048, {}, False, "pallas"),
+    "qwen2_cell": ("qwen2-0.5b", 512, 512, {}, False, "dense"),
+    "decode": ("granite-moe-3b-a800m", 1, 4096, {"decode": True}, False,
+               "dense"),
+    "mesh": ("granite-moe-3b-a800m", 2048, 2048, {}, True, "tiled"),
+    "softcap": ("gemma2-9b", 2048, 2048, {}, False, "tiled"),
+    "local_window": ("granite-moe-3b-a800m", 2048, 2048, {"kind": "local"},
+                     False, "tiled"),
+    "cross": ("seamless-m4t-medium", 2048, 2048, {"cross": True}, False,
+              "tiled"),
+    "caller_positions": ("granite-moe-3b-a800m", 2048, 2048,
+                         {"default_positions": False}, False, "tiled"),
+    "bidirectional": ("granite-moe-3b-a800m", 2048, 2048, {"causal": False},
+                      False, "tiled"),
+    "untiled_length": ("granite-moe-3b-a800m", 2112, 2112, {}, False,
+                       "tiled"),
+}
+
+
+@pytest.mark.parametrize("case", list(ATTENTION_PATHS))
+def test_attention_path(case):
+    from repro import sharding as shd
+    arch, s, s_kv, kw, on_mesh, want = ATTENTION_PATHS[case]
+    kw = {"decode": False, "cross": False, "default_positions": True, **kw}
+    shd.set_global_mesh(shd.make_mesh((1, 1), ("data", "model"))
+                        if on_mesh else None)
+    try:
+        assert L.attention_path(C.get(arch), s, s_kv, **kw) == want
+    finally:
+        shd.set_global_mesh(None)
+
+
+@pytest.mark.parametrize("arch,layers,b,s,want", [
+    ("granite-moe-3b-a800m", 6, 2, 2048, "pallas"),
+    ("qwen2-0.5b", 24, 8, 512, "dense")])
+def test_attention_path_of_the_benchmark_steps(arch, layers, b, s, want,
+                                               monkeypatch):
+    """Every attention layer of the benchmark cells' training steps, as
+    traced at full width (nothing runs): granite's 6 layers at seq 2048
+    take the kernel, qwen2's 24 at seq 512 stay dense."""
+    import dataclasses
+    cfg = dataclasses.replace(C.get(arch), n_layers=layers)
+    seen = []
+
+    def spy(*a, **kw):
+        seen.append(path(*a, **kw))
+        return seen[-1]
+
+    path = L.attention_path
+    monkeypatch.setattr(L, "attention_path", spy)
+    params = jax.eval_shape(lambda k: M.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    batch = {k: jax.ShapeDtypeStruct((b, s), jnp.int32)
+             for k in ("tokens", "labels")}
+    jax.eval_shape(jax.grad(lambda p, bt: M.lm_loss(p, cfg, bt)[0]),
+                   params, batch)
+    assert seen and set(seen) == {want}
+
+
+def test_attention_takes_the_kernel_and_matches_dense(monkeypatch):
+    """Long causal self-attention at the default positions runs the
+    Pallas kernel (Granite's 1/64 scale, grouped kv heads), and gives
+    what the dense path gives, forward and backward."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "FLASH_BLOCKS",
+                        {k: (128, 128) for k in ops.FLASH_BLOCKS})
+    cfg = C.get_smoke("granite-moe-3b-a800m")
+    p = L.init_attention(jax.random.PRNGKey(8), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(9), (2, 256, cfg.d_model),
+                          jnp.bfloat16)
+
+    def run(want_path):
+        assert L.attention_path(cfg, 256, 256, decode=False, cross=False,
+                                default_positions=True) == want_path
+        return L.attention(p, cfg, x)[0], jax.grad(
+            lambda p_: jnp.sum(L.attention(p_, cfg, x)[0].astype(
+                jnp.float32) ** 2))(p)
+
+    want = run("dense")
+    monkeypatch.setattr(L, "FLASH_THRESHOLD", 1)
+    got = run("pallas")
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.abs(g - w).max() <= 2e-2 * np.abs(w).max()
 
 
 def test_mlstm_chunkwise_equals_sequential():

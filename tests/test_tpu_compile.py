@@ -134,6 +134,28 @@ def test_expert_grouped_matmuls_compile_for_v5e(one_chip):
     assert compiled.as_text().count("tpu_custom_call") >= 6
 
 
+def test_granite_flash_attention_compiles_for_v5e(one_chip, monkeypatch):
+    """Granite's attention at the benchmark cell's shape, (2, 2048, 24,
+    64) queries over 8 kv heads, forward and backward through the
+    Pallas kernels (``ops.flash_attention``), with the tiles it runs."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+
+    def loss(q, k, v):
+        out = ops.flash_attention(q, k, v, scale=1 / 64)
+        return jnp.sum(out.astype(jnp.float32))
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                        sds(2, 2048, 24, 64), sds(2, 2048, 8, 64),
+                        sds(2, 2048, 8, 64))
+    # forward, dq and dkv
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
 def _granite_on(topo, shape):
     """The granite smoke model and a (data, model) mesh of described
     chips, set as the model code's mesh."""
