@@ -34,8 +34,9 @@ def run(steps=60, seed=0):
         tr = Trainer(cfg, data_cfg=dc,
                      opt_cfg=OptConfig(lr=1e-3, warmup_steps=10,
                                        total_steps=500),
-                     celeris=CelerisConfig(enabled=drop > 0,
-                                           min_coded_size=1024),
+                     celeris=CelerisConfig(
+                         mode="lossy_hadamard" if drop > 0 else "exact",
+                         min_coded_size=1024),
                      seed=seed, straggler=_FixedDrop(drop))
         h = tr.run(steps)
         final = float(np.mean(h["loss"][-10:]))

@@ -32,8 +32,9 @@ def main():
     tr = Trainer(cfg, data_cfg=dc,
                  opt_cfg=OptConfig(lr=1e-3, warmup_steps=10,
                                    total_steps=args.steps * 2),
-                 celeris=CelerisConfig(enabled=args.celeris,
-                                       min_coded_size=1024))
+                 celeris=CelerisConfig(
+                     mode="lossy_hadamard" if args.celeris else "exact",
+                     min_coded_size=1024))
     hist = tr.run(args.steps, on_metrics=lambda s, m: print(
         f"step {s:3d} loss {m['loss']:.4f} recv {m['recv_frac']:.3f} "
         f"({m['wall_s']:.2f}s)"))

@@ -80,8 +80,8 @@ def main():
     try:
         h_lossy = run_one(
             cfg, "celeris", args.steps,
-            CelerisConfig(enabled=True, min_coded_size=4096), seed=0,
-            ckpt_dir=tmp, fault_at=min(args.steps - 10, 40))
+            CelerisConfig(mode="lossy_hadamard", min_coded_size=4096),
+            seed=0, ckpt_dir=tmp, fault_at=min(args.steps - 10, 40))
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
 

@@ -10,18 +10,25 @@ collective payloads so that *bounded, partial* loss is absorbed:
 
 **Wire interleaving** — rotation must span *more* than the loss
 granularity or a dropped chunk would take a whole rotation block with
-it.  After rotating each (B, n) block-row we transpose to (n, B) "wire
-layout": network chunk j carries coordinate j of *every* rotation block,
-so any lost chunk removes a 1/n coordinate slice from each block and the
-unbiased rescale recovers the rest.  This implements the paper's
-"critical information ... split across packets for partial recovery".
+it.  A leaf is cut into rotation blocks laid out as ``(tiles, n_rot,
+Ns)`` (:func:`to_tiles_nd`); the rotation runs along the middle axis,
+and wire row ``j`` is coordinate ``j`` of *every* block (``t[:, j,
+:]``).  A lost row removes a 1/n coordinate slice from each block and
+the unbiased rescale recovers the rest: the paper's "critical
+information ... split across packets for partial recovery".  Arrival
+masks are ``(n_rot,)`` vectors over those rows.
+
+This one coder (:class:`NdPlan`, :func:`encode_nd`, :func:`decode_nd`)
+serves every path: the train step's one-device sync (as the Pallas
+``coded_roundtrip`` kernel, :func:`roundtrip_nd`, where the tiles are
+lane-wide flat rows), its dp-mesh psum
+(``lossy_collectives.lossy_psum``) and the serve path's KV transfer.
+Outside the kernel the transform is :func:`fwht_nd`, two ``HIGHEST``
+contractions.
 
 **XOR parity** — exact recovery of any single lost chunk per parity
 group (the paper's lightweight coding alternative for prioritized data,
 e.g. activation shards under lossy TP).
-
-All transforms run through the Pallas FWHT kernel (MXU path on TPU);
-``use_pallas=False`` routes to the jnp oracle for dry-run lowering.
 """
 from __future__ import annotations
 
@@ -33,204 +40,11 @@ import jax.numpy as jnp
 from repro.kernels import ops, ref
 
 
-@dataclasses.dataclass(frozen=True)
-class HadamardCode:
-    """Static coding geometry for one flat payload."""
-    n_rot: int          # rotation block width (power of two)
-    n_blocks: int       # number of rotation blocks  (padded_len = n_rot*n_blocks)
-    orig_len: int       # unpadded payload length
-
-    @property
-    def padded_len(self) -> int:
-        return self.n_rot * self.n_blocks
-
-    @property
-    def wire_shape(self) -> tuple[int, int]:
-        """(n_rot, n_blocks): wire row j = coordinate j of every block."""
-        return (self.n_rot, self.n_blocks)
-
-
-def plan(orig_len: int, n_rot: int = 4096, block_multiple: int = 1
-         ) -> HadamardCode:
-    """``block_multiple``: round n_blocks up so the block dim shards
-    cleanly over the model axis (keeps the FWHT collective-free)."""
-    while n_rot > 1 and n_rot > orig_len:
-        n_rot //= 2
-    n_rot = max(n_rot, 2)
-    n_blocks = -(-orig_len // n_rot)
-    n_blocks = -(-n_blocks // block_multiple) * block_multiple
-    return HadamardCode(n_rot=n_rot, n_blocks=n_blocks, orig_len=orig_len)
-
-
-def rademacher(key: jax.Array, code: HadamardCode) -> jax.Array:
-    """Random sign diagonal D, shared by every participant (same key).
-
-    One (n_rot,) vector shared across rotation blocks — per-block signs
-    would double parameter-scale memory at 15B-model size, and per-block
-    unbiasedness holds either way (OptiReduce likewise reuses one
-    rotation per chunk).
-    """
-    return jax.random.rademacher(key, (code.n_rot,), dtype=jnp.float32)
-
-
-def encode(x: jax.Array, signs: jax.Array, code: HadamardCode, *,
-           use_pallas: bool = True, constrain=None) -> jax.Array:
-    """flat (orig_len,) -> wire layout (n_rot, n_blocks).
-
-    ``constrain(a, kind)`` (kind in {"blocks","wire"}): optional sharding
-    hint applied inside — used by the trainer to keep the block dim on
-    the model axis so the FWHT stays collective-free under GSPMD.
-    """
-    if x.ndim == 2 and x.shape == (code.n_blocks, code.n_rot):
-        blocks = x          # pre-blocked (keeps big leaves sharded)
-    else:
-        x = x.reshape(-1)
-        x = jnp.pad(x, (0, code.padded_len - code.orig_len))
-        blocks = x.reshape(code.n_blocks, code.n_rot)
-    if constrain is not None:
-        blocks = constrain(blocks, "blocks")
-    # sign-multiply + 1/sqrt(n) normalization fused into the kernel
-    # (saves two full HBM round-trips per encode on the Pallas path)
-    rot = ops.fwht(blocks, signs=signs, scale=code.n_rot ** -0.5,
-                   use_pallas=use_pallas)
-    wire = rot.T
-    if constrain is not None:
-        wire = constrain(wire, "wire")
-    return wire
-
-
-def encode_quantized(x: jax.Array, signs: jax.Array, code: HadamardCode,
-                     noise_key: jax.Array, *, use_pallas: bool = True,
-                     constrain=None) -> tuple[jax.Array, jax.Array]:
-    """:func:`encode` with the wire payload quantized to int8.
-
-    Per rotation block the rotated coordinates are stochastically
-    rounded to absmax-scaled int8 (QSGD-style; the rotation's variance
-    flattening is exactly what makes a shared per-block scale cheap) —
-    a 4x cut in collective wire bytes.  The rotate and quantize stages
-    run as ONE fused Pallas kernel (``ops.fwht_quantize``): the rotated
-    tile never round-trips through HBM between them.
-
-    Returns ``(q_wire (n_rot, n_blocks) int8, scales (n_blocks,))``;
-    :func:`dequantize_wire` restores the f32 wire layout that
-    :func:`decode` consumes.
-    """
-    if x.ndim == 2 and x.shape == (code.n_blocks, code.n_rot):
-        blocks = x
-    else:
-        x = x.reshape(-1)
-        x = jnp.pad(x, (0, code.padded_len - code.orig_len))
-        blocks = x.reshape(code.n_blocks, code.n_rot)
-    if constrain is not None:
-        blocks = constrain(blocks, "blocks")
-    noise = jax.random.uniform(noise_key, blocks.shape)
-    q, scales = ops.fwht_quantize(blocks, noise, signs=signs,
-                                  scale=code.n_rot ** -0.5,
-                                  use_pallas=use_pallas)
-    return q.T, scales
-
-
-def dequantize_wire(q_wire: jax.Array, scales: jax.Array) -> jax.Array:
-    """int8 wire layout (n_rot, n_blocks) -> f32 wire layout."""
-    return q_wire.astype(jnp.float32) * scales[None, :]
-
-
-def decode(wire_sum: jax.Array, counts: jax.Array, signs: jax.Array,
-           code: HadamardCode, *, total_peers: int = 1,
-           use_pallas: bool = True, constrain=None,
-           out_blocks: bool = False) -> jax.Array:
-    """Inverse of :func:`encode` over *summed received* wire data.
-
-    ``wire_sum`` (n_rot, n_blocks): per-wire-row sums of the
-    contributions that arrived inside the window.  ``counts`` (n_rot,):
-    how many of the ``total_peers`` expected contributions arrived per
-    row (rows with 0 arrivals hold zeros).
-
-    Two unbiasing stages (both exact in expectation, both no-ops when
-    nothing was lost):
-      1. peer unbias — scale row r by total_peers/counts[r] so each
-         present row estimates the *full-peer* sum of that coordinate;
-      2. sampling unbias — scale every present row by n_rot/k
-         (k = rows with any arrival) so the inverse rotation of the
-         zero-filled coordinate vector is unbiased.
-    """
-    row_est = ops.masked_unbias(wire_sum, counts, total_peers,
-                                use_pallas=use_pallas)       # stage 1
-    k = jnp.sum(counts > 0)
-    scale = jnp.where(k > 0, code.n_rot / jnp.maximum(k, 1), 0.0)
-    rot = row_est.T * scale                                  # stage 2
-    if constrain is not None:
-        rot = constrain(rot, "blocks")
-    blocks = (ops.fwht(rot, scale=code.n_rot ** -0.5, use_pallas=use_pallas)
-              * signs[None, :])
-    if constrain is not None:
-        blocks = constrain(blocks, "blocks")
-    if out_blocks:
-        return blocks       # (n_blocks, n_rot), caller reshapes in place
-    return blocks.reshape(-1)[: code.orig_len]
-
-
 # ----------------------------------------------------------------------
-# XOR parity (exact single-loss recovery per group)
-# ----------------------------------------------------------------------
-
-def xor_parity_encode(chunks: jax.Array) -> jax.Array:
-    """chunks (g, m) float32 -> parity chunk (m,) via bitwise XOR."""
-    bits = jax.lax.bitcast_convert_type(chunks, jnp.int32)
-    parity = jax.lax.reduce(bits, jnp.int32(0), jax.lax.bitwise_xor, (0,))
-    return jax.lax.bitcast_convert_type(parity, jnp.float32)
-
-
-def xor_parity_decode(chunks: jax.Array, parity: jax.Array,
-                      arrived: jax.Array) -> jax.Array:
-    """Recover at most one lost chunk in the group.
-
-    ``chunks`` (g, m) with lost rows zeroed, ``arrived`` (g,) bool.
-    If exactly one row is lost it is reconstructed exactly; with zero
-    losses the input is returned unchanged; with >1 losses the lost rows
-    stay zero (decoder falls back to statistical tolerance).
-    """
-    n_lost = jnp.sum(~arrived)
-    bits = jax.lax.bitcast_convert_type(chunks, jnp.int32)
-    # Zeroed-by-mask rows can carry -0.0 (sign bit set) — scrub them so
-    # lost rows contribute true zero bits to the XOR.
-    bits = jnp.where(arrived[:, None], bits, 0)
-    pbits = jax.lax.bitcast_convert_type(parity, jnp.int32)
-    xor_all = jax.lax.reduce(bits, jnp.int32(0), jax.lax.bitwise_xor, (0,))
-    recovered = jax.lax.bitwise_xor(xor_all, pbits)          # = missing row
-    rec_f = jax.lax.bitcast_convert_type(recovered, jnp.float32)
-    fill = jnp.where((n_lost == 1) & ~arrived[:, None], rec_f[None, :], 0.0)
-    return jnp.where(arrived[:, None], chunks, fill)
-
-
-# ----------------------------------------------------------------------
-# Convenience: pytree-level encode/decode used by the trainer
-# ----------------------------------------------------------------------
-
-def tree_ravel(tree) -> tuple[jax.Array, object]:
-    flat, treedef = jax.tree_util.tree_flatten(tree)
-    shapes = [(l.shape, l.dtype) for l in flat]
-    vec = jnp.concatenate([l.reshape(-1).astype(jnp.float32) for l in flat])
-    return vec, (treedef, shapes)
-
-
-def tree_unravel(vec: jax.Array, spec) -> object:
-    treedef, shapes = spec
-    out, off = [], 0
-    for shape, dtype in shapes:
-        size = 1
-        for s in shape:
-            size *= s
-        out.append(vec[off: off + size].reshape(shape).astype(dtype))
-        off += size
-    return jax.tree_util.tree_unflatten(treedef, out)
-
-
-# ----------------------------------------------------------------------
-# Sharding-aware ND coding (the form the trainer uses at scale)
+# Sharding-aware ND coding
 # ----------------------------------------------------------------------
 #
-# Rotating a TP-sharded gradient leaf through the flat (n_blocks, n_rot)
+# Rotating a TP-sharded gradient leaf through a flat (blocks, n_rot)
 # layout forces SPMD to reshard through a reshape — the old partitioner
 # handles that by full rematerialization (GiB-scale replicated buffers
 # at 15B params).  Instead we rotate along the *unsharded* axes only:
@@ -272,6 +86,8 @@ class NdPlan:
 
 
 def rademacher_nd(key: jax.Array, plan: "NdPlan") -> jax.Array:
+    """Random sign diagonal D, shared by every participant (same key):
+    one (n_rot,) vector for all of a leaf's rotation blocks."""
     return jax.random.rademacher(key, (plan.n_rot,), dtype=jnp.float32)
 
 
@@ -288,8 +104,12 @@ def plan_nd(shape, sharded_dim, n_rot: int = 4096) -> NdPlan:
                   shape=tuple(shape), m_orig=m)
 
 
-def _to_tiles(g: jax.Array, plan: NdPlan) -> jax.Array:
-    """leaf -> (tiles, n_rot, Ns) with only unsharded dims reshaped."""
+def to_tiles_nd(g: jax.Array, plan: NdPlan) -> jax.Array:
+    """leaf -> (tiles, n_rot, Ns) with only unsharded dims reshaped.
+
+    With no rotation this is also the uncoded wire layout: the plain
+    lossy ablation drops rows straight out of it, so what Hadamard buys
+    is exactly the delta between the two modes on identical tilings."""
     sd = plan.sharded_dim
     if sd is not None:
         perm = [i for i in range(g.ndim) if i != sd] + [sd]
@@ -305,7 +125,8 @@ def _to_tiles(g: jax.Array, plan: NdPlan) -> jax.Array:
     return g.reshape(plan.tiles, plan.n_rot, ns)
 
 
-def _from_tiles(t: jax.Array, plan: NdPlan) -> jax.Array:
+def from_tiles_nd(t: jax.Array, plan: NdPlan) -> jax.Array:
+    """Inverse of :func:`to_tiles_nd` (drops the padding)."""
     sd = plan.sharded_dim
     ns = t.shape[-1]
     g = t.reshape(-1, ns)[: plan.m_orig]
@@ -318,40 +139,35 @@ def _from_tiles(t: jax.Array, plan: NdPlan) -> jax.Array:
     return g.transpose(inv)
 
 
-# Public tile layout (no rotation): the plain-lossy ablation path drops
-# wire rows straight out of this layout, so what Hadamard buys is exactly
-# the delta between the two modes on identical tilings.
-def to_tiles_nd(g: jax.Array, plan: NdPlan) -> jax.Array:
-    return _to_tiles(g, plan)
-
-
-def from_tiles_nd(t: jax.Array, plan: NdPlan) -> jax.Array:
-    return _from_tiles(t, plan)
-
-
-def fwht_nd(t: jax.Array, plan: NdPlan) -> jax.Array:
+def fwht_nd(t: jax.Array) -> jax.Array:
     """Normalized (self-inverse) FWHT along the rotation axis of a
     (tiles, n_rot, Ns) block: fwht_nd(fwht_nd(t)) == t."""
-    return _fwht_axis1(t) * (plan.n_rot ** -0.5)
+    return _fwht_axis1(t) * (t.shape[1] ** -0.5)
 
 
 def encode_nd(g: jax.Array, signs: jax.Array, plan: NdPlan) -> jax.Array:
     """leaf -> rotated tiles (tiles, n_rot, Ns); signs: (n_rot,)."""
-    t = _to_tiles(g.astype(jnp.float32), plan)
-    t = t * signs[None, :, None]
-    return _fwht_axis1(t) * (plan.n_rot ** -0.5)
+    t = to_tiles_nd(g.astype(jnp.float32), plan)
+    return fwht_nd(t * signs[None, :, None])
 
 
 def decode_nd(tiles_sum: jax.Array, counts: jax.Array, signs: jax.Array,
               plan: NdPlan, *, total_peers: int = 1) -> jax.Array:
-    """Inverse of encode_nd over summed received tiles; counts (n_rot,)."""
+    """Inverse of :func:`encode_nd` over *summed received* tiles.
+
+    ``counts`` (n_rot,): how many of the ``total_peers`` expected
+    contributions arrived per wire row (rows with 0 arrivals hold
+    zeros).  Two unbiasing stages, both exact in expectation and no-ops
+    when nothing was lost: scale row j by total_peers/counts[j] so each
+    present row estimates the full-peer sum, then every present row by
+    n_rot/k (k = rows with any arrival) so the inverse rotation of the
+    zero-filled coordinates is unbiased."""
     c = counts[None, :, None]
     safe = jnp.maximum(c, 1.0)
     est = jnp.where(c > 0, tiles_sum * (total_peers / safe), 0.0)
     k = jnp.sum(counts > 0)
     est = est * jnp.where(k > 0, plan.n_rot / jnp.maximum(k, 1), 0.0)
-    est = _fwht_axis1(est) * (plan.n_rot ** -0.5) * signs[None, :, None]
-    return _from_tiles(est, plan)
+    return from_tiles_nd(fwht_nd(est) * signs[None, :, None], plan)
 
 
 def one_peer_colscale(mask: jax.Array, plan: NdPlan) -> jax.Array:
@@ -368,10 +184,46 @@ def roundtrip_nd(g: jax.Array, signs: jax.Array, colscale: jax.Array,
     tiles are flat rows (``plan.sharded_dim is None``, ``n_rot >= 128``)
     as one Pallas kernel (``ops.coded_roundtrip``), in the leaf's dtype;
     ``colscale`` is :func:`one_peer_colscale` of the mask."""
-    rows = ops.coded_roundtrip(_to_tiles(g, plan)[..., 0], signs, colscale)
+    rows = ops.coded_roundtrip(to_tiles_nd(g, plan)[..., 0], signs, colscale)
     # The barrier keeps the relayout back to the leaf's shape here, in
     # the leaf's dtype.  Without it XLA sinks the reshape into the
     # consumers: AdamW then updates its f32 moments in the (tiles,
     # n_rot) layout and relayouts those, twice the bytes (+20 ms a step
     # for qwen2-0.5b on a v5e).
-    return jax.lax.optimization_barrier(_from_tiles(rows[..., None], plan))
+    return jax.lax.optimization_barrier(from_tiles_nd(rows[..., None], plan))
+
+
+# ----------------------------------------------------------------------
+# XOR parity (exact single-loss recovery per group)
+# ----------------------------------------------------------------------
+
+def xor_parity_encode(chunks: jax.Array) -> jax.Array:
+    """chunks (g, m) float32 -> parity chunk (m,) via bitwise XOR."""
+    bits = jax.lax.bitcast_convert_type(chunks, jnp.int32)
+    parity = jax.lax.reduce(bits, jnp.int32(0), jax.lax.bitwise_xor, (0,))
+    return jax.lax.bitcast_convert_type(parity, jnp.float32)
+
+
+def xor_parity_decode(chunks: jax.Array, parity: jax.Array,
+                      arrived: jax.Array) -> jax.Array:
+    """Recover at most one lost chunk in the group.
+
+    ``chunks`` (g, m) with lost rows zeroed, ``arrived`` (g,) bool.
+    If exactly one row is lost it is reconstructed exactly; with zero
+    losses the input is returned unchanged; with >1 losses the lost rows
+    stay zero (decoder falls back to statistical tolerance).
+    """
+    n_lost = jnp.sum(~arrived)
+    bits = jax.lax.bitcast_convert_type(chunks, jnp.int32)
+    # Zeroed-by-mask rows can carry -0.0 (sign bit set) — scrub them so
+    # lost rows contribute true zero bits to the XOR.
+    bits = jnp.where(arrived[:, None], bits, 0)
+    pbits = jax.lax.bitcast_convert_type(parity, jnp.int32)
+    xor_all = jax.lax.reduce(bits, jnp.int32(0), jax.lax.bitwise_xor, (0,))
+    recovered = jax.lax.bitwise_xor(xor_all, pbits)          # = missing row
+    rec_f = jax.lax.bitcast_convert_type(recovered, jnp.float32)
+    fill = jnp.where((n_lost == 1) & ~arrived[:, None], rec_f[None, :], 0.0)
+    return jnp.where(arrived[:, None], chunks, fill)
+
+
+# ----------------------------------------------------------------------

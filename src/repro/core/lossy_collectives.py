@@ -1,24 +1,19 @@
 """Lossy (best-effort) collectives — Celeris semantics on a TPU mesh.
 
 TPU ICI is lossless, so Celeris's "packets that miss the bounded window
-are discarded" is emulated at *wire-chunk granularity inside the
+are discarded" is emulated at *wire-row granularity inside the
 collective*: every participant samples a per-(peer, wire-row) arrival
 mask from the step's drop probability (itself derived from the timeout
 controller + transport latency model) and contributes only the rows that
 "arrived".  Receivers finalize with what they have — exactly the
 receiver-side semantics of the paper's §III-B — and recover through the
-Hadamard/XOR coding layer (:mod:`repro.core.coding`).
+Hadamard coding layer (:mod:`repro.core.coding`).
 
-Everything here is shard_map-compatible and lowers to plain
-``psum`` / ``all_gather`` / ``all_to_all`` HLOs plus elementwise masking,
-so the dry-run (16x16 and 2x16x16 meshes) sees ordinary TPU collectives.
-
-Provided:
-- :func:`lossy_psum` / :func:`lossy_pmean` — gradient AllReduce (DP).
-- :func:`lossy_all_gather` — TP gather with optional XOR parity repair.
-- :func:`lossy_all_to_all` — MoE dispatch; dropped blocks surface as an
-  arrival mask so the router can take the shared-expert fallback path.
-- exact twins (``exact_*``) with identical signatures for A/B runs.
+:func:`lossy_psum` is the coded AllReduce of one gradient leaf, the one
+the train step's dp-manual island runs per coded leaf.  It is
+shard_map-compatible and lowers to plain ``psum`` / ``pmax`` HLOs plus
+elementwise masking, so the dry-run (16x16 and 2x16x16 meshes) sees
+ordinary TPU collectives.
 """
 from __future__ import annotations
 
@@ -33,167 +28,75 @@ from repro.core import coding
 AxisNames = str | Sequence[str]
 
 
-def _axis_size(axis_name: AxisNames) -> int:
-    return shd.axis_size(axis_name)
-
-
-def _peer_key(key: jax.Array, axis_name: AxisNames) -> jax.Array:
-    """Fold the device's coordinate along ``axis_name`` into the key so
-    each peer samples an independent arrival mask (same key across the
-    rest of the mesh)."""
-    if isinstance(axis_name, str):
-        return jax.random.fold_in(key, jax.lax.axis_index(axis_name))
-    k = key
-    for a in axis_name:
-        k = jax.random.fold_in(k, jax.lax.axis_index(a))
-    return k
-
-
 def arrival_mask(key: jax.Array, n_rows: int, drop_rate: jax.Array) -> jax.Array:
     """Bernoulli(1 - drop_rate) per wire row: True = arrived in window."""
     return jax.random.uniform(key, (n_rows,)) >= drop_rate
 
 
-# ----------------------------------------------------------------------
-# AllReduce (data-parallel gradient sync)
-# ----------------------------------------------------------------------
+def _psum(x, axes):
+    with jax.named_scope("psum"):
+        return jax.lax.psum(x, axes)
 
-def lossy_psum(x: jax.Array, axis_name: AxisNames, *, key: jax.Array,
-               drop_rate: jax.Array, signs: jax.Array,
-               code: coding.HadamardCode,
-               use_pallas: bool = True,
-               quantize_wire: bool = False,
-               constrain=None, out_blocks: bool = False
+
+def quantize_rows(x: jax.Array, scale: jax.Array, noise: jax.Array
+                  ) -> jax.Array:
+    """Stochastic int8-range codes of (tiles, n_rot, Ns) wire tiles on
+    one grid per wire row: ``clip(floor(x / scale + noise), -127, 127)``
+    with ``scale`` (n_rot,) the row's absmax / 127 and ``noise`` uniform
+    on [0, 1), held in int16 so that a psum over up to 258 peers cannot
+    overflow."""
+    return jnp.clip(jnp.floor(x / scale[None, :, None] + noise),
+                    -127, 127).astype(jnp.int16)
+
+
+def lossy_psum(g: jax.Array, axis_name: AxisNames, *, plan: coding.NdPlan,
+               signs: jax.Array, key: jax.Array, leaf: int,
+               peer_id: jax.Array, drop_rate: jax.Array,
+               quantize_wire: bool = False, wire_dtype: str = "float32"
                ) -> tuple[jax.Array, jax.Array]:
-    """Best-effort AllReduce of a flat f32 payload.
+    """Best-effort AllReduce of gradient leaf number ``leaf``, ``g``,
+    over ``axis_name``.
 
-    Returns (unbiased sum estimate, realized received fraction).
-    ``signs``/``code`` must be identical on every participant.
+    Each peer encodes ``g`` (:func:`coding.encode_nd` under ``signs``
+    (n_rot,), the same on every peer), keeps the wire rows that its
+    arrival mask lets through, and the peers psum their tiles and their
+    masks; the decode unbiases by the arrivals.  The mask is drawn from
+    ``fold_in(fold_in(key, 2 * leaf + 1), peer_id)`` at ``drop_rate``;
+    ``peer_id`` is this shard's index along ``axis_name``, passed in as
+    data because ``axis_index`` does not lower under partial-auto
+    shard_map.  Returns ``(estimate of the sum of g over the peers, f32
+    in g's shape; arrivals per wire row (n_rot,))``.
 
-    ``quantize_wire=True`` additionally quantizes each peer's wire
-    contribution to absmax int8 per rotation block before the reduce
-    (``coding.encode_quantized`` — rotate and quantize fused in one
-    Pallas kernel), modeling a 4x-smaller collective payload; the
-    stochastic-rounding noise key is derived from ``key`` per peer, so
-    the ``False`` path's draws are untouched.
+    ``quantize_wire``: every peer's kept tiles share one scale per wire
+    row (a ``pmax`` of their absmax, n_rot scalars), are rounded by
+    :func:`quantize_rows` with noise from ``fold_in(key, 3 * leaf + 2)``
+    and summed in int16, half the collective bytes of f32.  Otherwise
+    the tiles travel in ``wire_dtype``.
     """
-    peers = _axis_size(axis_name)
+    peers = shd.axis_size(axis_name)
+    with jax.named_scope("encode"):
+        tiles = coding.encode_nd(g, signs, plan)
+    with jax.named_scope("mask"):
+        mask = arrival_mask(
+            jax.random.fold_in(jax.random.fold_in(key, 2 * leaf + 1),
+                               peer_id), plan.n_rot, drop_rate)
+        contrib = tiles * mask[None, :, None].astype(tiles.dtype)
     if quantize_wire:
-        nk = jax.random.fold_in(_peer_key(key, axis_name), 1)
-        q_wire, scales = coding.encode_quantized(
-            x, signs, code, nk, use_pallas=use_pallas, constrain=constrain)
-        wire = coding.dequantize_wire(q_wire, scales)
-        if constrain is not None:
-            wire = constrain(wire, "wire")
+        with jax.named_scope("psum"):
+            absmax = jax.lax.pmax(jnp.max(jnp.abs(contrib), axis=(0, 2)),
+                                  axis_name)
+        with jax.named_scope("encode"):
+            scale = jnp.where(absmax > 0, absmax / 127.0, 1.0)
+            noise = jax.random.uniform(
+                jax.random.fold_in(key, 3 * leaf + 2), contrib.shape)
+            q = quantize_rows(contrib, scale, noise)
+        tiles_sum = (_psum(q, axis_name).astype(jnp.float32)
+                     * scale[None, :, None])
     else:
-        wire = coding.encode(x, signs, code, use_pallas=use_pallas,
-                             constrain=constrain)
-    mask = arrival_mask(_peer_key(key, axis_name), code.n_rot, drop_rate)
-    contrib = wire * mask[:, None].astype(wire.dtype)
-    counts = mask.astype(jnp.float32)
-    wire_sum = jax.lax.psum(contrib, axis_name)
-    count_sum = jax.lax.psum(counts, axis_name)
-    est = coding.decode(wire_sum, count_sum, signs, code,
-                        total_peers=peers, use_pallas=use_pallas,
-                        constrain=constrain, out_blocks=out_blocks)
-    frac = jnp.sum(count_sum) / (peers * code.n_rot)
-    return est, frac
-
-
-def lossy_pmean(x: jax.Array, axis_name: AxisNames, **kw):
-    peers = _axis_size(axis_name)
-    s, frac = lossy_psum(x, axis_name, **kw)
-    return s / peers, frac
-
-
-def exact_psum(x: jax.Array, axis_name: AxisNames) -> jax.Array:
-    return jax.lax.psum(x, axis_name)
-
-
-def exact_pmean(x: jax.Array, axis_name: AxisNames) -> jax.Array:
-    return jax.lax.pmean(x, axis_name)
-
-
-# ----------------------------------------------------------------------
-# AllGather (tensor-parallel activations) with XOR parity repair
-# ----------------------------------------------------------------------
-
-def lossy_all_gather(x: jax.Array, axis_name: str, *, key: jax.Array,
-                     drop_rate: jax.Array, parity: bool = True,
-                     tiled: bool = False) -> tuple[jax.Array, jax.Array]:
-    """Best-effort AllGather of this shard.
-
-    Each peer's shard is one "chunk".  A dropped chunk is zero-filled;
-    when ``parity`` is on, an XOR parity chunk rides along (1/P bandwidth
-    overhead) and repairs any *single* lost shard exactly — the paper's
-    prioritized-data path for activations, where statistical tolerance
-    alone is weaker than for gradients.
-
-    Returns (gathered (P, ...) or tiled, arrived mask (P,)).
-    """
-    p = shd.axis_size(axis_name)
-    me = jax.lax.axis_index(axis_name)
-    mask = arrival_mask(_peer_key(key, axis_name), p, drop_rate)
-    arrived_here = mask[me]
-    contrib = jnp.where(arrived_here, x, jnp.zeros_like(x))
-    gathered = jax.lax.all_gather(contrib, axis_name)          # (P, ...)
-    arrived = jax.lax.all_gather(arrived_here, axis_name)      # (P,)
-    if parity:
-        flat = gathered.reshape(p, -1)
-        # parity of *all* shards is an XOR all-reduce of bit patterns;
-        # it rides along the same step (counts as collective bytes).
-        pbits = jax.lax.bitcast_convert_type(x.reshape(-1), jnp.int32)
-        parity_bits = _xor_allreduce(pbits, axis_name)
-        parity_chunk = jax.lax.bitcast_convert_type(parity_bits, jnp.float32)
-        flat = coding.xor_parity_decode(flat, parity_chunk, arrived)
-        gathered = flat.reshape(gathered.shape)
-    if tiled:
-        gathered = gathered.reshape((p * x.shape[0],) + x.shape[1:])
-    return gathered, arrived
-
-
-def _xor_allreduce(bits: jax.Array, axis_name: str) -> jax.Array:
-    """XOR all-reduce via gather+fold (XLA has no XOR all-reduce op)."""
-    g = jax.lax.all_gather(bits, axis_name)                    # (P, n)
-    return jax.lax.reduce(g, jnp.int32(0), jax.lax.bitwise_xor, (0,))
-
-
-def exact_all_gather(x: jax.Array, axis_name: str, *, tiled: bool = False):
-    return jax.lax.all_gather(x, axis_name, tiled=tiled)
-
-
-# ----------------------------------------------------------------------
-# All-to-All (expert-parallel dispatch)
-# ----------------------------------------------------------------------
-
-def lossy_all_to_all(x: jax.Array, axis_name: str, *, key: jax.Array,
-                     drop_rate: jax.Array,
-                     split_axis: int = 0, concat_axis: int = 0
-                     ) -> tuple[jax.Array, jax.Array]:
-    """Best-effort All-to-All.
-
-    ``x`` is split into P blocks along ``split_axis``; block j travels to
-    peer j.  Each (src, dst) block is dropped i.i.d. with ``drop_rate``.
-    Returns (received tensor with dropped blocks zeroed, arrival mask of
-    shape (P,) — True where the block from peer j arrived here).  The
-    MoE layer routes un-arrived tokens to the shared-expert fallback
-    (paper §II-B "expert fallback paths").
-    """
-    p = shd.axis_size(axis_name)
-    assert x.shape[split_axis] == p, (x.shape, split_axis, p)
-    # (src=me, dst=j) arrival coin for every destination block
-    mask_out = arrival_mask(_peer_key(key, axis_name), p, drop_rate)  # (P,)
-    shape = [1] * x.ndim
-    shape[split_axis] = p
-    masked = x * mask_out.reshape(shape).astype(x.dtype)
-    recv = jax.lax.all_to_all(masked, axis_name, split_axis=split_axis,
-                              concat_axis=concat_axis)
-    arrived = jax.lax.all_to_all(mask_out[:, None], axis_name,
-                                 split_axis=0, concat_axis=0)[:, 0]
-    return recv, arrived
-
-
-def exact_all_to_all(x: jax.Array, axis_name: str, *, split_axis: int = 0,
-                     concat_axis: int = 0) -> jax.Array:
-    return jax.lax.all_to_all(x, axis_name, split_axis=split_axis,
-                              concat_axis=concat_axis)
+        contrib = contrib.astype(jnp.dtype(wire_dtype))
+        tiles_sum = _psum(contrib, axis_name).astype(jnp.float32)
+    counts = _psum(mask.astype(jnp.float32), axis_name)
+    with jax.named_scope("decode"):
+        est = coding.decode_nd(tiles_sum, counts, signs, plan,
+                               total_peers=peers)
+    return est, counts

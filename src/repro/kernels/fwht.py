@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: blocked fast Walsh-Hadamard transform.
+"""Pallas TPU kernel: one peer's coded sync of a gradient leaf.
 
 TPU-native design (this is the HW adaptation of the paper's Hadamard
 recovery, which OptiReduce runs on GPU with CUDA butterflies):
@@ -14,24 +14,15 @@ recovery, which OptiReduce runs on GPU with CUDA butterflies):
   whole-chunk adds and subtracts on the VPU.  No op splits the lane
   dimension: every slice is a 128-lane-aligned window of the tile, which
   is what Mosaic lays out natively (an in-kernel reshape of the lane axis
-  to ``(a, b)`` with ``b < 128`` is refused by the TPU compiler).  Rows
-  of ``n <= 128`` are one chunk: a single ``X @ H_n`` matmul.
+  to ``(a, b)`` with ``b < 128`` is refused by the TPU compiler).
 
 - Grid tiles rows; each kernel instance holds a ``(block_rows, n)`` tile
-  plus the (128, 128) Hadamard factor in VMEM.  With ``block_rows=128``
-  and n=4096 (f32) the in/out tiles are 2 MiB each, double-buffered.
+  plus the (128, 128) Hadamard factor in VMEM.
 
-- The matmuls run at ``Precision.HIGHEST``: the factor is exactly +-1
-  (times a power-of-two scale for even log2(n)), so the f32 result
-  matches the jnp butterfly oracle to f32 rounding.
-
-``block_rows`` must be a multiple of 8 (f32 sublanes) or the whole row
-count; ops.py pads rows to a multiple of it.
-
-**Coded round trip** (``coded_roundtrip_pallas``): one peer's whole
-coded sync of a gradient leaf, ``D H diag(colscale) H D x`` per row, in
-one pass over HBM — the leaf is read once and written once in its own
-dtype (bf16 for the train step), and no f32 copy of it reaches HBM.
+``coded_roundtrip_pallas`` computes ``D H diag(colscale) H D x`` per
+row in one pass over HBM — the leaf is read once and written once in
+its own dtype (bf16 for the train step), and no f32 copy of it reaches
+HBM.
 Per ``(block_rows, n)`` tile in VMEM:
 
 1. multiply by the Rademacher signs in the leaf's dtype (exact);
@@ -67,24 +58,6 @@ from repro.kernels import ref
 LANES = 128
 
 
-def _rotate(x_ref, h_ref, signs_ref):
-    """The tile's rows, Hadamard-transformed: a list of (rows, w) f32
-    chunks, ``w = min(n, 128)``, in output order."""
-    n = x_ref.shape[1]
-    w = h_ref.shape[0]
-    h = h_ref[...]
-    chunks = []
-    for k in range(n // w):
-        xk = x_ref[:, k * w:(k + 1) * w].astype(jnp.float32)
-        if signs_ref is not None:
-            # fused Rademacher pre-multiply on the VMEM-resident tile
-            # instead of a separate HBM round trip before the transform
-            xk = xk * signs_ref[:, k * w:(k + 1) * w]
-        chunks.append(jnp.dot(xk, h, preferred_element_type=jnp.float32,
-                              precision=jax.lax.Precision.HIGHEST))
-    return _butterfly(chunks)
-
-
 def _butterfly(chunks):
     """The cross-chunk stage ``H_c``: log2(c) passes of whole-chunk adds
     and subtracts on the VPU (in place on the list, which it returns)."""
@@ -96,122 +69,6 @@ def _butterfly(chunks):
                 chunks[j], chunks[j + half] = a + b, a - b
         half *= 2
     return chunks
-
-
-def _fwht_kernel(x_ref, h_ref, *rest):
-    o_ref = rest[-1]
-    signs_ref = rest[0] if len(rest) == 2 else None
-    chunks = _rotate(x_ref, h_ref, signs_ref)
-    w = h_ref.shape[0]
-    for k, y in enumerate(chunks):
-        o_ref[:, k * w:(k + 1) * w] = y.astype(o_ref.dtype)
-
-
-def _fwht_quant_kernel(x_ref, h_ref, *rest):
-    q_ref, scale_ref = rest[-2:]
-    if len(rest) == 4:
-        signs_ref, noise_ref = rest[0], rest[1]
-    else:
-        signs_ref, noise_ref = None, rest[0]
-    chunks = _rotate(x_ref, h_ref, signs_ref)
-    w = h_ref.shape[0]
-    # quantize while the rotated tile is still in VMEM: the unfused
-    # pair writes the f32 rotation to HBM and reads it straight back —
-    # this kernel's whole point is skipping that round trip, leaving
-    # one f32 read (input) + one int8 write (output) per element
-    absmax = functools.reduce(
-        jnp.maximum,
-        [jnp.max(jnp.abs(y), axis=-1, keepdims=True) for y in chunks])
-    qscale = jnp.where(absmax > 0, absmax / 127.0, 1.0)
-    for k, y in enumerate(chunks):
-        sl = slice(k * w, (k + 1) * w)
-        q = jnp.floor(y / qscale + noise_ref[:, sl].astype(jnp.float32))
-        q_ref[:, sl] = jnp.clip(q, -127, 127).astype(jnp.int8)
-    scale_ref[...] = qscale
-
-
-def _rotate_specs(x, signs, scale, block_rows):
-    """BlockSpecs + operands shared by both kernels: the row tile, the
-    (w, w) Hadamard factor with ``scale`` folded in, optional signs."""
-    rows, n = x.shape
-    assert rows % block_rows == 0, (rows, block_rows)
-    w = min(n, LANES)
-    h = ref.hadamard_matrix(w) * jnp.float32(scale)
-    in_specs = [pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
-                pl.BlockSpec((w, w), lambda i: (0, 0))]
-    operands = [x, h]
-    if signs is not None:
-        in_specs.append(pl.BlockSpec((1, n), lambda i: (0, 0)))
-        operands.append(signs.reshape(1, n).astype(jnp.float32))
-    return in_specs, operands
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("block_rows", "interpret", "scale"))
-def fwht_quantize_pallas(x: jax.Array, noise: jax.Array,
-                         signs: jax.Array | None = None, *,
-                         scale: float = 1.0, block_rows: int = 128,
-                         interpret: bool):
-    """Fused FWHT + per-row absmax int8 quantization in one pass.
-
-    The rotate stage is exactly :func:`fwht_pallas` (same chunked
-    Kronecker body, same optional Rademacher/scale fusions); its VMEM
-    tile feeds the :mod:`quantize` stage directly.  Returns
-    ``(q int8 (rows, n), scale f32 (rows,))`` — the wire payload of
-    ``coding.encode_quantized``.
-    """
-    rows, n = x.shape
-    in_specs, operands = _rotate_specs(x, signs, scale, block_rows)
-    in_specs.append(pl.BlockSpec((block_rows, n), lambda i: (i, 0)))
-    operands.append(noise)
-    # the per-row scale leaves as a (rows, 1) column: a 1-D block of a
-    # longer 1-D array does not match the chip's HBM tiling
-    q, scale = pl.pallas_call(
-        _fwht_quant_kernel,
-        grid=(rows // block_rows,),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((rows, n), jnp.int8),
-            jax.ShapeDtypeStruct((rows, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*operands)
-    return q, scale[:, 0]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("block_rows", "interpret", "scale"))
-def fwht_pallas(x: jax.Array, signs: jax.Array | None = None, *,
-                scale: float = 1.0, block_rows: int = 128,
-                interpret: bool) -> jax.Array:
-    """FWHT along the last axis of a 2-D array via pallas_call.
-
-    ``x`` must be (rows, n) with n a power of two >= 2 and rows a
-    multiple of ``block_rows`` (ops.py handles padding).
-
-    Optional fusions (used by ``coding.encode``, which otherwise pays
-    two extra full HBM round-trips per call):
-
-    - ``signs`` (n,): Rademacher diagonal multiplied into the input tile
-      in VMEM before the transform;
-    - ``scale``: static scalar folded into the Hadamard factor (entries
-      become ±scale), so the normalization costs zero extra FLOPs on
-      the MXU path.
-    """
-    rows, n = x.shape
-    in_specs, operands = _rotate_specs(x, signs, scale, block_rows)
-    return pl.pallas_call(
-        _fwht_kernel,
-        grid=(rows // block_rows,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
-        interpret=interpret,
-    )(*operands)
 
 
 def _exact_dot(x, h):

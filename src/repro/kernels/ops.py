@@ -1,12 +1,10 @@
-"""Public jit'd wrappers around the Pallas kernels.
+"""Public wrappers around the Pallas kernels the program runs: the
+coded sync's ``coded_roundtrip``, JAX's megablox grouped matmul and its
+splash (flash) attention.
 
-Shape-polymorphic entry points: callers pass any (rows, n) with n a
-power of two; padding to kernel tile multiples happens here.  The
-kernels compile for the chip; on the CPU backend (tests, the dry-run
-host) they run in Pallas interpret mode instead, decided per call from
-``jax.default_backend()``.  ``use_pallas=False`` routes to the pure-jnp
-oracle (used by the dry-run lowering, where interpret-mode callbacks
-cannot be staged for a TPU mesh).
+The kernels compile for the chip; on the CPU backend (tests, the
+dry-run host) they run in Pallas interpret mode instead, decided per
+call from ``jax.default_backend()``.
 """
 from __future__ import annotations
 
@@ -19,65 +17,11 @@ from jax.experimental.pallas.ops.tpu.splash_attention import (
 )
 
 from repro.kernels import fwht as _fwht
-from repro.kernels import quantize as _quant
-from repro.kernels import ref
-from repro.kernels import unbias as _unbias
 
 
 def _interpret() -> bool:
     """Interpret the kernels only where there is no chip to compile for."""
     return jax.default_backend() == "cpu"
-
-
-def _pad_rows(x: jax.Array, mult: int) -> tuple[jax.Array, int]:
-    rows = x.shape[0]
-    pad = (-rows) % mult
-    if pad:
-        x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
-    return x, rows
-
-
-def fwht(x: jax.Array, *, signs: jax.Array | None = None, scale: float = 1.0,
-         use_pallas: bool = True, block_rows: int = 128) -> jax.Array:
-    """FWHT along the last axis of a 2-D array (unnormalized by default).
-
-    ``signs`` (n,) and ``scale`` fuse the Rademacher pre-multiply and
-    the normalization into the kernel (the Pallas path keeps them in
-    VMEM / folds the scale into a Hadamard factor); the jnp-oracle path
-    applies them unfused with identical semantics.
-    """
-    if not use_pallas:
-        out = ref.fwht(x if signs is None else x * signs[None, :])
-        return out if scale == 1.0 else out * scale
-    rows, n = x.shape
-    block_rows = min(block_rows, max(8, rows))
-    xp, rows0 = _pad_rows(x, block_rows)
-    out = _fwht.fwht_pallas(xp, signs, scale=scale, block_rows=block_rows,
-                            interpret=_interpret())
-    return out[:rows0]
-
-
-def fwht_quantize(x: jax.Array, noise: jax.Array, *,
-                  signs: jax.Array | None = None, scale: float = 1.0,
-                  use_pallas: bool = True, block_rows: int = 128):
-    """Fused rotate-then-quantize: the FWHT output feeds the per-row
-    absmax int8 quantizer without a round trip through HBM (what
-    ``coding.encode_quantized`` issues).  Semantically identical to
-    ``quantize_int8(fwht(x, signs=..., scale=...), noise)``.
-    """
-    if not use_pallas:
-        y = ref.fwht(x if signs is None else x * signs[None, :])
-        if scale != 1.0:
-            y = y * scale
-        return ref.quantize_int8(y, noise)
-    rows, n = x.shape
-    block_rows = min(block_rows, max(8, rows))
-    xp, rows0 = _pad_rows(x, block_rows)
-    np_, _ = _pad_rows(noise, block_rows)
-    q, s = _fwht.fwht_quantize_pallas(xp, np_, signs, scale=scale,
-                                      block_rows=block_rows,
-                                      interpret=_interpret())
-    return q[:rows0], s[:rows0]
 
 
 def coded_roundtrip(x: jax.Array, signs: jax.Array, colscale: jax.Array, *,
@@ -159,33 +103,3 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     out = jax.vmap(kernel)(qs.swapaxes(1, 2), k.swapaxes(1, 2),
                            v.swapaxes(1, 2))
     return out.swapaxes(1, 2)
-
-
-def quantize_int8(x: jax.Array, noise: jax.Array, *, use_pallas: bool = True,
-                  block_rows: int = 128):
-    if not use_pallas:
-        return ref.quantize_int8(x, noise)
-    rows, n = x.shape
-    block_rows = min(block_rows, max(8, rows))
-    xp, rows0 = _pad_rows(x, block_rows)
-    np_, _ = _pad_rows(noise, block_rows)
-    q, scale = _quant.quantize_int8_pallas(xp, np_, block_rows=block_rows,
-                                           interpret=_interpret())
-    return q[:rows0], scale[:rows0]
-
-
-def dequantize_int8(q: jax.Array, scale: jax.Array) -> jax.Array:
-    return ref.dequantize_int8(q, scale)
-
-
-def masked_unbias(y_sum: jax.Array, counts: jax.Array, total: int, *,
-                  use_pallas: bool = True, block_rows: int = 256) -> jax.Array:
-    if not use_pallas:
-        return ref.masked_unbias(y_sum, counts, total)
-    rows, n = y_sum.shape
-    block_rows = min(block_rows, max(8, rows))
-    yp, rows0 = _pad_rows(y_sum, block_rows)
-    cp, _ = _pad_rows(counts, block_rows)
-    out = _unbias.masked_unbias_pallas(yp, cp, total=total,
-                                       block_rows=block_rows, interpret=_interpret())
-    return out[:rows0]

@@ -1,4 +1,4 @@
-"""Pure-jnp oracles for every Pallas kernel in this package."""
+"""Pure-jnp oracles for the Pallas kernels in this package."""
 from __future__ import annotations
 
 import jax
@@ -35,37 +35,6 @@ def fwht(x: jax.Array) -> jax.Array:
         x = jnp.stack([a + b, a - b], axis=2).reshape(-1, n)
         m *= 2
     return x.reshape(orig_shape)
-
-
-def quantize_int8(x: jax.Array, noise: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Per-row absmax int8 stochastic quantization.
-
-    ``noise`` is uniform[0,1) with the same shape as ``x`` (supplied by the
-    caller so that the kernel and the oracle consume identical bits).
-    Returns (q_int8, scale_per_row).
-    """
-    absmax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
-    scale = jnp.where(absmax > 0, absmax / 127.0, 1.0)
-    scaled = x / scale
-    q = jnp.floor(scaled + noise)              # stochastic rounding
-    q = jnp.clip(q, -127, 127).astype(jnp.int8)
-    return q, scale[..., 0]
-
-
-def dequantize_int8(q: jax.Array, scale: jax.Array) -> jax.Array:
-    return q.astype(jnp.float32) * scale[..., None]
-
-
-def masked_unbias(y_sum: jax.Array, counts: jax.Array, total: int) -> jax.Array:
-    """Decode-side unbiasing: scale received sums by total/count (0 where none).
-
-    ``y_sum``  (rows, n): summed received contributions.
-    ``counts`` (rows,) or (rows, n): how many contributions arrived.
-    """
-    if counts.ndim == y_sum.ndim - 1:
-        counts = counts[..., None]
-    safe = jnp.maximum(counts, 1)
-    return jnp.where(counts > 0, y_sum * (total / safe), 0.0)
 
 
 def coded_roundtrip(x: jax.Array, signs: jax.Array,

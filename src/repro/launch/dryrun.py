@@ -95,7 +95,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
                                         mesh, jax.sharding.PartitionSpec()))
         step_fn = ts.make_train_step(
             cfg, mesh, adamw.OptConfig(),
-            ts.CelerisConfig(enabled=celeris,
+            ts.CelerisConfig(mode="lossy_hadamard" if celeris else "exact",
                              lossy_moe=celeris and cfg.moe is not None,
                              quantize_wire=quantize_wire),
             donate=True, microbatches=micro)

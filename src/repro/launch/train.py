@@ -58,8 +58,9 @@ def main():
         data_cfg=DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq_len,
                             global_batch=args.global_batch),
         opt_cfg=OptConfig(lr=args.lr, total_steps=args.steps),
-        celeris=CelerisConfig(enabled=args.celeris,
-                              lossy_moe=args.lossy_moe),
+        celeris=CelerisConfig(
+            mode="lossy_hadamard" if args.celeris else "exact",
+            lossy_moe=args.lossy_moe),
         mesh=mesh, ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every)
     tr.run(args.steps, on_metrics=lambda s, m: print(
         f"step {s:4d} loss {m['loss']:.4f} nll {m['nll']:.4f} "

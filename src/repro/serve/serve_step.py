@@ -91,7 +91,7 @@ def greedy_generate(cfg: ModelConfig, params, prompt: jax.Array,
 # ----------------------------------------------------------------------
 
 def kv_wire_roundtrip(flat: jax.Array, mask: jax.Array, signs: jax.Array,
-                      code: coding.HadamardCode, *, coded: bool = True
+                      plan: coding.NdPlan, *, coded: bool = True
                       ) -> jax.Array:
     """One flat KV payload through the wire: encode (or just block),
     drop the wire rows where ``mask`` is 0, decode.
@@ -102,9 +102,10 @@ def kv_wire_roundtrip(flat: jax.Array, mask: jax.Array, signs: jax.Array,
     either way; the two layouts differ in what a block *carries*:
 
     - ``coded=True``: block ``j`` is wire row ``j`` of the Hadamard
-      layout — coordinate ``j`` of every rotation block.  Lost rows
-      are unbiased over by ``core.coding.decode``, so the damage is
-      small dense noise spread across the entire payload.
+      layout — coordinate ``j`` of every rotation block
+      (``coding.encode_nd``).  Lost rows are unbiased over by
+      ``coding.decode_nd``, so the damage is small dense noise spread
+      across the entire payload.
     - ``coded=False``: block ``j`` is the ``j``-th *contiguous chunk*
       of the raw payload (how an uncoded sender packs KV).  Lost
       chunks are holes: whole spans of cache positions zeroed —
@@ -112,13 +113,11 @@ def kv_wire_roundtrip(flat: jax.Array, mask: jax.Array, signs: jax.Array,
     """
     mask = mask.astype(flat.dtype)
     if coded:
-        wire = coding.encode(flat, signs, code, use_pallas=False)
-        wire = wire * mask[:, None]
-        return coding.decode(wire, mask, signs, code, total_peers=1,
-                             use_pallas=False)
-    x = jnp.pad(flat.reshape(-1), (0, code.padded_len - code.orig_len))
-    chunks = x.reshape(code.n_rot, code.n_blocks) * mask[:, None]
-    return chunks.reshape(-1)[: code.orig_len]
+        tiles = coding.encode_nd(flat, signs, plan) * mask[None, :, None]
+        return coding.decode_nd(tiles, mask, signs, plan, total_peers=1)
+    x = jnp.pad(flat, (0, plan.tiles * plan.n_rot - plan.m_orig))
+    chunks = x.reshape(plan.n_rot, plan.tiles) * mask[:, None]
+    return chunks.reshape(-1)[: plan.m_orig]
 
 
 def degrade_caches(caches, mask: jax.Array, key: jax.Array, *,
@@ -134,14 +133,14 @@ def degrade_caches(caches, mask: jax.Array, key: jax.Array, *,
     must agree on it, exactly like the trainer's coded all-reduce.
     """
     def _ship(leaf):
-        code = coding.plan(int(leaf.size), n_rot=int(mask.shape[0]))
-        if code.n_rot != int(mask.shape[0]):
+        plan = coding.plan_nd((int(leaf.size),), None, int(mask.shape[0]))
+        if plan.n_rot != int(mask.shape[0]):
             raise ValueError(
                 f"KV leaf of {leaf.size} elements cannot carry a "
-                f"{mask.shape[0]}-row wire mask (plan chose {code.n_rot})")
-        signs = coding.rademacher(key, code)
+                f"{mask.shape[0]}-row wire mask (plan chose {plan.n_rot})")
+        signs = coding.rademacher_nd(key, plan)
         out = kv_wire_roundtrip(leaf.reshape(-1).astype(jnp.float32),
-                                mask, signs, code, coded=coded)
+                                mask, signs, plan, coded=coded)
         return out.reshape(leaf.shape).astype(leaf.dtype)
 
     def _one(node):

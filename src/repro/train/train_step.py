@@ -91,20 +91,17 @@ from repro.train import sharding_rules as rules
 @dataclasses.dataclass(frozen=True)
 class CelerisConfig:
     """Celeris integration knobs for training."""
-    enabled: bool = False            # legacy switch: True == lossy_hadamard
-    mode: str | CollectiveMode | None = None
+    mode: str | CollectiveMode = "exact"
                                      # "exact" | "lossy" | "lossy_hadamard"
-                                     # | "hierarchical"; None defers to
-                                     # ``enabled``.  "lossy" is the uncoded
-                                     # ablation: dropped wire rows stay
-                                     # dropped, so the Fig.-1 A/B isolates
-                                     # what the Hadamard layer buys.
-                                     # "hierarchical" needs a 'pod' mesh
-                                     # axis and a (2,) [intra, cross] drop
-                                     # input (coupling.AxisSchedules).
+                                     # | "hierarchical".  "lossy" is the
+                                     # uncoded ablation: dropped wire rows
+                                     # stay dropped, so the Fig.-1 A/B
+                                     # isolates what the Hadamard layer
+                                     # buys.  "hierarchical" needs a 'pod'
+                                     # mesh axis and a (2,) [intra, cross]
+                                     # drop input (coupling.AxisSchedules).
     lossy_moe: bool = False          # lossy expert-parallel All-to-All
     n_rot: int = 4096                # Hadamard rotation width
-    use_pallas: bool = False         # FWHT via Pallas kernel (TPU) vs jnp
     min_coded_size: int = 65536      # leaves smaller than this sync exactly
     wire_dtype: str = "float32"      # collective payload dtype.  H3: set
                                      # "bfloat16" on TPU to halve DP sync
@@ -115,8 +112,9 @@ class CelerisConfig:
     quantize_wire: bool = False      # H6 (beyond-paper): int8-quantized
                                      # wire with shared per-row scales,
                                      # summed over dp in int16 -> 2x fewer
-                                     # collective bytes than f32 (max peer
-                                     # sum 16*127 < 2^15).  Composes with
+                                     # collective bytes than f32 (a sum
+                                     # over up to 258 peers fits: 258*127
+                                     # < 2^15).  Composes with
                                      # the Hadamard rotation (QSGD-style:
                                      # rotation whitens the per-row range
                                      # so one scale fits all peers).
@@ -127,10 +125,7 @@ class CelerisConfig:
                                      # full precision.
 
     def collective_mode(self) -> CollectiveMode:
-        if self.mode is not None:
-            return CollectiveMode.parse(self.mode)
-        return (CollectiveMode.LOSSY_HADAMARD if self.enabled
-                else CollectiveMode.EXACT)
+        return CollectiveMode.parse(self.mode)
 
 
 def _pmean32(g, axes):
@@ -155,7 +150,8 @@ def _dp_size(dp, mesh):
 
 def _leaf_mask(key, i, peer_id, n_rot, drop_rate):
     """Per-(leaf, peer) arrival mask.  ``peer_id`` is this shard's index
-    along the dp axes (a P(dp)-sharded arange fed into the island)."""
+    along the dp axes (a P(dp)-sharded arange fed into the island); the
+    same stream as ``lc.lossy_psum``'s masks."""
     k = jax.random.fold_in(jax.random.fold_in(key, 2 * i + 1), peer_id)
     return lc.arrival_mask(k, n_rot, drop_rate)
 
@@ -186,33 +182,12 @@ def _sync_grads_celeris(grads, dp, plans, key, drop_rate, celeris, mesh,
         with jax.named_scope("encode"):
             signs = coding.rademacher_nd(jax.random.fold_in(key, 2 * i),
                                          plan)
-            tiles = coding.encode_nd(g, signs, plan)
-        with jax.named_scope("mask"):
-            mask = _leaf_mask(key, i, peer_id, plan.n_rot, drop_rate)
-            contrib = tiles * mask[None, :, None].astype(tiles.dtype)
-        if celeris.quantize_wire:
-            # shared scale per wire row: psum-max of |contrib| so every
-            # peer's int8 payload lives on one grid (tiny f32 pre-pass:
-            # n_rot scalars per leaf)
-            with jax.named_scope("psum"):
-                absmax = jax.lax.pmax(
-                    jnp.max(jnp.abs(contrib), axis=(0, 2)), lossy_axes)
-            with jax.named_scope("encode"):
-                scale = jnp.where(absmax > 0, absmax / 127.0, 1.0)
-                noise = jax.random.uniform(
-                    jax.random.fold_in(key, 3 * i + 2), contrib.shape)
-                q = jnp.clip(jnp.floor(contrib / scale[None, :, None]
-                                       + noise),
-                             -127, 127).astype(jnp.int16)
-            tiles_sum = (_psum(q, lossy_axes).astype(jnp.float32)
-                         * scale[None, :, None])
-        else:
-            contrib = contrib.astype(jnp.dtype(celeris.wire_dtype))
-            tiles_sum = _psum(contrib, lossy_axes).astype(jnp.float32)
-        counts = _psum(mask.astype(jnp.float32), lossy_axes)
+        est, counts = lc.lossy_psum(
+            g, lossy_axes, plan=plan, signs=signs, key=key, leaf=i,
+            peer_id=peer_id,
+            drop_rate=drop_rate, quantize_wire=celeris.quantize_wire,
+            wire_dtype=celeris.wire_dtype)
         with jax.named_scope("decode"):
-            est = coding.decode_nd(tiles_sum, counts, signs, plan,
-                                   total_peers=n_lossy)
             out.append((est / n_lossy).astype(g.dtype))
             fracs.append(jnp.sum(counts) / (n_lossy * plan.n_rot))
     frac = jnp.stack(fracs).mean() if fracs else jnp.float32(1.0)

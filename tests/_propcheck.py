@@ -18,6 +18,7 @@ are exercised on every run.
 from __future__ import annotations
 
 import functools
+import inspect
 import zlib
 
 import numpy as np
@@ -71,6 +72,13 @@ class _HypothesisModule:
     @staticmethod
     def given(*strategies: _Strategy):
         def deco(fn):
+            # as in hypothesis, the strategies fill the rightmost
+            # parameters; the rest stay visible to pytest (parametrize)
+            sig = inspect.signature(fn)
+            names = list(sig.parameters)
+            kept, drawn = (names[:len(names) - len(strategies)],
+                           names[len(names) - len(strategies):])
+
             @functools.wraps(fn)
             def wrapper(*args, **kwargs):
                 # settings() may sit above given() (decorating wrapper)
@@ -91,10 +99,12 @@ class _HypothesisModule:
                 while len(cases) < n:
                     cases.append(tuple(s.draw(rng) for s in strategies))
                 for case in cases[:max(n, n_ep)]:
-                    fn(*args, *case, **kwargs)
+                    fn(*args, **kwargs, **dict(zip(drawn, case)))
             # pytest follows __wrapped__ when introspecting the signature
-            # and would treat the original parameters as fixtures
+            # and would treat the drawn parameters as fixtures
             del wrapper.__wrapped__
+            wrapper.__signature__ = sig.replace(
+                parameters=[sig.parameters[k] for k in kept])
             return wrapper
         return deco
 

@@ -1,5 +1,5 @@
 """Transport→trainer coupling layer (engine-derived drop schedules,
-CollectiveMode dispatch, sharded encode→lossy_psum→decode roundtrip)."""
+CollectiveMode dispatch, the sharded coded lossy_psum roundtrip)."""
 import os
 import subprocess
 import sys
@@ -113,17 +113,17 @@ def test_celeris_config_mode_resolution():
     from repro.train.train_step import CelerisConfig
     CM = coupling.CollectiveMode
     assert CelerisConfig().collective_mode() is CM.EXACT
-    assert CelerisConfig(enabled=True).collective_mode() is CM.LOSSY_HADAMARD
     assert CelerisConfig(mode="lossy").collective_mode() is CM.LOSSY
-    # explicit mode wins over the legacy switch
-    assert (CelerisConfig(enabled=True, mode="exact").collective_mode()
-            is CM.EXACT)
+    assert (CelerisConfig(mode="lossy+hadamard").collective_mode()
+            is CM.LOSSY_HADAMARD)
+    assert (CelerisConfig(mode=CM.HIERARCHICAL).collective_mode()
+            is CM.HIERARCHICAL)
 
 
 # ------------------------------------- sharded roundtrip (8-device mesh)
 
 def test_sharded_lossy_psum_roundtrip_engine_rate():
-    """encode → lossy_psum → decode on an 8-device mesh, drop rate taken
+    """The coded lossy_psum on an 8-device mesh, drop rate taken
     from an engine schedule, vs the single-device exact sum: zero-drop
     agrees to the coding tolerance (2e-3, see tests/test_coding.py);
     at the engine's realized rate the unbiased estimate stays within
@@ -139,23 +139,23 @@ def test_sharded_lossy_psum_roundtrip_engine_rate():
         from repro.core import coding, lossy_collectives as lc
         mesh = shd.make_mesh((8,), ('data',))
         N = 5000
-        code = coding.plan(N)
-        signs = coding.rademacher(jax.random.PRNGKey(7), code)
+        plan = coding.plan_nd((N,), None)
+        signs = coding.rademacher_nd(jax.random.PRNGKey(7), plan)
         xs = jax.random.normal(jax.random.PRNGKey(0), (8, N))
-        def f(x, key, p):
-            est, frac = lc.lossy_psum(x[0], 'data', key=key, drop_rate=p,
-                                      signs=signs, code=code,
-                                      use_pallas=False)
-            return est[None], frac[None]
-        sm = shd.shard_map(f, mesh=mesh, in_specs=(P('data', None), P(), P()),
-                           out_specs=(P('data', None), P('data')),
-                           check_vma=False)
+        def f(x, key, p, peer):
+            est, counts = lc.lossy_psum(x[0], 'data', plan=plan, signs=signs,
+                                        key=key, leaf=0, peer_id=peer[0],
+                                        drop_rate=p)
+            return est[None], (jnp.sum(counts) / (8 * plan.n_rot))[None]
+        sm = jax.jit(lambda x, key, p: shd.shard_map(
+            f, mesh=mesh, in_specs=(P('data', None), P(), P(), P('data')),
+            out_specs=(P('data', None), P('data')), check_vma=False,
+        )(x, key, p, jnp.arange(8)))
         exact = np.asarray(xs.sum(0))
-        est0, _ = jax.jit(sm)(xs, jax.random.PRNGKey(1), jnp.float32(0.0))
+        est0, _ = sm(xs, jax.random.PRNGKey(1), jnp.float32(0.0))
         np.testing.assert_allclose(np.asarray(est0[0]), exact,
                                    rtol=2e-3, atol=2e-3)
-        est, frac = jax.jit(sm)(xs, jax.random.PRNGKey(2),
-                                jnp.float32({drop}))
+        est, frac = sm(xs, jax.random.PRNGKey(2), jnp.float32({drop}))
         assert abs(float(frac[0]) - (1 - {drop})) < 0.05, float(frac[0])
         rel = (np.linalg.norm(np.asarray(est[0]) - exact)
                / np.linalg.norm(exact))

@@ -25,29 +25,36 @@ def _run(code: str, devices: int = 8, timeout: int = 420):
     return r.stdout
 
 
-def test_lossy_psum_zero_drop_equals_exact():
-    out = _run("""
+# the train step's per-leaf coded psum over 8 peers: leaf 0 of shape
+# (N,), sum estimate and received fraction per shard
+PSUM = """
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro import sharding as shd
         from repro.core import coding, lossy_collectives as lc
         mesh = shd.make_mesh((8,), ('data',))
         N = 5000
-        code = coding.plan(N)
-        signs = coding.rademacher(jax.random.PRNGKey(7), code)
+        plan = coding.plan_nd((N,), None)
+        signs = coding.rademacher_nd(jax.random.PRNGKey(7), plan)
         xs = jax.random.normal(jax.random.PRNGKey(0), (8, N))
-        def f(x, key, p):
-            est, frac = lc.lossy_psum(x[0], 'data', key=key, drop_rate=p,
-                                      signs=signs, code=code,
-                                      use_pallas=False)
-            return est[None], frac[None]
-        sm = shd.shard_map(f, mesh=mesh, in_specs=(P('data', None), P(), P()),
-                           out_specs=(P('data', None), P('data')),
-                           check_vma=False)
-        est, frac = jax.jit(sm)(xs, jax.random.PRNGKey(1), jnp.float32(0.0))
+        def f(x, key, p, peer):
+            est, counts = lc.lossy_psum(x[0], 'data', plan=plan, signs=signs,
+                                        key=key, leaf=0, peer_id=peer[0],
+                                        drop_rate=p, quantize_wire=QUANTIZE)
+            return est[None], (jnp.sum(counts) / (8 * plan.n_rot))[None]
+        sm = jax.jit(lambda x, key, p: shd.shard_map(
+            f, mesh=mesh, in_specs=(P('data', None), P(), P(), P('data')),
+            out_specs=(P('data', None), P('data')), check_vma=False,
+        )(x, key, p, jnp.arange(8)))
+"""
+
+
+def test_lossy_psum_zero_drop_equals_exact():
+    out = _run(PSUM.replace("QUANTIZE", "False") + """
+        est, frac = sm(xs, jax.random.PRNGKey(1), jnp.float32(0.0))
         np.testing.assert_allclose(np.asarray(est[0]), np.asarray(xs.sum(0)),
                                    rtol=2e-3, atol=2e-3)
-        est5, frac5 = jax.jit(sm)(xs, jax.random.PRNGKey(2), jnp.float32(0.05))
+        est5, frac5 = sm(xs, jax.random.PRNGKey(2), jnp.float32(0.05))
         assert abs(float(frac5[0]) - 0.95) < 0.04
         rel = np.linalg.norm(np.asarray(est5[0] - xs.sum(0)))
         rel /= np.linalg.norm(np.asarray(xs.sum(0)))
@@ -73,7 +80,7 @@ def test_celeris_train_on_mesh_learns():
         st = ts.init_state(jax.random.PRNGKey(0), cfg)
         st = jax.device_put(st, ts.state_shardings(st, mesh))
         fn = ts.make_train_step(cfg, mesh, OptConfig(lr=1e-3),
-                                ts.CelerisConfig(enabled=True,
+                                ts.CelerisConfig(mode="lossy_hadamard",
                                                  min_coded_size=1024))
         losses = []
         for i in range(14):
@@ -161,28 +168,12 @@ def test_elastic_restart_across_meshes(tmp_path):
 
 
 def test_lossy_psum_quantized_wire_close_to_f32():
-    """quantize_wire=True (fused rotate+quantize int8 wire) stays an
-    unbiased-ish estimate: zero-drop reduce matches the exact sum to
+    """quantize_wire=True (the trainer's wire: a pmax-shared scale per
+    wire row, stochastic floor, int16 psum) stays an unbiased-ish
+    estimate: the zero-drop reduce matches the exact sum to
     quantization tolerance."""
-    out = _run("""
-        import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import PartitionSpec as P
-        from repro import sharding as shd
-        from repro.core import coding, lossy_collectives as lc
-        mesh = shd.make_mesh((8,), ('data',))
-        N = 5000
-        code = coding.plan(N)
-        signs = coding.rademacher(jax.random.PRNGKey(7), code)
-        xs = jax.random.normal(jax.random.PRNGKey(0), (8, N))
-        def f(x, key, p):
-            est, frac = lc.lossy_psum(x[0], 'data', key=key, drop_rate=p,
-                                      signs=signs, code=code,
-                                      use_pallas=False, quantize_wire=True)
-            return est[None], frac[None]
-        sm = shd.shard_map(f, mesh=mesh, in_specs=(P('data', None), P(), P()),
-                           out_specs=(P('data', None), P('data')),
-                           check_vma=False)
-        est, frac = jax.jit(sm)(xs, jax.random.PRNGKey(1), jnp.float32(0.0))
+    out = _run(PSUM.replace("QUANTIZE", "True") + """
+        est, frac = sm(xs, jax.random.PRNGKey(1), jnp.float32(0.0))
         assert float(frac[0]) == 1.0
         want = np.asarray(xs.sum(0))
         err = np.linalg.norm(np.asarray(est[0]) - want) / np.linalg.norm(want)

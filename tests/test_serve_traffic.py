@@ -103,7 +103,6 @@ def test_kv_hole_masks_seeded_and_calibrated():
 
 # ----------------------------------------------- degraded-KV decode
 
-@pytest.mark.slow
 def test_kv_wire_roundtrip_exact_and_coded_beats_uncoded():
     """Full mask -> bitwise-faithful roundtrip both ways; lossy mask ->
     the coded layout keeps more usable context than contiguous chunks
@@ -115,12 +114,12 @@ def test_kv_wire_roundtrip_exact_and_coded_beats_uncoded():
 
     n_rot = 64
     x = jax.random.normal(jax.random.PRNGKey(0), (n_rot * 37,))
-    code = coding.plan(int(x.size), n_rot=n_rot)
-    signs = coding.rademacher(jax.random.PRNGKey(1), code)
+    plan = coding.plan_nd((int(x.size),), None, n_rot)
+    signs = coding.rademacher_nd(jax.random.PRNGKey(1), plan)
 
     full = jnp.ones(n_rot)
     for coded in (True, False):
-        y = serve_step.kv_wire_roundtrip(x, full, signs, code, coded=coded)
+        y = serve_step.kv_wire_roundtrip(x, full, signs, plan, coded=coded)
         np.testing.assert_allclose(np.asarray(y), np.asarray(x),
                                    atol=1e-5)
 
@@ -132,7 +131,7 @@ def test_kv_wire_roundtrip_exact_and_coded_beats_uncoded():
     # usable-context metric is per-position relative L2 (fig8's TAU)
     usable = {}
     for coded in (True, False):
-        y = serve_step.kv_wire_roundtrip(x, mask, signs, code, coded=coded)
+        y = serve_step.kv_wire_roundtrip(x, mask, signs, plan, coded=coded)
         d = np.asarray(y - x).reshape(n_rot, -1)
         r = np.asarray(x).reshape(n_rot, -1)
         rel = np.linalg.norm(d, axis=1) / np.linalg.norm(r, axis=1)

@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import fwht, quantize, unbias
+from repro.kernels import fwht
 
 ROWS = 256
 
@@ -46,26 +46,12 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
-KERNELS = {
-    "fwht": lambda x, z, s, n: fwht.fwht_pallas(x, interpret=False),
-    "fwht_signs_scale": lambda x, z, s, n: fwht.fwht_pallas(
-        x, s, scale=n ** -0.5, interpret=False),
-    "fwht_quantize": lambda x, z, s, n: fwht.fwht_quantize_pallas(
-        x, z, interpret=False),
-    "fwht_quantize_signs_scale": lambda x, z, s, n: fwht.fwht_quantize_pallas(
-        x, z, s, scale=n ** -0.5, interpret=False),
-    "quantize_int8": lambda x, z, s, n: quantize.quantize_int8_pallas(
-        x, z, interpret=False),
-    "masked_unbias": lambda x, z, s, n: unbias.masked_unbias_pallas(
-        x, z[:, 0], total=4, interpret=False),
-    # the train step's one-chip coded sync: a leaf's rows, bf16 or f32,
-    # at the default tile (which has to fit the scoped VMEM)
-    "coded_roundtrip": lambda x, z, s, n: fwht.coded_roundtrip_pallas(
-        x.astype(jnp.bfloat16), s, z[0], interpret=False),
-    "coded_roundtrip_f32": lambda x, z, s, n: fwht.coded_roundtrip_pallas(
-        x, s, z[0], interpret=False),
-}
-CASES = [(name, 4096) for name in KERNELS] + [("fwht", 1024)]
+# the train step's one-chip coded sync: a leaf's rows, bf16 or f32, at
+# every rotation width a fused leaf can have and the default tile (which
+# has to fit the scoped VMEM)
+KERNELS = {"coded_roundtrip": jnp.bfloat16, "coded_roundtrip_f32": jnp.float32}
+CASES = [(name, n) for name in KERNELS
+         for n in (128, 256, 512, 1024, 2048, 4096)]
 
 
 @pytest.mark.parametrize("name,n", CASES)
@@ -73,9 +59,11 @@ def test_kernel_compiles_for_v5e(one_chip, name, n):
     def sds(shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
 
-    fn = KERNELS[name]
-    compiled = _compile(lambda x, z, s: fn(x, z, s, n),
-                        sds((ROWS, n)), sds((ROWS, n)), sds((n,)))
+    def fn(x, signs, colscale):
+        return fwht.coded_roundtrip_pallas(x.astype(KERNELS[name]), signs,
+                                           colscale, interpret=False)
+
+    compiled = _compile(fn, sds((ROWS, n)), sds((n,)), sds((n,)))
     assert "tpu_custom_call" in compiled.as_text()
 
 

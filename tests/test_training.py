@@ -94,12 +94,13 @@ def test_lossy_training_parity_small_drop():
     within noise of lossless (single-device: drop applies to MoE path /
     degenerate dp, so this mainly checks plumbing + stability)."""
     h_exact = _trainer(seed=3).run(25)
-    h_lossy = _trainer(seed=3, celeris=CelerisConfig(enabled=True)).run(25)
+    h_lossy = _trainer(seed=3, celeris=CelerisConfig(
+        mode="lossy_hadamard")).run(25)
     assert abs(h_lossy["loss"][-1] - h_exact["loss"][-1]) < 0.3
 
 
 def test_trainer_timeout_adapts():
-    t = _trainer(celeris=CelerisConfig(enabled=True))
+    t = _trainer(celeris=CelerisConfig(mode="lossy_hadamard"))
     h = t.run(10)
     assert all(0.5 <= x <= 8.0 for x in h["timeout"])
 
