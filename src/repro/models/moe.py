@@ -12,7 +12,10 @@ Design (TPU/XLA-friendly — every shape static):
   order), gathered, and each expert's contiguous group runs through its
   SwiGLU as grouped matmuls with the per-expert row counts; the rows are
   put back in token order and summed with their gates.  No capacity:
-  every routed row is computed.  With no mesh set the grouped matmuls
+  every routed row is computed.  Both permutes are gathers whose
+  backward is a gather by the inverse permutation (custom VJPs), and
+  the indices come from sorts and a one-hot count: no scatter runs,
+  forward or backward.  With no mesh set the grouped matmuls
   are ``kernels.ops.grouped_matmul`` (the Pallas megablox kernel); on a
   mesh (a dp-only mesh, the dp-manual train island, decode on a
   model-sharded mesh) they are ``jax.lax.ragged_dot``, plain XLA that
@@ -45,6 +48,7 @@ dummy experts are unroutable: router logits forced to -inf).
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -187,28 +191,86 @@ def _scatter_combine(x2d, top_p, flat, slot, keep, out_buf, cap):
     return got.reshape(g, k, d).sum(1)
 
 
+def _sort_by_expert(flat: jax.Array, e_pad: int):
+    """(G*k,) expert ids -> (order, back, sizes): the stable sort of the
+    routed rows by expert, its inverse permutation and each expert's
+    row count, with no scatter."""
+    order = jnp.argsort(flat, stable=True)     # rows grouped by expert
+    back = jnp.argsort(order)                  # back[order[i]] = i
+    sizes = jnp.sum(flat[:, None] == jnp.arange(e_pad, dtype=flat.dtype),
+                    axis=0, dtype=jnp.int32)
+    return order, back, sizes
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch_rows(x2d, order, back, k):
+    """Each token's row once per pick, grouped by expert:
+    ``x2d[order // k]`` (G*k, d).  Its backward gathers the rows'
+    cotangents back to token order by ``back`` and sums each token's k
+    in float32, where JAX would transpose the gather to a scatter-add."""
+    return jnp.take(x2d, order // k, axis=0)
+
+
+def _dispatch_rows_fwd(x2d, order, back, k):
+    return _dispatch_rows(x2d, order, back, k), back
+
+
+def _dispatch_rows_bwd(k, back, drows):
+    g = back.shape[0] // k
+    dx = jnp.take(drows, back, axis=0).reshape(g, k, -1)
+    return dx.astype(jnp.float32).sum(1).astype(drows.dtype), None, None
+
+
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine_rows(out, top_p, order, back):
+    """The experts' rows (G*k, d) back in token order, summed with their
+    gates ``top_p`` (G, k) in float32: (G, d) in out's dtype.  Its
+    backward gathers too: row ``i``'s cotangent is its token's,
+    ``order[i] // k``, times its gate, and each gate's is its row's dot
+    with its token's."""
+    return _combine_rows_fwd(out, top_p, order, back)[0]
+
+
+def _combine_rows_fwd(out, top_p, order, back):
+    g, k = top_p.shape
+    got = jnp.take(out, back, axis=0).reshape(g, k, -1)
+    y = jnp.einsum("gkd,gk->gd", got.astype(jnp.float32), top_p)
+    return y.astype(out.dtype), (got, top_p, order)
+
+
+def _combine_rows_bwd(res, dy):
+    got, top_p, order = res
+    k = top_p.shape[1]
+    gate = jnp.take(top_p.reshape(-1), order)
+    d_out = (jnp.take(dy, order // k, axis=0).astype(jnp.float32)
+             * gate[:, None])
+    d_top_p = jnp.einsum("gkd,gd->gk", got.astype(jnp.float32),
+                         dy.astype(jnp.float32))
+    return d_out.astype(got.dtype), d_top_p, None, None
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
 def _moe_dropless(p, cfg, x2d, e_pad, grouped, routes: bool = False):
     """Every routed row through its expert, grouped by expert with
     ``grouped(rows, weights, sizes)``.  Returns (out (G, d),
     aux, stats)."""
-    g, d = x2d.shape
     k = cfg.moe.top_k
     with jax.named_scope("route"):
         top_p, top_i, aux, share = _route(p, cfg, x2d, e_pad)
     with jax.named_scope("dispatch"):
-        flat = top_i.reshape(-1)                               # (G*k,)
-        order = jnp.argsort(flat, stable=True)     # rows grouped by expert
-        sizes = jnp.bincount(flat, length=e_pad)
-        rows = jnp.take(x2d, order // k, axis=0)               # (G*k, d)
+        order, back, sizes = _sort_by_expert(top_i.reshape(-1), e_pad)
+        rows = _dispatch_rows(x2d, order, back, k)             # (G*k, d)
     with jax.named_scope("experts"):
         a = grouped(rows, p["wg"], sizes)
         b = grouped(rows, p["wi"], sizes)
         out = grouped(jax.nn.silu(a) * b, p["wo"], sizes)
     with jax.named_scope("combine"):
-        back = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.shape[0], dtype=order.dtype))
-        got = jnp.take(out, back, axis=0).reshape(g, k, d)
-        y = jnp.einsum("gkd,gk->gd", got.astype(jnp.float32), top_p)
+        y = _combine_rows(out, top_p, order, back)
     stats = {"moe_load_max": _load_max(cfg, share),
              "moe_dropped": jnp.zeros((), jnp.float32)}
     if routes:

@@ -79,6 +79,95 @@ def test_dropless_block_equals_dense_per_expert_sum(pad, skew, on_mesh):
     assert np.isfinite(float(aux)) and float(aux) > 0
 
 
+@pytest.mark.parametrize("pad", [1, 16])
+@pytest.mark.parametrize("skew", [False, True])
+@pytest.mark.parametrize("on_mesh", [False, True])
+def test_dropless_block_gradients_equal_dense_per_expert_sum(pad, skew,
+                                                             on_mesh):
+    """The block's gradient with respect to the tokens, the router and
+    the expert weights is that of its definition, the dense sum over
+    experts, at float32: the permutes' backward gathers put every
+    cotangent where the transposed gathers would."""
+    from repro import sharding as shd
+    cfg = _granite(pad)
+    p = MOE.init_moe(jax.random.PRNGKey(0), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 48, cfg.d_model))
+    if skew:
+        x = jnp.abs(x)
+        p["router"] = p["router"].at[:, 3].set(1.0)
+    ct = jax.random.normal(jax.random.PRNGKey(2), x.shape)
+
+    def block(p, x):
+        return jnp.sum(MOE.moe_block(p, cfg, x)[0] * ct)
+
+    def dense(p, x):
+        d = cfg.d_model
+        return jnp.sum(_dense_sum(p, cfg, x.reshape(-1, d))[0]
+                       * ct.reshape(-1, d))
+
+    shd.set_global_mesh(shd.make_mesh((1, 1), ("data", "model"))
+                        if on_mesh else None)
+    try:
+        with jax.default_matmul_precision("highest"):
+            got = jax.grad(block, (0, 1))(p, x)
+    finally:
+        shd.set_global_mesh(None)
+    want = jax.grad(dense, (0, 1))(p, x)
+    for name in ("x", "router", "wg", "wi", "wo"):
+        a, b = ((got[1], want[1]) if name == "x"
+                else (got[0][name], want[0][name]))
+        b = np.asarray(b)
+        np.testing.assert_allclose(np.asarray(a), b, rtol=1e-5,
+                                   atol=1e-5 * np.abs(b).max(),
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("skew", [False, True])
+def test_permute_gradients_equal_transposed_gathers(skew):
+    """The dropless dispatch and combine (custom VJPs whose backward
+    gathers) have the gradients of the plain gathers and gate-weighted
+    sum that JAX transposes to scatter-adds, at float32; the index
+    helper's inverse undoes its sort and its sizes count each expert."""
+    g, k, d, e = 64, 8, 32, 40
+    keys = jax.random.split(jax.random.PRNGKey(3), 6)
+    top_i = jax.random.randint(keys[0], (g, k), 0, 12 if skew else e)
+    order, back, sizes = MOE._sort_by_expert(top_i.reshape(-1), e)
+    np.testing.assert_array_equal(np.asarray(order),
+                                  np.argsort(np.asarray(top_i).ravel(),
+                                             kind="stable"))
+    np.testing.assert_array_equal(np.asarray(order)[np.asarray(back)],
+                                  np.arange(g * k))
+    np.testing.assert_array_equal(
+        np.asarray(sizes), np.bincount(np.asarray(top_i).ravel(),
+                                       minlength=e))
+    x2d = jax.random.normal(keys[1], (g, d))
+    out = jax.random.normal(keys[2], (g * k, d))
+    top_p = jax.nn.softmax(jax.random.normal(keys[3], (g, k)), -1)
+
+    def plain_dispatch(x2d):
+        return jnp.take(x2d, order // k, axis=0)
+
+    def plain_combine(out, top_p):
+        got = jnp.take(out, back, axis=0).reshape(g, k, d)
+        return jnp.einsum("gkd,gk->gd", got, top_p)
+
+    with jax.default_matmul_precision("highest"):
+        drows = jax.random.normal(keys[4], (g * k, d))
+        dy = jax.random.normal(keys[5], (g, d))
+        rows, vjp = jax.vjp(
+            lambda x: MOE._dispatch_rows(x, order, back, k), x2d)
+        rows_want, vjp_want = jax.vjp(plain_dispatch, x2d)
+        y, cvjp = jax.vjp(
+            lambda o, t: MOE._combine_rows(o, t, order, back), out, top_p)
+        y_want, cvjp_want = jax.vjp(plain_combine, out, top_p)
+        pairs = [(rows, rows_want), (y, y_want),
+                 (vjp(drows)[0], vjp_want(drows)[0])]
+        pairs += list(zip(cvjp(dy), cvjp_want(dy)))
+    for a, b in pairs:
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
+                                   atol=1e-6 * float(np.abs(b).max()))
+
+
 def test_capacity_dispatch_counts_its_drops():
     """The expert-parallel path's capacity slots: picks beyond an
     expert's capacity are dropped and counted."""

@@ -132,19 +132,27 @@ def test_lowered_step_carries_layer_scopes(mode):
 
 
 MOE_CHILDREN = ("route", "dispatch", "experts", "combine")
+# an instruction's name and opcode; a sort's shape is a tuple
+_OPCODE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = (?:\([^)]*\)|\S+) ([\w\-]+)\(")
 
 
-def test_moe_scope_and_children_in_granite_step():
-    """Granite's MoE block runs under ``moe`` with its four children,
-    inside ``fwd_bwd``, forward and backward, in the compiled step's
-    op_names; qwen2's step has no ``moe`` op."""
+@pytest.fixture(scope="module")
+def granite_hlo():
+    """The compiled smoke Granite train step (coded sync) as text."""
     cfg = C.get_smoke("granite-moe-3b-a800m")
     shapes = jax.eval_shape(lambda k: ts.init_state(k, cfg),
                             jax.random.PRNGKey(0))
-    hlo = _step(cfg, "lossy_hadamard").lower(
+    return _step(cfg, "lossy_hadamard").lower(
         shapes, _batches(cfg, 1)[0], jax.random.PRNGKey(0),
         jnp.float32(0.2)).compile().as_text()
-    names = re.findall(r'op_name="([^"]*)"', hlo)
+
+
+def test_moe_scope_and_children_in_granite_step(granite_hlo):
+    """Granite's MoE block runs under ``moe`` with its four children,
+    inside ``fwd_bwd``, forward and backward, in the compiled step's
+    op_names; qwen2's step has no ``moe`` op."""
+    names = re.findall(r'op_name="([^"]*)"', granite_hlo)
     for child in MOE_CHILDREN:
         mine = [n for n in names if f"/moe/{child}/" in n
                 and "/fwd_bwd/" in n]
@@ -153,6 +161,26 @@ def test_moe_scope_and_children_in_granite_step():
     qwen = _cfg()
     assert not any("moe/" in n for n in re.findall(
         r'op_name="([^"]*)"', _lowered_hlo(_step(qwen, "exact"), qwen)))
+
+
+def test_moe_permutes_gather_and_never_scatter(granite_hlo):
+    """The dropless block's dispatch and combine run no scatter, forward,
+    recomputed forward or backward: their backward is the inverse
+    gather, and their indices come from sorts and a one-hot count.
+    Both children gather, in the forward and under ``transpose(``."""
+    ops = collections.defaultdict(list)
+    for line in granite_hlo.splitlines():
+        m, on = _OPCODE.match(line), re.search(r'op_name="([^"]*)"', line)
+        if m and on:
+            child = next((c for c in ("dispatch", "combine")
+                          if f"/moe/{c}/" in on.group(1)), None)
+            if child:
+                ops[(child, m.group(1))].append(on.group(1))
+    for child in ("dispatch", "combine"):
+        assert not ops[(child, "scatter")], ops[(child, "scatter")]
+        gathers = ops[(child, "gather")]
+        assert any("transpose(" not in n for n in gathers), child
+        assert any("transpose(" in n for n in gathers), child
 
 
 def _no_scope(monkeypatch):
