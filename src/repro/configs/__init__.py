@@ -4,7 +4,7 @@ from __future__ import annotations
 import importlib
 
 from repro.configs.base import (
-    ModelConfig, MoEConfig, ShapeConfig, SHAPES, runnable_shapes)
+    MLAConfig, ModelConfig, MoEConfig, ShapeConfig, SHAPES, runnable_shapes)
 
 ARCHS = (
     "nemotron_4_15b",
@@ -14,6 +14,7 @@ ARCHS = (
     "recurrentgemma_9b",
     "qwen2_moe_a2_7b",
     "granite_moe_3b_a800m",
+    "moonlight_16b_a3b",
     "xlstm_350m",
     "phi_3_vision_4_2b",
     "seamless_m4t_medium",
@@ -35,5 +36,5 @@ def get_smoke(name: str) -> ModelConfig:
     return mod.SMOKE
 
 
-__all__ = ["ModelConfig", "MoEConfig", "ShapeConfig", "SHAPES", "ARCHS",
+__all__ = ["MLAConfig", "ModelConfig", "MoEConfig", "ShapeConfig", "SHAPES", "ARCHS",
            "runnable_shapes", "get", "get_smoke", "canonical"]

@@ -26,6 +26,38 @@ class MoEConfig:
     # are unroutable): 16 lets a 16-way model axis hold them evenly
     # (expert parallelism); 1 holds exactly the published experts
     expert_pad_multiple: int = 16
+    # the router (DeepSeek-V3's noaux_tc is "sigmoid" with a bias rate):
+    # scores softmax or sigmoid over the routed experts; the top-k gates
+    # renormalised over the k, then times routed_scaling
+    score_func: str = "softmax"     # softmax | sigmoid
+    routed_scaling: float = 1.0
+    # > 0: each layer's router holds a load-balancing bias, added to the
+    # scores for the top-k choice only, outside the gradient; after each
+    # step b_e += bias_rate * sign(mean(c) - c_e) from the step's
+    # per-expert routed counts c
+    bias_rate: float = 0.0
+    # the expert-parallel share: this device holds routed experts
+    # [held_first, held_first + n_held) of the n_experts it routes over
+    # and computes only their part of the result; None holds them all
+    n_held: Optional[int] = None
+    held_first: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3), without a query
+    latent: q from one projection; keys and values from a
+    ``kv_lora_rank`` latent (normed) and one rope key shared by the
+    heads.  q.k is ``qk_nope_head_dim + qk_rope_head_dim`` wide, v
+    ``v_head_dim``."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,6 +88,11 @@ class ModelConfig:
     mlp_type: str = "swiglu"        # swiglu | geglu | sqrelu
 
     moe: Optional[MoEConfig] = None
+    # latent attention in every attention layer, in place of GQA
+    mla: Optional[MLAConfig] = None
+    # leading dense layers ("global" attention + a d_ff MLP) before the
+    # block_pattern, which covers the other n_layers - n_dense_layers
+    n_dense_layers: int = 0
 
     # encoder-decoder (seamless): n_layers applies to EACH stack
     encoder_layers: int = 0
@@ -110,21 +147,41 @@ class ModelConfig:
                    for k in self.block_pattern)
 
     def pattern_layout(self) -> Tuple[int, Tuple[str, ...]]:
-        """(n_groups, tail_kinds): n_layers = n_groups*len(pattern)+tail."""
+        """(n_groups, tail_kinds) of the layers after the dense ones:
+        n_layers - n_dense_layers = n_groups*len(pattern)+tail."""
         plen = len(self.block_pattern)
-        return self.n_layers // plen, self.block_pattern[: self.n_layers % plen]
+        n = self.n_layers - self.n_dense_layers
+        return n // plen, self.block_pattern[: n % plen]
+
+    def _attention_params(self) -> int:
+        d = self.d_model
+        if self.mla is not None:
+            a, h = self.mla, self.n_heads
+            return (d * h * a.qk_head_dim
+                    + d * (a.kv_lora_rank + a.qk_rope_head_dim)
+                    + a.kv_lora_rank
+                    + a.kv_lora_rank * h * (a.qk_nope_head_dim + a.v_head_dim)
+                    + h * a.v_head_dim * d)
+        hd = self.resolved_head_dim
+        return d * (self.n_heads * hd) * 2 + d * (self.n_kv_heads * hd) * 2
+
+    def _routed_held(self) -> int:
+        m = self.moe
+        return m.n_experts if m.n_held is None else m.n_held
 
     def param_count(self) -> int:
-        """Approximate parameter count (embeddings + blocks)."""
+        """Approximate parameter count (embeddings + blocks; norms left
+        out but the latent's): with a held share, the experts held."""
         d, dff, v = self.d_model, self.d_ff, self.vocab_size
         hd = self.resolved_head_dim
         qo = d * (self.n_heads * hd) * 2
         kv = d * (self.n_kv_heads * hd) * 2
+        attn = self._attention_params()
         mlp_mult = 3 if self.mlp_type in ("swiglu", "geglu") else 2
         per_kind = {}
         for kind in set(self.block_pattern):
             if kind in ("global", "local"):
-                per_kind[kind] = qo + kv + mlp_mult * d * dff
+                per_kind[kind] = attn + mlp_mult * d * dff
             elif kind == "rglru":
                 w = self.lru_width or d
                 per_kind[kind] = 2 * d * w + w * d + 3 * w + mlp_mult * d * dff
@@ -134,11 +191,13 @@ class ModelConfig:
                 per_kind[kind] = 4 * d * d + 4 * d * d // 4 + 2 * d * (2 * d)
             elif kind == "moe":
                 m = self.moe
-                e_params = (m.n_experts + m.n_shared) * 3 * d * m.d_expert
-                per_kind[kind] = qo + kv + e_params + d * m.n_experts
+                e_params = ((self._routed_held() + m.n_shared) * 3 * d
+                            * m.d_expert)
+                per_kind[kind] = attn + e_params + d * m.n_experts
         n_groups, tail = self.pattern_layout()
         blocks = n_groups * sum(per_kind[k] for k in self.block_pattern)
         blocks += sum(per_kind[k] for k in tail)
+        blocks += self.n_dense_layers * (attn + mlp_mult * d * dff)
         emb = v * d * (1 if self.tie_embeddings else 2)
         if self.is_encdec:
             blocks *= 2   # encoder + decoder stacks (cross-attn ~ attn)
@@ -153,9 +212,10 @@ class ModelConfig:
         m = self.moe
         d = self.d_model
         total = self.param_count()
-        all_e = (m.n_experts + m.n_shared) * 3 * d * m.d_expert
-        act_e = (m.top_k + m.n_shared) * 3 * d * m.d_expert
-        return total - self.n_layers * (all_e - act_e)
+        all_e = self._routed_held() * 3 * d * m.d_expert
+        act_e = min(m.top_k, self._routed_held()) * 3 * d * m.d_expert
+        n_moe = self.n_layers - self.n_dense_layers
+        return total - n_moe * (all_e - act_e)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -175,8 +235,11 @@ SHAPES = {
 
 
 def runnable_shapes(cfg: ModelConfig) -> Tuple[str, ...]:
-    """Which of the 4 assigned shapes this arch runs (spec skip rules)."""
-    out = ["train_4k", "prefill_32k", "decode_32k"]
+    """Which of the 4 assigned shapes this arch runs (spec skip rules):
+    no serving shape for latent attention, which has no KV cache here."""
+    out = ["train_4k"]
+    if cfg.mla is None:
+        out += ["prefill_32k", "decode_32k"]
     if cfg.is_subquadratic:
         out.append("long_500k")
     return tuple(out)

@@ -74,7 +74,9 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     shape = SHAPES[shape_name]
     if shape_name not in C.runnable_shapes(cfg):
         return {"arch": arch, "shape": shape_name, "skipped": True,
-                "reason": "long_500k needs sub-quadratic attention"}
+                "reason": ("latent attention has no KV cache here"
+                           if cfg.mla is not None and shape.kind != "train"
+                           else "long_500k needs sub-quadratic attention")}
 
     mesh = mesh_mod.make_production_mesh(multi_pod=multi_pod)
     shd.set_global_mesh(mesh)

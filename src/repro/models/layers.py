@@ -85,6 +85,8 @@ def _trunc_normal(key, shape, scale, dtype):
 
 
 def init_attention(key: jax.Array, cfg: ModelConfig) -> Params:
+    if cfg.mla is not None:
+        return init_mla(key, cfg)
     d, hd = cfg.d_model, cfg.resolved_head_dim
     h, hp, kv = cfg.n_heads, cfg.n_heads_padded, cfg.n_kv_heads
     dt = _dtype(cfg)
@@ -106,6 +108,26 @@ def init_attention(key: jax.Array, cfg: ModelConfig) -> Params:
         p["bk"] = jnp.zeros((kv * hd,), dt)
         p["bv"] = jnp.zeros((kv * hd,), dt)
     return p
+
+
+def init_mla(key: jax.Array, cfg: ModelConfig) -> Params:
+    """Latent attention's weights: ``wq`` (d, H x qk_head_dim), ``wkv_a``
+    (d, kv_lora_rank + qk_rope_head_dim) to the latent and the shared
+    rope key, the latent's norm, ``wkv_b`` (kv_lora_rank, H x (nope +
+    v)) to each head's key and value, ``wo`` (H x v_head_dim, d)."""
+    d, h, a = cfg.d_model, cfg.n_heads, cfg.mla
+    dt = _dtype(cfg)
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": _trunc_normal(ks[0], (d, h * a.qk_head_dim), 1.0, dt),
+        "wkv_a": _trunc_normal(
+            ks[1], (d, a.kv_lora_rank + a.qk_rope_head_dim), 1.0, dt),
+        "kv_norm": init_rmsnorm(a.kv_lora_rank),
+        "wkv_b": _trunc_normal(
+            ks[2], (a.kv_lora_rank, h * (a.qk_nope_head_dim + a.v_head_dim)),
+            1.0, dt),
+        "wo": _trunc_normal(ks[3], (h * a.v_head_dim, d), 1.0, dt),
+    }
 
 
 def _head_shard(x: jax.Array) -> jax.Array:
@@ -264,13 +286,13 @@ def _flash_attention(q, k, v, *, qpos, kpos, kind: str, cfg: ModelConfig,
                      causal: bool, q_blk: int = 1024, kv_blk: int = 1024):
     """Memory-efficient attention (Rabe–Staats style, mask-aware).
 
-    q: (B,Sq,H,D), k/v: (B,Skv,H,D); qpos (Sq,), kpos (Skv,) true
+    q/k: (B,Sq|Skv,H,D), v: (B,Skv,H,Dv); qpos (Sq,), kpos (Skv,) true
     positions.  Online softmax over kv tiles inside a scan over q tiles;
     each q-tile is jax.checkpoint'ed so backward recomputes tiles instead
     of storing O(Sq*Skv) residuals.  Never materializes (Sq, Skv).
     """
     b, sq, h, d = q.shape
-    skv = k.shape[1]
+    skv, dv = k.shape[1], v.shape[-1]
     q_blk = min(q_blk, sq)
     kv_blk = min(kv_blk, skv)
     assert sq % q_blk == 0 and skv % kv_blk == 0, (sq, q_blk, skv, kv_blk)
@@ -279,12 +301,12 @@ def _flash_attention(q, k, v, *, qpos, kpos, kind: str, cfg: ModelConfig,
 
     qr = q.reshape(b, nq, q_blk, h, d).swapaxes(0, 1)     # (nq,B,qb,H,D)
     kr = k.reshape(b, nk, kv_blk, h, d).swapaxes(0, 1)
-    vr = v.reshape(b, nk, kv_blk, h, d).swapaxes(0, 1)
+    vr = v.reshape(b, nk, kv_blk, h, dv).swapaxes(0, 1)
     qpr = qpos.reshape(nq, q_blk)
     kpr = kpos.reshape(nk, kv_blk)
 
     def q_tile(qt, qp):
-        """qt: (B,qb,H,D); returns (B,qb,H,D)."""
+        """qt: (B,qb,H,D); returns (B,qb,H,Dv)."""
         def kv_step(carry, t):
             m, l, acc = carry
             kt, vt, kp = t
@@ -314,15 +336,71 @@ def _flash_attention(q, k, v, *, qpos, kpos, kind: str, cfg: ModelConfig,
 
         m0 = jnp.full((b, h, q_blk), -1e30, jnp.float32)
         l0 = jnp.zeros((b, h, q_blk), jnp.float32)
-        a0 = jnp.zeros((b, h, q_blk, d), jnp.float32)
+        a0 = jnp.zeros((b, h, q_blk, dv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0), (kr, vr, kpr))
         out = acc / jnp.maximum(l, 1e-30)[..., None]
-        return out.swapaxes(1, 2)                        # (B,qb,H,D)
+        return out.swapaxes(1, 2)                        # (B,qb,H,Dv)
 
     outs = jax.lax.scan(
         lambda _, t: (None, jax.checkpoint(q_tile)(t[0], t[1])),
-        None, (qr, qpr))[1]                              # (nq,B,qb,H,D)
-    return outs.swapaxes(0, 1).reshape(b, sq, h, d)
+        None, (qr, qpr))[1]                              # (nq,B,qb,H,Dv)
+    return outs.swapaxes(0, 1).reshape(b, sq, h, dv)
+
+
+def _core(cfg: ModelConfig, q, kq, vq, *, path: str, scale: float,
+          x_dtype, positions, kind: str, causal: bool, cross: bool,
+          decode: bool, kpos1, cache_index):
+    """Attention of (B,S,H,D) queries over (B,S_kv,KV,D) keys and
+    (B,S_kv,KV,Dv) values by ``path`` (``attention_path``): (B,S,H,Dv)."""
+    b, s, h, hd = q.shape
+    kv, s_kv = kq.shape[2], kq.shape[1]
+    rep = h // kv
+    if path == "pallas":
+        # rope left q and k in float32: the kernel takes them in the
+        # compute dtype, as the MXU does the dense path's matmuls
+        out = ops.flash_attention(q.astype(x_dtype), kq.astype(x_dtype),
+                                  vq, scale=scale)
+    elif path == "tiled":
+        # memory-efficient path: never builds the (Sq,Skv) matrix;
+        # cross-attention uses it too (causal=False, all-valid kpos)
+        kq = _head_shard(jnp.repeat(kq, rep, axis=2))
+        vq = _head_shard(jnp.repeat(vq, rep, axis=2))
+        qpos1 = positions[0] if positions.ndim == 2 else positions
+        kpos_arr = jnp.arange(s_kv, dtype=jnp.int32)
+        out = _flash_attention(
+            q, kq, vq, qpos=qpos1, kpos=kpos_arr,
+            kind=("global" if cross else kind), cfg=cfg,
+            causal=(causal and not cross)).astype(x_dtype)
+    else:
+        # dense path: grouped-GQA einsums against the UNREPEATED kv (a
+        # materialized repeat of a 32k-token cache would cost GBs at
+        # decode)
+        qg = q.reshape(b, s, kv, rep, hd)
+        logits = jnp.einsum("bqkrd,bskd->bkrqs", qg, kq,
+                            preferred_element_type=jnp.float32) * scale
+        logits = logits.reshape(b, h, s, s_kv)
+        logits = _softcap(logits, cfg.attn_softcap)
+
+        # masks
+        if not cross:
+            if decode:
+                kpos = kpos1[None, None, None, :]   # true slot positions
+                mask = (kpos >= 0) & (kpos <= cache_index)
+                if kind == "local":
+                    mask = mask & (kpos > cache_index - cfg.window_size)
+            else:
+                qpos = positions[:, None, :, None]
+                kpos = jnp.arange(s_kv)[None, None, None, :]
+                mask = (kpos <= qpos) if causal else jnp.ones(
+                    (1, 1, s, s_kv), bool)
+                if kind == "local":
+                    mask = mask & (kpos > qpos - cfg.window_size)
+            logits = jnp.where(mask, logits, -1e30)
+
+        attn = jax.nn.softmax(logits, axis=-1).astype(vq.dtype)
+        attn_g = attn.reshape(b, kv, rep, s, s_kv)
+        out = jnp.einsum("bkrqs,bskd->bqkrd", attn_g, vq)
+    return out
 
 
 def attention(p: Params, cfg: ModelConfig, x: jax.Array, *,
@@ -343,8 +421,17 @@ def attention(p: Params, cfg: ModelConfig, x: jax.Array, *,
 
     ``positions`` None, or ``default_positions``, says they are 0..S-1:
     only then may long attention take the Pallas kernel
-    (``attention_path``).
+    (``attention_path``).  A configuration with ``mla`` runs
+    :func:`mla_attention` instead (self-attention, no cache).
     """
+    if cfg.mla is not None:
+        if cache is not None or memory is not None:
+            raise ValueError("latent attention runs without a KV cache and "
+                             "without cross-attention: serving MLA needs a "
+                             "latent cache, which this program lacks")
+        return mla_attention(p, cfg, x, kind=kind, positions=positions,
+                             causal=causal,
+                             default_positions=default_positions), None
     b, s, d = x.shape
     h, kv, hd = cfg.n_heads_padded, cfg.n_kv_heads, cfg.resolved_head_dim
 
@@ -385,7 +472,6 @@ def attention(p: Params, cfg: ModelConfig, x: jax.Array, *,
         kpos1 = None
     s_kv = kq.shape[1]
 
-    rep = h // kv
     path = attention_path(cfg, s, s_kv, decode=decode,
                           cross=memory is not None,
                           default_positions=default_positions, kind=kind,
@@ -393,53 +479,60 @@ def attention(p: Params, cfg: ModelConfig, x: jax.Array, *,
     scale = attention_scale(cfg, hd)
 
     with jax.named_scope("attention"):
-        if path == "pallas":
-            # rope left q and k in float32: the kernel takes them in the
-            # compute dtype, as the MXU does the dense path's matmuls
-            out = ops.flash_attention(q.astype(x.dtype), kq.astype(x.dtype),
-                                      vq, scale=scale)
-        elif path == "tiled":
-            # memory-efficient path: never builds the (Sq,Skv) matrix;
-            # cross-attention uses it too (causal=False, all-valid kpos)
-            kq = _head_shard(jnp.repeat(kq, rep, axis=2))
-            vq = _head_shard(jnp.repeat(vq, rep, axis=2))
-            qpos1 = positions[0] if positions.ndim == 2 else positions
-            kpos_arr = jnp.arange(s_kv, dtype=jnp.int32)
-            out = _flash_attention(
-                q, kq, vq, qpos=qpos1, kpos=kpos_arr,
-                kind=("global" if memory is not None else kind), cfg=cfg,
-                causal=(causal and memory is None)).astype(x.dtype)
-        else:
-            # dense path: grouped-GQA einsums against the UNREPEATED kv (a
-            # materialized repeat of a 32k-token cache would cost GBs at
-            # decode)
-            qg = q.reshape(b, s, kv, rep, hd)
-            logits = jnp.einsum("bqkrd,bskd->bkrqs", qg, kq,
-                                preferred_element_type=jnp.float32) * scale
-            logits = logits.reshape(b, h, s, s_kv)
-            logits = _softcap(logits, cfg.attn_softcap)
-
-            # masks
-            if memory is None:
-                if decode:
-                    kpos = kpos1[None, None, None, :]   # true slot positions
-                    mask = (kpos >= 0) & (kpos <= cache_index)
-                    if kind == "local":
-                        mask = mask & (kpos > cache_index - cfg.window_size)
-                else:
-                    qpos = positions[:, None, :, None]
-                    kpos = jnp.arange(s_kv)[None, None, None, :]
-                    mask = (kpos <= qpos) if causal else jnp.ones(
-                        (1, 1, s, s_kv), bool)
-                    if kind == "local":
-                        mask = mask & (kpos > qpos - cfg.window_size)
-                logits = jnp.where(mask, logits, -1e30)
-
-            attn = jax.nn.softmax(logits, axis=-1).astype(vq.dtype)
-            attn_g = attn.reshape(b, kv, rep, s, s_kv)
-            out = jnp.einsum("bkrqs,bskd->bqkrd", attn_g, vq)
-    out = out.reshape(b, s, h * hd) @ p["wo"]
+        out = _core(cfg, q, kq, vq, path=path, scale=scale, x_dtype=x.dtype,
+                    positions=positions, kind=kind, causal=causal,
+                    cross=memory is not None, decode=decode, kpos1=kpos1,
+                    cache_index=cache_index)
+    out = out.reshape(b, s, -1) @ p["wo"]
     return out, new_cache
+
+
+def mla_attention(p: Params, cfg: ModelConfig, x: jax.Array, *,
+                  kind: str = "global",
+                  positions: Optional[jax.Array] = None,
+                  causal: bool = True,
+                  default_positions: bool = False) -> jax.Array:
+    """Multi-head latent attention (DeepSeek-V3 without a query latent)
+    of (B,S,D) ``x``: each head's query is [q_nope, rope(q_rope)], its
+    key [k_nope, rope(k_rope)] and its value v, with k_nope and v from
+    the normed ``kv_lora_rank`` latent through ``wkv_b`` and one rope
+    key shared by the heads; scores x ``attention_scale`` of the
+    qk_head_dim.  Under ``attention``: the projections to q, k and v
+    under ``latent``, the scores and values (``attention_path``'s path)
+    under ``core``."""
+    b, s, _ = x.shape
+    a, h = cfg.mla, cfg.n_heads
+    nope, rope = a.qk_nope_head_dim, a.qk_rope_head_dim
+    if positions is None:
+        positions = jnp.arange(s, dtype=jnp.int32)[None, :]
+        default_positions = True
+    with jax.named_scope("attention"):
+        with jax.named_scope("latent"):
+            q = (x @ p["wq"]).reshape(b, s, h, a.qk_head_dim)
+            ckv = x @ p["wkv_a"]
+            latent = rmsnorm(p["kv_norm"], ckv[..., :a.kv_lora_rank],
+                             cfg.norm_eps)
+            kv = (latent @ p["wkv_b"]).reshape(b, s, h, nope + a.v_head_dim)
+            cos, sin, rot = rope_tables(positions, rope, cfg.rope_theta, 1.0)
+            q_rope = apply_rope(q[..., nope:], cos, sin, rot)
+            k_rope = apply_rope(ckv[..., None, a.kv_lora_rank:], cos, sin,
+                                rot)
+            q = jnp.concatenate([q[..., :nope], q_rope.astype(x.dtype)], -1)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_rope.astype(x.dtype), (b, s, h, rope))],
+                -1)
+            v = kv[..., nope:]
+        path = attention_path(cfg, s, s, decode=False, cross=False,
+                              default_positions=default_positions,
+                              kind=kind, causal=causal)
+        with jax.named_scope("core"):
+            out = _core(cfg, q, k, v, path=path,
+                        scale=attention_scale(cfg, a.qk_head_dim),
+                        x_dtype=x.dtype, positions=positions, kind=kind,
+                        causal=causal, cross=False, decode=False,
+                        kpos1=None, cache_index=None)
+        return out.reshape(b, s, h * a.v_head_dim) @ p["wo"]
 
 
 # ----------------------------------------------------------------------

@@ -187,10 +187,14 @@ def _apply_stack(stack: Params, cfg: ModelConfig, n_layers: int,
                  base_key: Optional[jax.Array] = None, remat: bool = True,
                  routes: bool = False, default_positions: bool = False):
     """caches: {"groups": [stacked per position], "tail": [per layer]}.
+    Leading dense layers (``stack["lead"]``) run first, with no cache;
+    ``n_layers`` counts the others.
 
     Returns (x, new_caches, aux, stats): the blocks' counters merged
-    over layers (``moe.merge_stats``); with ``routes``, ``stats`` also
-    holds ``moe_routes``, every MoE layer's expert ids stacked (L, G, k)."""
+    over layers (``moe.merge_stats``); ``stats`` also holds the
+    per-layer ones (``moe.PER_LAYER``) stacked over the MoE layers in
+    order: with ``routes`` ``moe_routes``, every MoE layer's expert ids
+    (L, G, k), and with a router bias ``moe_counts`` (L, E)."""
     plen = len(cfg.block_pattern)
     n_groups = n_layers // plen
     tail_kinds = cfg.block_pattern[: n_layers % plen]
@@ -215,8 +219,20 @@ def _apply_stack(stack: Params, cfg: ModelConfig, n_layers: int,
             mesh, jax.sharding.PartitionSpec(None, shd.MODEL_AXIS, None))
         seq_pin = lambda t: jax.lax.with_sharding_constraint(t, nsp)
 
+    if "lead" in stack and caches is not None:
+        raise ValueError("leading dense layers take no KV cache here")
+    for lead_p in stack.get("lead", ()):
+        def dense(p_, x_):
+            return apply_block(p_, "global", cfg, x_, positions=positions,
+                               memory=memory, causal=causal,
+                               default_positions=default_positions)
+        if remat:
+            dense = jax.checkpoint(dense)
+        x = dense(lead_p, x)[0]
+
     def unit(x, slices, caches_slice, idx):
-        new_caches, aux, stats, ids = [], jnp.zeros((), jnp.float32), {}, []
+        new_caches, aux, stats = [], jnp.zeros((), jnp.float32), {}
+        ids = {k: [] for k in MOE.PER_LAYER}
         if seq_pin is not None:
             x = seq_pin(x)
         for j, kind in enumerate(cfg.block_pattern):
@@ -229,14 +245,15 @@ def _apply_stack(stack: Params, cfg: ModelConfig, n_layers: int,
                 default_positions=default_positions)
             new_caches.append(nc)
             aux = aux + a
-            if "moe_routes" in st:
-                ids.append(st.pop("moe_routes"))
+            for k in MOE.PER_LAYER:
+                if k in st:
+                    ids[k].append(st.pop(k))
             stats = MOE.merge_stats(stats, st)
         if seq_pin is not None:
             x = seq_pin(x)
         return x, new_caches, aux, stats, ids
 
-    stats, ids = {}, []
+    stats, ids = {}, {k: [] for k in MOE.PER_LAYER}
     if n_groups:
         unit_fn = jax.checkpoint(unit) if remat else unit
 
@@ -250,8 +267,9 @@ def _apply_stack(stack: Params, cfg: ModelConfig, n_layers: int,
         xs = (stack["groups"], group_caches, jnp.arange(n_groups))
         (x, aux_total, stats), (new_group_caches, g_ids) = jax.lax.scan(
             body, (x, aux_total, MOE.zero_stats(cfg)), xs)
-        if g_ids:   # (groups, MoE positions, G, k) -> layers in order
-            ids = [jnp.stack(g_ids, 1).reshape((-1,) + g_ids[0].shape[1:])]
+        for k, per in g_ids.items():
+            if per:   # (groups, MoE positions, ...) -> layers in order
+                ids[k] = [jnp.stack(per, 1).reshape((-1,) + per[0].shape[1:])]
     else:
         new_group_caches = None
 
@@ -270,12 +288,14 @@ def _apply_stack(stack: Params, cfg: ModelConfig, n_layers: int,
         x, nc, a, st = blk(stack["tail"][i], x)
         new_tail.append(nc)
         aux_total = aux_total + a
-        if "moe_routes" in st:
-            ids.append(st.pop("moe_routes")[None])
+        for k in MOE.PER_LAYER:
+            if k in st:
+                ids[k].append(st.pop(k)[None])
         stats = MOE.merge_stats(stats, st)
 
-    if ids:
-        stats["moe_routes"] = jnp.concatenate(ids, 0)
+    for k, per in ids.items():
+        if per:
+            stats[k] = jnp.concatenate(per, 0)
     new_caches = None
     if caches is not None:
         new_caches = {"groups": new_group_caches, "tail": new_tail}
@@ -290,10 +310,14 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> Params:
     ks = jax.random.split(key, 5)
     p: Params = {
         "embed": L.init_embedding(ks[0], cfg),
-        "decoder": _init_stack(ks[1], cfg, cfg.n_layers,
+        "decoder": _init_stack(ks[1], cfg, cfg.n_layers - cfg.n_dense_layers,
                                cross=cfg.is_encdec),
         "final_norm": L.init_rmsnorm(cfg.d_model),
     }
+    if cfg.n_dense_layers:   # leading dense layers, before the pattern
+        p["decoder"]["lead"] = [
+            init_block(jax.random.fold_in(ks[1], 2000 + i), "global", cfg)
+            for i in range(cfg.n_dense_layers)]
     if cfg.is_encdec:
         p["encoder"] = _init_stack(ks[2], cfg, cfg.encoder_layers)
         p["enc_norm"] = L.init_rmsnorm(cfg.d_model)
@@ -346,7 +370,8 @@ def forward(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
         positions = jnp.arange(s, dtype=jnp.int32)[None, :]
 
     x, new_caches, aux, stats = _apply_stack(
-        params["decoder"], cfg, cfg.n_layers, x, positions=positions,
+        params["decoder"], cfg, cfg.n_layers - cfg.n_dense_layers, x,
+        positions=positions,
         caches=caches, cache_index=cache_index, memory=memory, lossy=lossy,
         remat=remat, routes=routes, default_positions=default_positions)
 
@@ -390,6 +415,9 @@ def _cache_len(kind: str, cfg: ModelConfig, s_max: int) -> int:
 
 
 def init_layer_cache(kind: str, cfg: ModelConfig, batch: int, s_max: int):
+    if cfg.mla is not None and kind in ATTN_KINDS:
+        raise ValueError(f"{cfg.name}: latent attention has no KV cache "
+                         "here (serving MLA needs a latent cache)")
     dt = jnp.dtype(cfg.dtype)
     kv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     if kind in ("global", "local", "moe", "xattn"):
@@ -408,8 +436,7 @@ def init_layer_cache(kind: str, cfg: ModelConfig, batch: int, s_max: int):
 
 
 def init_caches(cfg: ModelConfig, batch: int, s_max: int):
-    plen = len(cfg.block_pattern)
-    n_groups, tail = cfg.n_layers // plen, cfg.block_pattern[: cfg.n_layers % plen]
+    n_groups, tail = cfg.pattern_layout()
     groups = []
     for kind in cfg.block_pattern:
         one = init_layer_cache(kind, cfg, batch, s_max)

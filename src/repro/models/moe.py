@@ -42,6 +42,15 @@ counters: ``moe_load_max``, the busiest expert's routed rows over the
 mean, and ``moe_dropped``, the routed rows dropped (0 on the dropless
 path; capacity overflow on EP).
 
+DeepSeek-V3's block adds three things (``MoEConfig``): sigmoid routing
+(``score_func``) whose top-k choice adds a per-layer load-balancing
+bias that is no gate and takes no gradient (``bias_rate``; the train
+step moves it from each layer's routed counts, ``moe_counts``, with
+:func:`update_bias`); ``routed_scaling`` on the gates; and a held
+share (``n_held``, ``held_first``): the device routes over every
+expert but holds and computes only its own, the expert-parallel layer
+without its exchange, counted by ``moe_held_rows``.
+
 Routed experts are zero-padded to ``MoEConfig.expert_pad_multiple``
 (16 keeps parameter shapes mesh-independent up to a 16-way model axis;
 dummy experts are unroutable: router logits forced to -inf).
@@ -61,20 +70,50 @@ from repro.kernels import ops
 
 Params = Dict[str, Any]
 
-# how each counter combines over layers, microbatches and dp shards
+# how each counter combines over layers, microbatches and dp shards;
+# ``moe_held_rows`` (a held share only: the routed rows of the held
+# experts, each layer's over the MoE layers, so their mean)
 STATS = {"moe_load_max": "max", "moe_dropped": "sum"}
+HELD_STATS = {"moe_held_rows": "sum"}
+# per-layer outputs of a block, stacked over the MoE layers and not
+# merged: each token's expert ids (``routes``), and with a router bias
+# each routed expert's count of the layer's routed rows (summed over
+# microbatches and dp shards)
+PER_LAYER = ("moe_routes", "moe_counts")
+_HOW = {**STATS, **HELD_STATS, "moe_counts": "sum"}
 
 
 def padded_experts(cfg: ModelConfig) -> int:
-    """Routed experts held, padded to ``cfg.moe.expert_pad_multiple``."""
+    """Routed experts routed over, padded to
+    ``cfg.moe.expert_pad_multiple``."""
     e, m = cfg.moe.n_experts, cfg.moe.expert_pad_multiple
     return -(-e // m) * m
+
+
+def held(cfg: ModelConfig) -> tuple[int, int] | None:
+    """(first, count) of the routed experts this device holds, or None
+    where it holds every one."""
+    m = cfg.moe
+    if m.n_held is None:
+        return None
+    if not 0 <= m.held_first <= m.held_first + m.n_held <= m.n_experts:
+        raise ValueError(f"experts [{m.held_first}, "
+                         f"{m.held_first + m.n_held}) are not among the "
+                         f"{m.n_experts} routed")
+    return m.held_first, m.n_held
+
+
+def has_bias(cfg: ModelConfig) -> bool:
+    """Whether the MoE layers' routers hold a load-balancing bias."""
+    return cfg.moe is not None and cfg.moe.bias_rate > 0
 
 
 def init_moe(key: jax.Array, cfg: ModelConfig) -> Params:
     m = cfg.moe
     d, f = cfg.d_model, m.d_expert
     e_pad = padded_experts(cfg)
+    share = held(cfg)
+    e_here = e_pad if share is None else share[1]
     dt = jnp.dtype(cfg.dtype)
     ks = jax.random.split(key, 5)
 
@@ -84,10 +123,20 @@ def init_moe(key: jax.Array, cfg: ModelConfig) -> Params:
 
     p: Params = {
         "router": tn(ks[0], (d, e_pad), d).astype(jnp.float32),
-        "wi": tn(ks[1], (e_pad, d, f), d),
-        "wg": tn(ks[2], (e_pad, d, f), d),
-        "wo": tn(ks[3], (e_pad, f, d), f),
+        "wi": tn(ks[1], (e_here, d, f), d),
+        "wg": tn(ks[2], (e_here, d, f), d),
+        "wo": tn(ks[3], (e_here, f, d), f),
     }
+    if has_bias(cfg):
+        if cfg.block_pattern != ("moe",):
+            raise ValueError("a router bias needs block_pattern ('moe',)")
+        if e_pad != m.n_experts:
+            raise ValueError("a router bias needs unpadded experts: a "
+                             "dummy's would rise by bias_rate every step "
+                             "until it is chosen (expert_pad_multiple "
+                             f"{m.expert_pad_multiple}, {m.n_experts} "
+                             "experts)")
+        p["bias"] = jnp.zeros((e_pad,), jnp.float32)
     if m.n_shared:
         fs = m.n_shared * m.d_expert
         p["shared"] = {
@@ -113,25 +162,67 @@ def param_specs(cfg: ModelConfig) -> Params:
     return specs
 
 
-def zero_stats(cfg: ModelConfig) -> dict:
-    """The counters before any layer: none for a model with no MoE."""
+def zero_stats(cfg: ModelConfig, counts: bool = False) -> dict:
+    """The counters before any layer: none for a model with no MoE;
+    ``counts``: also ``moe_counts`` (every MoE layer's, for a step's
+    microbatches to sum) where the routers hold a bias."""
     if "moe" not in cfg.block_pattern:
         return {}
-    return {k: jnp.zeros((), jnp.float32) for k in STATS}
+    out = {k: jnp.zeros((), jnp.float32)
+           for k in {**STATS, **(HELD_STATS if held(cfg) else {})}}
+    if counts and has_bias(cfg):
+        n_moe = cfg.n_layers - cfg.n_dense_layers
+        out["moe_counts"] = jnp.zeros((n_moe, padded_experts(cfg)),
+                                      jnp.float32)
+    return out
 
 
 def merge_stats(a: dict, b: dict) -> dict:
     """Two sets of counters as one (``STATS`` says how)."""
     if not a:
         return dict(b)
-    return {k: (jnp.maximum if STATS[k] == "max" else jnp.add)(a[k], b[k])
+    return {k: (jnp.maximum if _HOW[k] == "max" else jnp.add)(a[k], b[k])
             for k in a}
 
 
 def reduce_stats(stats: dict, axes) -> dict:
     """Counters of one shard as those of all ``axes``' shards."""
-    return {k: (jax.lax.pmax if STATS[k] == "max" else jax.lax.psum)(
+    return {k: (jax.lax.pmax if _HOW[k] == "max" else jax.lax.psum)(
         v, axes) for k, v in stats.items()}
+
+
+def split_bias(params: Params) -> tuple[Params, Params]:
+    """(weights, biases): ``params`` with every router bias as None, and
+    the router biases alone (None elsewhere).  Without a bias the
+    weights are ``params`` itself."""
+    def is_bias(path):
+        keys = [getattr(k, "key", None) for k in path[-2:]]
+        return keys == ["moe", "bias"]
+
+    weights = jax.tree_util.tree_map_with_path(
+        lambda p_, x: None if is_bias(p_) else x, params)
+    biases = jax.tree_util.tree_map_with_path(
+        lambda p_, x: x if is_bias(p_) else None, params)
+    return weights, biases
+
+
+def join_bias(weights: Params, biases: Params) -> Params:
+    """The inverse of :func:`split_bias`."""
+    return jax.tree.map(lambda w, b: b if w is None else w, weights, biases,
+                        is_leaf=lambda x: x is None)
+
+
+def update_bias(biases: Params, counts: jax.Array,
+                cfg: ModelConfig) -> Params:
+    """DeepSeek-V3's auxiliary-loss-free balance: each MoE layer's bias
+    ``b_e += bias_rate * sign(mean(c) - c_e)``, ``counts`` (L, E) the
+    step's routed rows of every expert of each MoE layer in order."""
+    rate = cfg.moe.bias_rate
+
+    def one(b):
+        return b + rate * jnp.sign(
+            jnp.mean(counts, -1, keepdims=True) - counts).reshape(b.shape)
+    return jax.tree.map(one, biases)
 
 
 def _capacity(cfg: ModelConfig, g_tokens: int) -> int:
@@ -149,6 +240,8 @@ def _route(p: Params, cfg: ModelConfig, x2d: jax.Array, e_pad: int):
     if e_pad > m.n_experts:   # dummy padded experts are unroutable
         pad_mask = jnp.arange(e_pad) >= m.n_experts
         logits = jnp.where(pad_mask[None, :], -1e30, logits)
+    if m.score_func == "sigmoid":
+        return _route_sigmoid(p, cfg, logits, e_pad)
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_i = jax.lax.top_k(probs, m.top_k)
     top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
@@ -158,6 +251,33 @@ def _route(p: Params, cfg: ModelConfig, x2d: jax.Array, e_pad: int):
            + m.router_z_weight * jnp.mean(
                jnp.square(jax.nn.logsumexp(logits, axis=-1))))
     return top_p, top_i, aux, ce
+
+
+def _route_sigmoid(p: Params, cfg: ModelConfig, logits: jax.Array,
+                   e_pad: int):
+    """DeepSeek-V3's router over ``logits`` (G, e_pad): scores
+    ``sigmoid(logits)``; the top k of the scores plus the layer's bias
+    (where it holds one: for the choice only, outside the gradient);
+    gates the chosen scores, renormalised over the k, times
+    ``routed_scaling``.  No auxiliary loss."""
+    m = cfg.moe
+    scores = jax.nn.sigmoid(logits)
+    choice = scores
+    if "bias" in p:
+        choice = scores + jax.lax.stop_gradient(p["bias"])
+    top_i = jax.lax.top_k(choice, m.top_k)[1]
+    top_p = jnp.take_along_axis(scores, top_i, axis=-1)
+    top_p = top_p / (top_p.sum(-1, keepdims=True) + 1e-20) * m.routed_scaling
+    return (top_p, top_i, jnp.zeros((), jnp.float32),
+            _counts(top_i, e_pad) / top_i.size)
+
+
+def _counts(top_i: jax.Array, e_pad: int) -> jax.Array:
+    """Each expert's routed rows (e_pad,) in float32, counted without a
+    scatter."""
+    return jnp.sum(top_i.reshape(-1)[:, None]
+                   == jnp.arange(e_pad, dtype=top_i.dtype), axis=0,
+                   dtype=jnp.float32)
 
 
 def _load_max(cfg: ModelConfig, share: jax.Array) -> jax.Array:
@@ -203,40 +323,49 @@ def _sort_by_expert(flat: jax.Array, e_pad: int):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch_rows(x2d, order, back, k):
+def _dispatch_rows(x2d, order, back, k, keep=None):
     """Each token's row once per pick, grouped by expert:
     ``x2d[order // k]`` (G*k, d).  Its backward gathers the rows'
     cotangents back to token order by ``back`` and sums each token's k
-    in float32, where JAX would transpose the gather to a scatter-add."""
+    in float32, where JAX would transpose the gather to a scatter-add;
+    ``keep`` (G, k), where given, zeroes the picks it leaves out (rows
+    no matmul computed)."""
     return jnp.take(x2d, order // k, axis=0)
 
 
-def _dispatch_rows_fwd(x2d, order, back, k):
-    return _dispatch_rows(x2d, order, back, k), back
+def _dispatch_rows_fwd(x2d, order, back, k, keep=None):
+    return _dispatch_rows(x2d, order, back, k, keep), (back, keep)
 
 
-def _dispatch_rows_bwd(k, back, drows):
+def _dispatch_rows_bwd(k, res, drows):
+    back, keep = res
     g = back.shape[0] // k
     dx = jnp.take(drows, back, axis=0).reshape(g, k, -1)
-    return dx.astype(jnp.float32).sum(1).astype(drows.dtype), None, None
+    if keep is not None:
+        dx = jnp.where(keep[..., None], dx, 0)
+    return dx.astype(jnp.float32).sum(1).astype(drows.dtype), None, None, None
 
 
 _dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
 
 
 @jax.custom_vjp
-def _combine_rows(out, top_p, order, back):
+def _combine_rows(out, top_p, order, back, keep=None):
     """The experts' rows (G*k, d) back in token order, summed with their
     gates ``top_p`` (G, k) in float32: (G, d) in out's dtype.  Its
     backward gathers too: row ``i``'s cotangent is its token's,
     ``order[i] // k``, times its gate, and each gate's is its row's dot
-    with its token's."""
-    return _combine_rows_fwd(out, top_p, order, back)[0]
+    with its token's.  ``keep`` (G, k), where given, zeroes the picks it
+    leaves out: rows no matmul computed, whatever they hold."""
+    return _combine_rows_fwd(out, top_p, order, back, keep)[0]
 
 
-def _combine_rows_fwd(out, top_p, order, back):
+def _combine_rows_fwd(out, top_p, order, back, keep=None):
     g, k = top_p.shape
     got = jnp.take(out, back, axis=0).reshape(g, k, -1)
+    if keep is not None:
+        got = jnp.where(keep[..., None], got, 0)
+        top_p = jnp.where(keep, top_p, 0.0)
     y = jnp.einsum("gkd,gk->gd", got.astype(jnp.float32), top_p)
     return y.astype(out.dtype), (got, top_p, order)
 
@@ -249,7 +378,7 @@ def _combine_rows_bwd(res, dy):
              * gate[:, None])
     d_top_p = jnp.einsum("gkd,gd->gk", got.astype(jnp.float32),
                          dy.astype(jnp.float32))
-    return d_out.astype(got.dtype), d_top_p, None, None
+    return d_out.astype(got.dtype), d_top_p, None, None, None
 
 
 _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
@@ -258,21 +387,43 @@ _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 def _moe_dropless(p, cfg, x2d, e_pad, grouped, routes: bool = False):
     """Every routed row through its expert, grouped by expert with
     ``grouped(rows, weights, sizes)``.  Returns (out (G, d),
-    aux, stats)."""
+    aux, stats).
+
+    With a held share (``held``) only the rows routed to the held
+    experts are computed: the sort puts them first, grouped by expert,
+    and the rows of absent experts after them, which no grouped matmul
+    visits (its ``sizes`` cover the held rows alone) and which the
+    combine and the dispatch's backward mask to zero."""
     k = cfg.moe.top_k
+    held_share = held(cfg)
     with jax.named_scope("route"):
         top_p, top_i, aux, share = _route(p, cfg, x2d, e_pad)
     with jax.named_scope("dispatch"):
-        order, back, sizes = _sort_by_expert(top_i.reshape(-1), e_pad)
-        rows = _dispatch_rows(x2d, order, back, k)             # (G*k, d)
+        keep = None
+        if held_share is None:
+            order, back, sizes = _sort_by_expert(top_i.reshape(-1), e_pad)
+        else:
+            first, n_here = held_share
+            local = top_i - first
+            keep = (local >= 0) & (local < n_here)
+            # absent experts sort last, as one group no matmul visits
+            order, back, sizes = _sort_by_expert(
+                jnp.where(keep, local, n_here).reshape(-1), n_here + 1)
+            sizes = sizes[:n_here]
+        rows = _dispatch_rows(x2d, order, back, k, keep)       # (G*k, d)
     with jax.named_scope("experts"):
         a = grouped(rows, p["wg"], sizes)
         b = grouped(rows, p["wi"], sizes)
         out = grouped(jax.nn.silu(a) * b, p["wo"], sizes)
     with jax.named_scope("combine"):
-        y = _combine_rows(out, top_p, order, back)
+        y = _combine_rows(out, top_p, order, back, keep)
     stats = {"moe_load_max": _load_max(cfg, share),
              "moe_dropped": jnp.zeros((), jnp.float32)}
+    if held_share is not None:
+        n_moe = cfg.n_layers - cfg.n_dense_layers
+        stats["moe_held_rows"] = jnp.sum(keep, dtype=jnp.float32) / n_moe
+    if "bias" in p:
+        stats["moe_counts"] = _counts(top_i, e_pad)
     if routes:
         stats["moe_routes"] = top_i
     return y.astype(x2d.dtype), aux, stats
@@ -372,6 +523,9 @@ def _moe_ep_gspmd(p, cfg, x, e_pad, tp, lossy, key, drop_rate):
     y = ys.reshape(tp, b, s_loc, d).swapaxes(0, 1).reshape(b, s, d)
     stats = {"moe_load_max": _load_max(cfg, shares.mean(0)),
              "moe_dropped": jnp.sum(dropped)}
+    if "bias" in p:   # each sender's share is its counts over G_loc * k
+        stats["moe_counts"] = jnp.round(
+            shares.sum(0) * (b * s_loc * cfg.moe.top_k))
     return y, auxs.mean(), stats
 
 
@@ -402,6 +556,10 @@ def moe_block(p: Params, cfg: ModelConfig, x: jax.Array, *,
                                                e_pad, grouped, routes=routes)
             routed = routed.reshape(b, s, d)
         else:
+            if held(cfg) is not None:
+                raise ValueError("a held share runs the dropless path "
+                                 "only: the expert-parallel exchange that "
+                                 "completes it is not here")
             if e_pad % tp:
                 raise ValueError(
                     f"{e_pad} experts do not split over a {tp}-way model "

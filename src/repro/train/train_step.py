@@ -62,7 +62,12 @@ of its own) and ``optimizer``
 (``adamw.apply_updates``).  Scopes change op metadata only.  An MoE
 model's blocks add ``moe`` inside ``fwd_bwd`` (``repro.models.moe``),
 and its step returns their counters (``moe.STATS``: ``moe_load_max``,
-``moe_dropped``) among its metrics.
+``moe_dropped``; with a held share ``moe_held_rows``) among its
+metrics.  Where the routers hold a load-balancing bias (DeepSeek-V3's
+noaux_tc), the bias sits in the parameters but outside the gradient,
+the sync and AdamW: after AdamW, under ``moe_bias``, each layer's is
+moved by the step's routed counts over all its experts
+(``moe.update_bias``), and ``moe_bias_absmax`` joins the metrics.
 
 The ``drop_rate`` step input is where the transport engine couples in:
 ``Trainer`` walks an engine-derived ``DropSchedule`` (or the standalone
@@ -369,6 +374,8 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
             f"DCI phase must be the lowest (cut-first) priority class; "
             f"HierarchicalSchedule.PRIORITY is {prio}")
 
+    bias = MOE.has_bias(cfg)
+
     @jax.named_scope("fwd_bwd")
     def _grads_one(params, batch, key, drop_rate):
         # the MoE all-to-all coin expects one scalar; hierarchical mode
@@ -377,9 +384,11 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
         moe_rate = jnp.reshape(drop_rate, (-1,))[-1]
         lossy_ctx = M.LossyCtx(enabled=celeris.lossy_moe, key=key,
                                drop_rate=moe_rate)
+        params, biases = MOE.split_bias(params)   # biases: no gradient
 
         def loss_fn(p):
-            return M.lm_loss(p, cfg, batch, lossy=lossy_ctx)
+            return M.lm_loss(MOE.join_bias(p, biases), cfg, batch,
+                             lossy=lossy_ctx)
 
         return jax.value_and_grad(loss_fn, has_aux=True)(params)
 
@@ -407,15 +416,16 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
             return (gacc, lacc + l, nacc + n, aacc + a_,
                     MOE.merge_stats(sacc, s_)), None
 
+        weights = MOE.split_bias(params)[0]
         g0 = jax.tree.map(
-            lambda p_: jnp.zeros(p_.shape, jnp.float32), params)
+            lambda p_: jnp.zeros(p_.shape, jnp.float32), weights)
         z = jnp.zeros((), jnp.float32)
         (gsum, loss, nll, aux, stats), _ = jax.lax.scan(
-            mb_step, (g0, z, z, z, MOE.zero_stats(cfg)),
+            mb_step, (g0, z, z, z, MOE.zero_stats(cfg, counts=True)),
             (mb, jnp.arange(microbatches)))
         inv = 1.0 / microbatches
         grads = jax.tree.map(
-            lambda g_, p_: (g_ * inv).astype(p_.dtype), gsum, params)
+            lambda g_, p_: (g_ * inv).astype(p_.dtype), gsum, weights)
         loss, nll, aux = loss * inv, nll * inv, aux * inv
         return loss, nll, aux, stats, grads
 
@@ -475,7 +485,10 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
 
     def train_step(state, batch, key, drop_rate):
         params = state["params"]
-        plans = _leaf_plans(params, celeris, mesh)
+        # the router biases (if any) are no weights: not synced, not
+        # AdamW's
+        weights, biases = MOE.split_bias(params)
+        plans = _leaf_plans(weights, celeris, mesh)
 
         island_modes = {CollectiveMode.LOSSY_HADAMARD, CollectiveMode.LOSSY}
         if pod_axes:
@@ -494,7 +507,8 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
                 fn, mesh=mesh,
                 in_specs=(rep, rules.batch_specs(mesh, batch), P(), P(),
                           P(dp)),
-                out_specs=(P(), P(), P(), P(), rep, P()),
+                out_specs=(P(), P(), P(), P(),
+                           jax.tree.map(lambda _: P(), weights), P()),
                 axis_names=set(dp), check_vma=False,
             )(params, batch, key, drop_rate,
               jnp.arange(_dp_size(dp, mesh), dtype=jnp.int32))
@@ -528,7 +542,14 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
                     frac = jnp.float32(1.0)
 
         new_params, new_opt, om = adamw.apply_updates(
-            params, grads, state["opt"], opt_cfg)
+            weights, grads, state["opt"], opt_cfg)
+        if bias:
+            with jax.named_scope("moe_bias"):
+                biases = MOE.update_bias(biases, stats.pop("moe_counts"),
+                                         cfg)
+                stats["moe_bias_absmax"] = jnp.max(jnp.stack(
+                    [jnp.max(jnp.abs(b)) for b in jax.tree.leaves(biases)]))
+        new_params = MOE.join_bias(new_params, biases)
         metrics = {"loss": loss, "nll": nll, "aux": aux,
                    "recv_frac": frac, **stats, **om}
         return {"params": new_params, "opt": new_opt,
@@ -540,7 +561,7 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: adamw.OptConfig,
 
 def init_state(key, cfg: ModelConfig):
     params = M.init_params(key, cfg)
-    opt = adamw.init_opt_state(params)
+    opt = adamw.init_opt_state(MOE.split_bias(params)[0])
     return {"params": params, "opt": opt, "step": jnp.zeros((), jnp.int32)}
 
 
@@ -549,6 +570,7 @@ def state_shardings(state, mesh):
     ps = rules.param_shardings(state["params"], mesh)
     return {
         "params": ps,
-        "opt": rules.opt_state_shardings(state["opt"], state["params"], mesh),
+        "opt": rules.opt_state_shardings(
+            state["opt"], MOE.split_bias(state["params"])[0], mesh),
         "step": NamedSharding(mesh, P()),
     }

@@ -128,6 +128,52 @@ def test_moe_ep_on_mesh_matches_single_device():
     assert "OK" in out
 
 
+def test_router_bias_on_expert_parallel_mesh():
+    """DeepSeek-V3's router with its bias on the expert-parallel path
+    (all experts, a model axis): the same per-layer counts as the
+    dropless path, and a train step on the mesh moves every bias entry
+    by bias_rate."""
+    out = _run("""
+        import dataclasses
+        import jax, jax.numpy as jnp, numpy as np
+        import repro.configs as C
+        from repro import sharding as shd
+        from repro.models import moe as MOE
+        from repro.optim.adamw import OptConfig
+        from repro.train import train_step as ts, sharding_rules as rules
+        cfg = dataclasses.replace(C.get_smoke('moonlight-16b-a3b'),
+                                  dtype='float32')
+        cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+            cfg.moe, capacity_factor=50.0))
+        p = MOE.init_moe(jax.random.PRNGKey(0), cfg)
+        p['bias'] = jax.random.normal(jax.random.PRNGKey(2), (16,)) * 0.05
+        x = jax.random.normal(jax.random.PRNGKey(1), (2, 64, cfg.d_model))
+        shd.set_global_mesh(None)
+        _, _, local = MOE.moe_block(p, cfg, x)
+        mesh = shd.make_mesh((4, 2), ('data', 'model'))
+        shd.set_global_mesh(mesh)
+        _, _, ep = jax.jit(lambda p_, x_: MOE.moe_block(p_, cfg, x_))(p, x)
+        np.testing.assert_array_equal(np.asarray(ep['moe_counts']),
+                                      np.asarray(local['moe_counts']))
+        st = ts.init_state(jax.random.PRNGKey(0), cfg)
+        st = jax.device_put(st, ts.state_shardings(st, mesh))
+        fn = ts.make_train_step(cfg, mesh, OptConfig(lr=1e-3),
+                                ts.CelerisConfig(mode='lossy_hadamard',
+                                                 min_coded_size=1024))
+        tok = jax.random.randint(jax.random.PRNGKey(3), (8, 64), 0, 512)
+        host = {'tokens': tok, 'labels': tok}
+        sp = rules.batch_specs(mesh, host)
+        b = {k: jax.device_put(v, jax.sharding.NamedSharding(mesh, sp[k]))
+             for k, v in host.items()}
+        st, m = fn(st, b, jax.random.PRNGKey(4), jnp.float32(0.0))
+        assert np.isfinite(float(m['loss']))
+        bias = np.asarray(st['params']['decoder']['groups'][0]['moe']['bias'])
+        np.testing.assert_allclose(np.abs(bias), 0.001)
+        print('OK')
+    """)
+    assert "OK" in out
+
+
 @pytest.mark.slow
 def test_dryrun_cell_compiles_and_fits():
     """One full production-mesh dry-run cell end-to-end (512 devices)."""

@@ -225,3 +225,40 @@ def test_moe_coded_train_step_compiles_on_a_dp_v5e_mesh(topo,
         jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep),
         jax.ShapeDtypeStruct((), jnp.float32, sharding=rep)).compile()
     assert compiled.memory_analysis() is not None
+
+
+def test_moonlight_smoke_train_step_compiles_for_v5e(one_chip,
+                                                     on_chip_kernels):
+    """DeepSeek-V3's block at smoke widths (latent attention at seq 2048
+    through the splash kernels, 4 of 16 experts held through the grouped
+    matmul, the router bias) in the one-chip coded train step, with the
+    kernels as the chip compiles them; the experts 128 wide, the
+    grouped matmul's least tile."""
+    import dataclasses
+
+    import repro.configs as C
+    from repro.optim.adamw import OptConfig
+    from repro.train import train_step as ts
+
+    cfg = C.get_smoke("moonlight-16b-a3b")
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+        cfg.moe, n_held=4, held_first=4, d_expert=128))
+    step = ts.make_train_step(
+        cfg, None, OptConfig(warmup_steps=1),
+        ts.CelerisConfig(mode="lossy_hadamard", n_rot=256,
+                         min_coded_size=1024), donate=False)
+    state = jax.eval_shape(lambda k: ts.init_state(k, cfg),
+                           jax.random.PRNGKey(0))
+    state = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), state)
+    batch = {k: jax.ShapeDtypeStruct((1, 2048), jnp.int32,
+                                     sharding=one_chip)
+             for k in ("tokens", "labels")}
+    compiled = step.lower(
+        state, batch, jax.ShapeDtypeStruct((2,), jnp.uint32,
+                                           sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    for kernel in ("splash_mha_fwd", "splash_mha_dq", "splash_mha_dkv"):
+        assert kernel in text, kernel
+    assert compiled.memory_analysis() is not None
