@@ -51,6 +51,21 @@ share (``n_held``, ``held_first``): the device routes over every
 expert but holds and computes only its own, the expert-parallel layer
 without its exchange, counted by ``moe_held_rows``.
 
+A held share's routed rows go into a compact buffer of C rows, not G*k:
+the sort puts the held experts' rows first, grouped by expert, and only
+its first C rows are gathered, run through the experts and read back by
+the combine, whose backward takes each gate's cotangent per buffer row.
+C is derived from shapes (:func:`_buffer_rows`): twice the held share's
+expected rows, ``2 * G*k * n_held / n_experts``, rounded up to the
+grouped matmul's row tile and at most G*k; the deployment's
+expert-parallel exchange would deliver about that share to the chip.
+Where the held rows do not fit (``sum(sizes) > C``, data-dependent), a
+``jax.lax.cond`` runs the tokens in chunks instead, each sorted alone
+into a buffer of all its picks (at most C) and under ``jax.checkpoint``,
+so that no full-size buffer is built or kept for the backward; the
+counter ``moe_held_overflow`` counts the layer calls that took it.  No
+row is dropped either way.
+
 Routed experts are zero-padded to ``MoEConfig.expert_pad_multiple``
 (16 keeps parameter shapes mesh-independent up to a 16-way model axis;
 dummy experts are unroutable: router logits forced to -inf).
@@ -72,9 +87,11 @@ Params = Dict[str, Any]
 
 # how each counter combines over layers, microbatches and dp shards;
 # ``moe_held_rows`` (a held share only: the routed rows of the held
-# experts, each layer's over the MoE layers, so their mean)
+# experts, each layer's over the MoE layers, so their mean) and
+# ``moe_held_overflow`` (the layer calls whose held rows overflowed the
+# compact buffer, so ran in token chunks)
 STATS = {"moe_load_max": "max", "moe_dropped": "sum"}
-HELD_STATS = {"moe_held_rows": "sum"}
+HELD_STATS = {"moe_held_rows": "sum", "moe_held_overflow": "sum"}
 # per-layer outputs of a block, stacked over the MoE layers and not
 # merged: each token's expert ids (``routes``), and with a router bias
 # each routed expert's count of the layer's routed rows (summed over
@@ -322,14 +339,25 @@ def _sort_by_expert(flat: jax.Array, e_pad: int):
     return order, back, sizes
 
 
+def _buffer_rows(cfg: ModelConfig, routed: int) -> int:
+    """C, the rows of a held share's compact buffer for ``routed`` (G*k)
+    routed rows: twice the held experts' expected rows,
+    ``routed * n_held / n_experts``, rounded up to the grouped matmul's
+    row tile, and at most ``routed``."""
+    m = cfg.moe
+    twice = -(-2 * routed * m.n_held // m.n_experts)
+    return min(routed, -(-twice // ops.GMM_TILE) * ops.GMM_TILE)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _dispatch_rows(x2d, order, back, k, keep=None):
     """Each token's row once per pick, grouped by expert:
-    ``x2d[order // k]`` (G*k, d).  Its backward gathers the rows'
+    ``x2d[order // k]`` (len(order), d).  Its backward gathers the rows'
     cotangents back to token order by ``back`` and sums each token's k
-    in float32, where JAX would transpose the gather to a scatter-add;
-    ``keep`` (G, k), where given, zeroes the picks it leaves out (rows
-    no matmul computed)."""
+    in float32, where JAX would transpose the gather to a scatter-add.
+    ``keep`` (G, k), where given, zeroes the picks it leaves out: rows
+    no matmul computed, and rows past the first C of the sort where
+    ``order`` is cut to them (their ``back`` is clamped to the buffer)."""
     return jnp.take(x2d, order // k, axis=0)
 
 
@@ -340,8 +368,10 @@ def _dispatch_rows_fwd(x2d, order, back, k, keep=None):
 def _dispatch_rows_bwd(k, res, drows):
     back, keep = res
     g = back.shape[0] // k
-    dx = jnp.take(drows, back, axis=0).reshape(g, k, -1)
-    if keep is not None:
+    if keep is None:
+        dx = jnp.take(drows, back, axis=0).reshape(g, k, -1)
+    else:
+        dx = jnp.take(drows, back, axis=0, mode="clip").reshape(g, k, -1)
         dx = jnp.where(keep[..., None], dx, 0)
     return dx.astype(jnp.float32).sum(1).astype(drows.dtype), None, None, None
 
@@ -351,37 +381,102 @@ _dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
 
 @jax.custom_vjp
 def _combine_rows(out, top_p, order, back, keep=None):
-    """The experts' rows (G*k, d) back in token order, summed with their
-    gates ``top_p`` (G, k) in float32: (G, d) in out's dtype.  Its
-    backward gathers too: row ``i``'s cotangent is its token's,
-    ``order[i] // k``, times its gate, and each gate's is its row's dot
-    with its token's.  ``keep`` (G, k), where given, zeroes the picks it
-    leaves out: rows no matmul computed, whatever they hold."""
+    """The experts' rows ``out``, in ``order``'s order, back in token
+    order, summed with their gates ``top_p`` (G, k) in float32: (G, d)
+    in out's dtype.  Its backward gathers too: row ``i``'s cotangent is
+    its token's, ``order[i] // k``, times its gate, and each gate's is
+    its row's dot with its token's.  ``keep`` (G, k), where given,
+    zeroes the picks it leaves out: rows no matmul computed, whatever
+    they hold, and rows past ``out``'s, where ``out`` and ``order`` are
+    cut to the first C rows of the sort (``back`` is clamped to them).
+    The backward then takes each gate's dot per row of ``out`` and
+    gathers it to the picks, so it keeps no (G, k, d) array."""
     return _combine_rows_fwd(out, top_p, order, back, keep)[0]
 
 
 def _combine_rows_fwd(out, top_p, order, back, keep=None):
     g, k = top_p.shape
-    got = jnp.take(out, back, axis=0).reshape(g, k, -1)
-    if keep is not None:
+    if keep is None:
+        got = jnp.take(out, back, axis=0).reshape(g, k, -1)
+        res = (got, top_p, order, None, None)
+    else:
+        got = jnp.take(out, back, axis=0, mode="clip").reshape(g, k, -1)
         got = jnp.where(keep[..., None], got, 0)
         top_p = jnp.where(keep, top_p, 0.0)
+        res = (out, top_p, order, back, keep)
     y = jnp.einsum("gkd,gk->gd", got.astype(jnp.float32), top_p)
-    return y.astype(out.dtype), (got, top_p, order)
+    return y.astype(out.dtype), res
 
 
 def _combine_rows_bwd(res, dy):
-    got, top_p, order = res
+    saved, top_p, order, back, keep = res
     k = top_p.shape[1]
     gate = jnp.take(top_p.reshape(-1), order)
-    d_out = (jnp.take(dy, order // k, axis=0).astype(jnp.float32)
-             * gate[:, None])
-    d_top_p = jnp.einsum("gkd,gd->gk", got.astype(jnp.float32),
-                         dy.astype(jnp.float32))
-    return d_out.astype(got.dtype), d_top_p, None, None, None
+    dy_rows = jnp.take(dy, order // k, axis=0).astype(jnp.float32)
+    d_out = dy_rows * gate[:, None]
+    if keep is None:   # saved: the (G, k, d) rows in token order
+        d_top_p = jnp.einsum("gkd,gd->gk", saved.astype(jnp.float32),
+                             dy.astype(jnp.float32))
+    else:              # saved: out itself
+        dots = jnp.sum(saved.astype(jnp.float32) * dy_rows, -1)
+        d_top_p = jnp.where(keep, jnp.take(dots, back, mode="clip")
+                            .reshape(top_p.shape), 0.0)
+    return d_out.astype(saved.dtype), d_top_p, None, None, None
 
 
 _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
+def _expert_rows(c, k, grouped, x2d, top_p, order, back, keep, sizes, w):
+    """The routed rows through their experts, in a buffer of the first
+    ``c`` rows of the sort (``order``), which has to hold every row a
+    matmul computes (``sum(sizes) <= c``); ``keep`` as the permutes
+    take it: (G, d) in x2d's dtype."""
+    with jax.named_scope("dispatch"):
+        order = order[:c]
+        rows = _dispatch_rows(x2d, order, back, k, keep)        # (c, d)
+    with jax.named_scope("experts"):
+        a = grouped(rows, w["wg"], sizes)
+        b = grouped(rows, w["wi"], sizes)
+        out = grouped(jax.nn.silu(a) * b, w["wo"], sizes)
+    with jax.named_scope("combine"):
+        return _combine_rows(out, top_p, order, back, keep)
+
+
+def _held_sort(top_i, held_share):
+    """The held share's sort of the routed rows: (keep (G, k), order,
+    back, sizes of the held experts); absent experts sort last, as one
+    group no matmul visits."""
+    first, n_here = held_share
+    local = top_i - first
+    keep = (local >= 0) & (local < n_here)
+    order, back, sizes = _sort_by_expert(
+        jnp.where(keep, local, n_here).reshape(-1), n_here + 1)
+    return keep, order, back, sizes[:n_here]
+
+
+def _held_in_chunks(c, k, grouped, held_share, w, x2d, top_p, top_i):
+    """The held share's rows where they overflow a compact buffer of
+    ``c``: the tokens in chunks of t, the most that divides G with
+    t*k <= c, each sorted alone into a buffer of all its t*k picks, so
+    that none can overflow.  Each chunk runs under ``jax.checkpoint``,
+    so the backward keeps only the chunks' inputs and holds one chunk's
+    rows at a time: (G, d)."""
+    g = x2d.shape[0]
+    t = max(n for n in range(1, c // k + 1) if g % n == 0)
+
+    @jax.checkpoint
+    def chunk(a):
+        xc, pc, ic = a
+        with jax.named_scope("dispatch"):
+            keep, order, back, sizes = _held_sort(ic, held_share)
+        return _expert_rows(t * k, k, grouped, xc, pc, order, back, keep,
+                            sizes, w)
+
+    return jax.lax.map(chunk, (x2d.reshape(g // t, t, -1),
+                               top_p.reshape(g // t, t, k),
+                               top_i.reshape(g // t, t, k))
+                       ).reshape(x2d.shape)
 
 
 def _moe_dropless(p, cfg, x2d, e_pad, grouped, routes: bool = False):
@@ -391,37 +486,45 @@ def _moe_dropless(p, cfg, x2d, e_pad, grouped, routes: bool = False):
 
     With a held share (``held``) only the rows routed to the held
     experts are computed: the sort puts them first, grouped by expert,
-    and the rows of absent experts after them, which no grouped matmul
-    visits (its ``sizes`` cover the held rows alone) and which the
-    combine and the dispatch's backward mask to zero."""
+    and the rows of absent experts after them.  Only the first C
+    (:func:`_buffer_rows`) are gathered and run through the experts,
+    whose ``sizes`` cover the held rows alone; the combine and the
+    dispatch's backward mask the rest to zero.  Where the held rows
+    exceed C, ``jax.lax.cond`` runs them in token chunks instead
+    (:func:`_held_in_chunks`)."""
     k = cfg.moe.top_k
     held_share = held(cfg)
+    w = {n: p[n] for n in ("wg", "wi", "wo")}
     with jax.named_scope("route"):
         top_p, top_i, aux, share = _route(p, cfg, x2d, e_pad)
-    with jax.named_scope("dispatch"):
-        keep = None
-        if held_share is None:
+    routed = top_i.size
+    if held_share is None:
+        with jax.named_scope("dispatch"):
             order, back, sizes = _sort_by_expert(top_i.reshape(-1), e_pad)
+        y = _expert_rows(routed, k, grouped, x2d, top_p, order, back, None,
+                         sizes, w)
+    else:
+        with jax.named_scope("dispatch"):
+            keep, order, back, sizes = _held_sort(top_i, held_share)
+        c = _buffer_rows(cfg, routed)
+
+        def compact(*_):
+            return _expert_rows(c, k, grouped, x2d, top_p, order, back, keep,
+                                sizes, w)
+
+        if c == routed:
+            overflow, y = jnp.zeros((), bool), compact()
         else:
-            first, n_here = held_share
-            local = top_i - first
-            keep = (local >= 0) & (local < n_here)
-            # absent experts sort last, as one group no matmul visits
-            order, back, sizes = _sort_by_expert(
-                jnp.where(keep, local, n_here).reshape(-1), n_here + 1)
-            sizes = sizes[:n_here]
-        rows = _dispatch_rows(x2d, order, back, k, keep)       # (G*k, d)
-    with jax.named_scope("experts"):
-        a = grouped(rows, p["wg"], sizes)
-        b = grouped(rows, p["wi"], sizes)
-        out = grouped(jax.nn.silu(a) * b, p["wo"], sizes)
-    with jax.named_scope("combine"):
-        y = _combine_rows(out, top_p, order, back, keep)
+            overflow = jnp.sum(sizes) > c
+            y = jax.lax.cond(overflow, functools.partial(
+                _held_in_chunks, c, k, grouped, held_share, w), compact,
+                x2d, top_p, top_i)
     stats = {"moe_load_max": _load_max(cfg, share),
              "moe_dropped": jnp.zeros((), jnp.float32)}
     if held_share is not None:
         n_moe = cfg.n_layers - cfg.n_dense_layers
         stats["moe_held_rows"] = jnp.sum(keep, dtype=jnp.float32) / n_moe
+        stats["moe_held_overflow"] = overflow.astype(jnp.float32)
     if "bias" in p:
         stats["moe_counts"] = _counts(top_i, e_pad)
     if routes:

@@ -8,6 +8,7 @@ and of one chip's share."""
 import dataclasses
 import json
 import os
+import re
 import sys
 
 import jax
@@ -206,6 +207,142 @@ def test_held_share_counts_and_gradients():
         scale = float(jnp.max(jnp.abs(b)))
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-4 * scale, rtol=1e-3)
+
+
+def _held_layer(first, to_held=False):
+    """A layer holding 4 of the 16 experts from ``first`` (float32); with
+    ``to_held`` its router bias sends every pick to them."""
+    cut = _cfg(n_held=4, held_first=first)
+    p = _moe_layer(cut, key=21 + first)
+    if to_held:
+        ids = jnp.arange(cut.moe.n_experts)
+        p["bias"] = jnp.where((ids >= first) & (ids < first + 4), 10.0, 0.)
+    return cut, p
+
+
+def _block(cut, p, x, monkeypatch, full=False):
+    """The held block's output, counters and gradients of its weights
+    (router, experts, shared experts) and of x; ``full``: in a buffer
+    of all G*k routed rows, with no fallback."""
+    if full:
+        monkeypatch.setattr(MOE, "_buffer_rows", lambda cfg, routed: routed)
+    weights = {k: v for k, v in p.items() if k != "bias"}
+
+    def f(w, x_):
+        y, _, st = MOE.moe_block({**w, "bias": p["bias"]}, cut, x_)
+        return jnp.sum(y * y), (y, st)
+
+    with jax.default_matmul_precision("highest"):
+        (_, (y, st)), g = jax.jit(jax.value_and_grad(
+            f, (0, 1), has_aux=True))(weights, x)
+    monkeypatch.undo()
+    return y, st, g
+
+
+def _assert_same_block(got, want):
+    (y, st, g), (y_w, st_w, g_w) = got, want
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y_w))
+    np.testing.assert_array_equal(np.asarray(st["moe_counts"]),
+                                  np.asarray(st_w["moe_counts"]))
+    assert float(st["moe_held_rows"]) == float(st_w["moe_held_rows"])
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(g)[0],
+                            jax.tree.leaves(g_w)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-6,
+            atol=1e-6 * float(jnp.max(jnp.abs(b))),
+            err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("first", [0, 4, 12])
+def test_compact_buffer_matches_the_full_size_one(first, monkeypatch):
+    """4 of 16 experts held over G*k = 256*4 routed rows: the held rows
+    (about 256) run in a compact buffer of C = 512, and the block's
+    output, routed counts and gradients (of x, the router, the experts
+    and the shared experts) equal those of the full-size buffer; the
+    output equals the reference's; no layer overflowed."""
+    cut, p = _held_layer(first)
+    x = _x(30 + first, 128)
+    assert MOE._buffer_rows(cut, 256 * 4) == 512
+    compact = _block(cut, p, x, monkeypatch)
+    _assert_same_block(compact, _block(cut, p, x, monkeypatch, full=True))
+    n_moe = cut.n_layers - cut.n_dense_layers
+    assert 0 < float(compact[1]["moe_held_rows"]) * n_moe <= 512
+    assert float(compact[1]["moe_held_overflow"]) == 0.0
+    with jax.default_matmul_precision("highest"):
+        want = jax.vmap(lambda r: _ref(cut)._moe(r, p, p["bias"])[0])(x)
+    np.testing.assert_allclose(np.asarray(compact[0]), np.asarray(want),
+                               atol=2e-5, rtol=2e-4)
+
+
+@pytest.mark.parametrize("first,s", [(0, 128), (12, 128), (4, 120)])
+def test_overflowing_held_rows_run_in_token_chunks(first, s, monkeypatch):
+    """A router bias that sends every pick to the 4 held experts gives
+    G*k = 2*s*4 held rows, more than the compact buffer's 512: the block
+    runs the tokens in chunks whose picks fit it (two of 128 tokens at
+    s = 128, two of 120 at s = 120), counts that in
+    ``moe_held_overflow`` and equals the full-size buffer's, gradients
+    included."""
+    cut, p = _held_layer(first, to_held=True)
+    x = _x(40 + first, s)
+    assert MOE._buffer_rows(cut, 2 * s * 4) == 512
+    got = _block(cut, p, x, monkeypatch)
+    _assert_same_block(got, _block(cut, p, x, monkeypatch, full=True))
+    n_moe = cut.n_layers - cut.n_dense_layers
+    assert float(got[1]["moe_held_rows"]) * n_moe == 2 * s * 4
+    assert float(got[1]["moe_held_overflow"]) == 1.0
+
+
+@pytest.mark.parametrize("to_held", [False, True])
+def test_train_step_counts_held_overflow(to_held):
+    """The train step's ``moe_held_overflow`` is the MoE layer calls
+    whose held rows overflowed the compact buffer: 0 on ordinary
+    routing, every MoE layer where the router biases send every pick to
+    the held experts; the step's loss is finite either way."""
+    cut = _cfg(n_held=4, held_first=4)
+    step = ts.make_train_step(cut, None, adamw.OptConfig(warmup_steps=1),
+                              ts.CelerisConfig(), donate=False)
+    state = ts.init_state(jax.random.PRNGKey(0), cut)
+    if to_held:
+        moe = state["params"]["decoder"]["groups"][0]["moe"]
+        ids = jnp.arange(cut.moe.n_experts)
+        moe["bias"] = jnp.broadcast_to(
+            jnp.where((ids >= 4) & (ids < 8), 10.0, 0.0), moe["bias"].shape)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 128),
+                                          0, 512)}
+    batch["labels"] = batch["tokens"]
+    _, metrics = step(state, batch, jax.random.PRNGKey(2), jnp.float32(0.))
+    n_moe = cut.n_layers - cut.n_dense_layers
+    assert float(metrics["moe_held_overflow"]) == (n_moe if to_held else 0)
+    held_rows = float(metrics["moe_held_rows"])   # a layer's, on average
+    assert held_rows == 256 * 4 if to_held else held_rows <= 512
+    assert np.isfinite(float(metrics["loss"]))
+
+
+@pytest.mark.parametrize("share", [None, 4])
+def test_block_compiled_alone(share):
+    """``moe_block`` forward and backward, compiled alone: its permutes
+    gather, forward and backward, and never scatter; a conditional of the
+    block's own (``moe/cond``, not the interpreted Pallas kernels' on
+    the CPU) only with a held share whose compact buffer
+    is cut below G*k, none without one (Granite's block)."""
+    if share is None:
+        cfg = C.get_smoke("granite-moe-3b-a800m")
+        p = MOE.init_moe(jax.random.PRNGKey(0), cfg)
+    else:
+        cfg, p = _held_layer(0)
+    x = _x(50, 128, d=cfg.d_model)
+    hlo = jax.jit(jax.grad(lambda p_, x_: jnp.sum(jnp.square(
+        MOE.moe_block(p_, cfg, x_)[0])), (0, 1))).lower(p, x).compile(
+        ).as_text()
+    for c in ("dispatch", "combine"):
+        mine = [ln for ln in hlo.splitlines() if f"/{c}/" in ln]
+        assert not [ln for ln in mine if " scatter(" in ln], c
+        for way in ("jvp(", "transpose("):
+            assert [ln for ln in mine if " gather(" in ln
+                    and f"/{way}" in ln], (c, way)
+    conds = [ln for ln in hlo.splitlines() if " conditional(" in ln
+             and re.search(r'moe\)*/cond"', ln)]
+    assert bool(conds) == (share is not None), conds
 
 
 def test_bias_update_over_two_steps():
